@@ -162,8 +162,9 @@ def main(argv=None):
                          "empty string disables ingestion)")
     args = ap.parse_args(argv)
 
-    from lightgbm_tpu.utils.common import honor_jax_platforms
-    honor_jax_platforms()
+
+    from lightgbm_tpu.utils.common import enable_compilation_cache
+    enable_compilation_cache()
 
     rows = args.rows or (4000 if args.dry else 200_000)
     leaves = args.leaves or (15 if args.dry else 255)

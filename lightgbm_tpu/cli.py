@@ -215,6 +215,11 @@ def main(argv=None) -> int:
     params = key_alias_transform(params, raise_unknown=False)
     cfg = Config(params)
     task = params.get("task", "train")
+    if task in ("train", "predict", "prediction", "test"):
+        # before the first compile: a second CLI run of the same shape
+        # loads its programs instead of compiling them
+        from .utils.common import enable_compilation_cache
+        enable_compilation_cache()
     if task == "train":
         run_train(cfg)
     elif task in ("predict", "prediction", "test"):

@@ -528,7 +528,7 @@ class GBDT:
                         or bool(getattr(lrn, "fused_autotune", False)))
                 if want:
                     fused = _fi.FusedIteration.build(
-                        self.learner, self.objective.get_gradients,
+                        self.learner, self.objective,
                         self.num_data, self.score_dtype)
         self._fused_state = (fused,)
         return fused

@@ -14,6 +14,7 @@ shaped (num_class, num_data) with the same meaning.
 """
 from __future__ import annotations
 
+import copy
 import functools
 from typing import List, Optional
 
@@ -44,6 +45,28 @@ class ObjectiveFunction:
 
     def get_gradients(self, score):
         raise NotImplementedError
+
+    def split_device_state(self):
+        """(arrays, rebind): every device array this objective holds
+        (labels, weights, per-row tables), and a function that returns a
+        shallow copy of the objective bound to replacement arrays.
+
+        The fused iteration (ops/fused_iter.py) passes ``arrays`` as
+        program ARGUMENTS and calls ``rebind(tracers).get_gradients``
+        inside the trace, so no dataset-sized array is baked into the
+        compiled program as a literal."""
+        leaves, treedef = jax.tree_util.tree_flatten(vars(self))
+        on_device = [isinstance(v, jax.Array) for v in leaves]
+
+        def rebind(arrays):
+            it = iter(arrays)
+            merged = [next(it) if d else v
+                      for v, d in zip(leaves, on_device)]
+            clone = copy.copy(self)
+            vars(clone).update(jax.tree_util.tree_unflatten(treedef, merged))
+            return clone
+
+        return [v for v, d in zip(leaves, on_device) if d], rebind
 
     def convert_output(self, x):
         return x

@@ -37,8 +37,8 @@ GPU Accelerated Learning" ground their claims in, built into the loop):
   event and routed through the health channel so a degenerate dataset
   fails fast under ``obs_health=fatal``;
 * ``roofline`` — roofline attribution: a device-peak registry (per
-  ``device_kind`` FLOP/s, HBM and ICI bandwidth, VMEM — with a CPU
-  fallback so the layer is testable off-TPU) joined against the
+  ``device_kind`` FLOP/s, HBM and ICI bandwidth, VMEM — with a ``cpu``
+  row so the layer is testable off-TPU) joined against the
   ``compile_attr`` cost estimates and measured execute times to give
   every jitted entry achieved-vs-peak utilization, arithmetic
   intensity, a compute/memory/collective/host-orchestration bound and

@@ -32,8 +32,8 @@ frames as the hard part of GBDT perf work applied *across* runs:
   rev.  ``--check`` exits nonzero when the CURRENT regime of a gated
   metric began with a bad-direction shift — the CI gate.
 
-Ingestion is idempotent (dedup on run id + header timestamp): bench
-retries and re-runs of a backfill are no-ops.  Every writer is
+Ingestion is idempotent (dedup on run id + header timestamp): ingesting
+a timeline twice is a no-op.  Every writer is
 best-effort — the ledger must never take a finished run down.
 """
 from __future__ import annotations

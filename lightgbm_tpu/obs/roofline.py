@@ -35,12 +35,12 @@ Three consumers:
 
 Peaks are **dataplane ceilings, not promises**: the table below holds
 published per-chip figures for the TPU generations the wave engine
-targets plus a deliberately modest CPU fallback profile so the whole
-layer is testable off-TPU.  An unknown ``device_kind`` falls back with
-``source="fallback"`` rather than failing — a wrong-but-labelled roof
-still ranks entries correctly relative to each other.  Override or
-extend via ``obs_roofline_peaks`` (a JSON file mapping device kinds to
-profiles, merged over the defaults).
+targets plus a deliberately modest ``cpu`` row so the whole layer is
+testable off-TPU.  A ``device_kind`` that is not in the table is an
+error (``peaks_for`` raises): a utilization against another device's
+roof is not a number.  Override or extend via ``obs_roofline_peaks`` (a
+JSON file mapping device kinds to profiles, merged over the defaults; a
+kind the table does not have must give every field).
 """
 from __future__ import annotations
 
@@ -79,9 +79,9 @@ DEFAULT_PEAKS = {
         "hbm_bytes_per_s": 1640e9, "ici_bytes_per_s": 448e9,
         "vmem_bytes": 128 * 2**20,
     },
-    # off-TPU fallback: a deliberately modest single-socket profile so
-    # CPU timelines (CI, tests) produce finite, clearly-labelled
-    # utilization numbers instead of failing the join
+    # the CPU backend (CI, tests): a deliberately modest single-socket
+    # profile so CPU timelines produce finite, clearly-labelled
+    # utilization numbers
     "cpu": {
         "flops_f32": 100e9, "flops_bf16": 100e9,
         "hbm_bytes_per_s": 25e9, "ici_bytes_per_s": 10e9,
@@ -141,29 +141,38 @@ def load_peak_overrides(path):
         return {}
 
 
+_PEAK_FIELDS = frozenset(DEFAULT_PEAKS["cpu"])
+
+
 def peaks_for(kind, overrides=None):
     """The peak profile of ``kind`` with provenance attached.
 
     Resolution: exact normalized match in ``overrides``, then in the
     default table, then a prefix match against the defaults (a
-    ``tpu_v5p_pod`` kind still finds ``tpu_v5p``), else the CPU
-    fallback with ``source="fallback"`` — an unknown chip must degrade
-    to labelled estimates, never to a crash.
+    ``tpu_v5p_pod`` kind still finds ``tpu_v5p``).  A kind that matches
+    no row raises ValueError, as does an override for a new kind that
+    leaves a field out: there is no default device.
     """
     nk = normalize_kind(kind)
     table = dict(DEFAULT_PEAKS)
     for k, v in (overrides or {}).items():
-        base = dict(table.get(normalize_kind(k), DEFAULT_PEAKS["cpu"]))
+        base = dict(table.get(normalize_kind(k), {}))
         base.update(v)
         table[normalize_kind(k)] = base
-    if nk in table:
-        return dict(table[nk], kind=nk,
-                    source=("override" if nk in (overrides or {})
-                            else "table"))
-    for k in table:
-        if k != "cpu" and (nk.startswith(k) or k.startswith(nk)) and nk:
-            return dict(table[k], kind=k, source="table")
-    return dict(table["cpu"], kind=nk or "unknown", source="fallback")
+    match = nk if nk in table else next(
+        (k for k in table if nk and k != "cpu"
+         and (nk.startswith(k) or k.startswith(nk))), None)
+    if match is None:
+        raise ValueError(
+            "no roofline peaks for device_kind %r (normalized %r): add a "
+            "row to obs/roofline.py DEFAULT_PEAKS or an obs_roofline_peaks "
+            "override; known kinds: %s" % (kind, nk, sorted(table)))
+    missing = _PEAK_FIELDS - set(table[match])
+    if missing:
+        raise ValueError("roofline peaks for %r lack %s"
+                         % (match, sorted(missing)))
+    return dict(table[match], kind=match,
+                source="override" if nk in (overrides or {}) else "table")
 
 
 # -- the per-entry join --------------------------------------------------
@@ -321,8 +330,8 @@ def utilization_rollup(entry_summary, costs, peaks, world_size=1):
         "hbm_util": sum(r["hbm_util"] * r["weight"] for r in rows) / wsum,
         "headroom_s": sum(r["headroom_s"] for r in rows),
         "bound": worst["bound"],
-        "device_kind": peaks.get("kind", "unknown"),
-        "roof_source": peaks.get("source", "fallback"),
+        "device_kind": peaks["kind"],
+        "roof_source": peaks["source"],
         "entries": {r["entry"]: {"flop_util": round(r["flop_util"], 6),
                                  "hbm_util": round(r["hbm_util"], 6),
                                  "bound": r["bound"]}

@@ -99,8 +99,6 @@ CACHE_SCHEMA_REV = 2
 # neighbour arms.
 MAX_CELLS = 6
 
-_CACHE_ENV = "LGBM_TPU_COMPILE_CACHE"
-_CACHE_DEFAULT_DIR = "/tmp/lgbm_tpu_xla_cache"
 _CACHE_FILENAME = "autotune_cache.json"
 
 
@@ -342,15 +340,13 @@ def resolve_mode(config: Config) -> str:
 
 
 def resolve_cache_path(config: Config) -> str:
-    """``tpu_autotune_cache`` when set, else ``autotune_cache.json``
-    next to the XLA compile cache (utils/common.py
-    enable_compilation_cache uses the same root)."""
+    """``tpu_autotune_cache`` when set, else ``autotune_cache.json`` in
+    the compile-cache directory (utils/common.py compilation_cache_dir)."""
     p = str(config.tpu_autotune_cache).strip()
     if p:
         return p
-    root = os.environ.get(_CACHE_ENV, _CACHE_DEFAULT_DIR) \
-        or _CACHE_DEFAULT_DIR
-    return os.path.join(root, _CACHE_FILENAME)
+    from ..utils.common import compilation_cache_dir
+    return os.path.join(compilation_cache_dir(), _CACHE_FILENAME)
 
 
 def cache_key(device_kind: str, bucket: ShapeBucket) -> str:

@@ -59,8 +59,7 @@ def fused_supported(booster) -> tuple:
     """(ok, reason) — can this booster's iteration be fused?
 
     Pure bookkeeping checks; the trace check (can the objective actually
-    be staged into a jit?) happens in FusedIteration.build, which
-    returns None on failure.
+    be staged into a jit?) happens in FusedIteration.build.
     """
     from ..models.gbdt import GBDT
     from .learner import SerialTreeLearner
@@ -91,20 +90,27 @@ class FusedIteration:
     a training run); ``run`` submits a single program and returns the
     same (TreeArrays, leaf_id, new_score) triple the staged chain
     produces across its three entries.
+
+    Every dataset-sized array — the bin matrix X, its transpose Xt, the
+    objective's labels / weights / per-row tables — is an ARGUMENT of
+    the program, never a closed-over constant: a literal would scale
+    compile time, the size and key of every compile-cache entry and a
+    second HBM copy with the dataset.
     """
 
-    def __init__(self, learner, grad_fn, num_data: int):
+    def __init__(self, learner, objective, num_data: int):
         self._learner = learner
         self._num_data = int(num_data)
+        self._obj_arrays, rebind = objective.split_device_state()
         pad = int(learner._row_pad)
         dtype = learner.dtype
         grow = learner._grow
 
-        def step(X, score, row_mult, feature_mask, scale):
+        def step(X, Xt, obj_arrays, score, row_mult, feature_mask, scale):
             # stage 1: objective gradients in-graph — same ops the staged
             # path dispatches as its own entry (reshape to (1, N) and the
             # [0] slice are identities at k=1, so they are elided)
-            g, h = grad_fn(score)
+            g, h = rebind(obj_arrays).get_gradients(score)
             g = jnp.asarray(g, dtype)
             h = jnp.asarray(h, dtype)
             if pad:
@@ -114,7 +120,10 @@ class FusedIteration:
             # stage 2: the learner's own grow program, inlined — the
             # lax.while_loop over the leaf frontier (hist accumulation,
             # FindBestThreshold, row->leaf partition) never touches host
-            tree, leaf_id = grow(X, g, h, row_mult, feature_mask)
+            if Xt is None:
+                tree, leaf_id = grow(X, g, h, row_mult, feature_mask)
+            else:
+                tree, leaf_id = grow(X, g, h, row_mult, feature_mask, Xt)
             if pad:
                 leaf_id = leaf_id[: self._num_data]
             # stage 3: partition-side score update, shared impl with the
@@ -125,31 +134,40 @@ class FusedIteration:
 
         self._step = jax.jit(step)
 
+    def step_args(self, score, row_mult, feature_mask, scale) -> tuple:
+        """The positional arguments of the jitted step, in order."""
+        lrn = self._learner
+        return (lrn.X, lrn._Xt, self._obj_arrays, score, row_mult,
+                feature_mask, scale)
+
     @classmethod
-    def build(cls, learner, grad_fn, num_data: int, score_dtype):
+    def build(cls, learner, objective, num_data: int, score_dtype):
         """Construct and trace-check the fused program.
 
-        A non-traceable gradient fn (a host-side custom objective that
-        slipped past the bookkeeping checks) fails here, once, cheaply —
-        jax.eval_shape traces without compiling or executing.  Returns
-        None (caller stays staged) instead of raising.
-        """
-        fused = cls(learner, grad_fn, num_data)
+        jax.eval_shape traces without compiling or executing, so a
+        program that cannot trace fails here, once, cheaply.  A built-in
+        objective that does not trace is a bug and raises.  Only a
+        user-defined ObjectiveFunction subclass whose get_gradients runs
+        host code on the score (numpy on a tracer: a JAXTypeError) sends
+        the booster back to the staged chain, with a warning."""
+        fused = cls(learner, objective, num_data)
+        n = int(num_data)
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            fused.step_args(
+                jax.ShapeDtypeStruct((n,), score_dtype), learner._ones,
+                jax.ShapeDtypeStruct(
+                    (max(learner.train_data.num_features, 1),), jnp.bool_),
+                jax.ShapeDtypeStruct((), score_dtype)))
         try:
-            n = int(num_data)
-            jax.eval_shape(
-                fused._step,
-                jax.ShapeDtypeStruct(learner.X.shape, learner.X.dtype)
-                if hasattr(learner.X, "shape") else learner.X,
-                jax.ShapeDtypeStruct((n,), score_dtype),
-                jax.ShapeDtypeStruct(learner._ones.shape, learner.dtype),
-                jax.ShapeDtypeStruct((max(
-                    learner.train_data.num_features, 1),), jnp.bool_),
-                jax.ShapeDtypeStruct((), score_dtype))
-        except Exception as e:          # objective not traceable
-            Log.warning("tpu_fused_iter: objective does not trace into "
+            jax.eval_shape(fused._step, *shapes)
+        except jax.errors.JAXTypeError as e:
+            from ..objectives import ObjectiveFunction
+            if type(objective).__module__ == ObjectiveFunction.__module__:
+                raise
+            Log.warning("tpu_fused_iter: objective %s does not trace into "
                         "the fused program (%s); using the staged "
-                        "iteration chain", e)
+                        "iteration chain", type(objective).__name__, e)
             return None
         return fused
 
@@ -168,10 +186,10 @@ class FusedIteration:
         if feature_mask is None:
             feature_mask = lrn.sample_feature_mask()
         obs = lrn._obs
-        args = (lrn.X, score, row_mult, feature_mask, scale)
+        args = self.step_args(score, row_mult, feature_mask, scale)
         obs.entry_args("fused_iter", self._step, args,
-                       names=("X", "score", "row_mult", "feature_mask",
-                              "scale"))
+                       names=("X", "Xt", "objective", "score", "row_mult",
+                              "feature_mask", "scale"))
         t0 = obs.entry_start()
         tree, leaf_id, new_score = self._step(*args)
         obs.entry_end("fused_iter", t0, (tree, leaf_id, new_score))

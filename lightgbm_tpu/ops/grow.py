@@ -105,18 +105,34 @@ def feature_hist_view(ghist, sums, meta, bundle, has_bundle: bool,
 
 
 def pvary_for(x, axis: str):
-    """Mark x shard-varying over `axis` under shard_map (VMA rules),
-    across jax versions (pcast is the newer spelling of pvary).  jax
-    lines old enough to have neither primitive predate the VMA checker
-    entirely, so the cast is a no-op there."""
-    try:
-        return lax.pcast(x, (axis,), to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return lax.pvary(x, (axis,))
-    except AttributeError:
-        return x
+    """Mark x shard-varying over `axis` under shard_map (VMA rules)."""
+    return lax.pcast(x, (axis,), to="varying")
+
+
+def _vma_of(*operands):
+    """The mesh axes any operand varies over under shard_map (empty
+    outside it)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def vma_struct(shape, dtype, *operands):
+    """``out_shape`` entry for a pallas_call computed from `operands`.
+
+    Under shard_map's varying-axes check a pallas_call must declare how
+    each output varies over the mesh: it varies over every axis any of
+    its operands varies over."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=_vma_of(*operands))
+
+
+def vary_like(init, *operands):
+    """`init` marked varying over every axis any operand varies over.
+
+    A scan carry must enter the loop with the type it leaves with: a
+    zeros accumulator that the body adds shard-local sums into has to
+    start out shard-varying too (the chunk scans only loop when a shard
+    holds more rows than one chunk, i.e. at real sizes)."""
+    vma = _vma_of(*operands)
+    return lax.pcast(init, tuple(vma), to="varying") if vma else init
 
 
 def default_row_capacities(n: int, min_capacity: int = 2048,

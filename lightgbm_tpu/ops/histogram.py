@@ -128,7 +128,8 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
     if nchunks == 1:
         hist, _ = step(init, (xb[0], wb[0]))
         return hist
-    hist, _ = lax.scan(step, init, (xb, wb))
+    from .grow import vary_like
+    hist, _ = lax.scan(step, vary_like(init, xb, wb), (xb, wb))
     return hist
 
 

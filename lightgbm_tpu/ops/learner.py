@@ -573,6 +573,9 @@ class SerialTreeLearner:
             if hist_mode not in ("pallas", "sparse",
                                  "sparse_mxu") + WAVE_ONLY_MODES
             else ())
+        # per-booster transposed bin matrix for the transposed Pallas
+        # kernels; passed to the grow program after feature_mask
+        self._Xt = None
         # distributed learners (psum_axis set) own their grow construction
         # in parallel/mesh.py — including the wave-vs-voting choice
         if growth == "wave" and psum_axis is None:
@@ -591,23 +594,25 @@ class SerialTreeLearner:
             # booster (X never changes across trees), not per dispatch;
             # the shared predicate keeps this in lockstep with the engine
             # gate so no dead (F, N) copy is pinned when the kernel won't
-            # run
+            # run.  It is a call ARGUMENT of the grow program (as X is),
+            # never a closed-over constant: a dataset-sized literal inside
+            # the program would scale compile time, every compile-cache
+            # entry and a second HBM copy with the dataset
             from .wave import transposed_wave_active
-            xt = (jnp.transpose(self.X)
-                  if transposed_wave_active(hist_mode, self.dtype)
-                  else None)
+            if transposed_wave_active(hist_mode, self.dtype):
+                self._Xt = jnp.transpose(self.X)
 
-            def _grow(X, g, h, rm, m, _core=core, _meta=meta,
-                      _bund=bund, _xt=xt):
-                return _core(X, g, h, rm, m, _meta, _bund, Xt=_xt)
+            def _grow(X, g, h, rm, m, Xt=None, _core=core, _meta=meta,
+                      _bund=bund):
+                return _core(X, g, h, rm, m, _meta, _bund, Xt=Xt)
 
             # AOT hook for obs compile attribution: the wrapper itself is
             # not jitted, so expose the core's lowering over the observed
             # call args (obs/compile.py analyze_compiled)
             _grow._aot_lower = (
-                lambda X, g, h, rm, m, _core=core, _meta=meta,
-                _bund=bund, _xt=xt:
-                _core.lower(X, g, h, rm, m, _meta, _bund, Xt=_xt))
+                lambda X, g, h, rm, m, Xt=None, _core=core, _meta=meta,
+                _bund=bund:
+                _core.lower(X, g, h, rm, m, _meta, _bund, Xt=Xt))
             self._grow = _grow
         elif psum_axis is None:
             # cached jitted core: a second booster/fold with the same
@@ -700,14 +705,16 @@ class SerialTreeLearner:
             score0 = jnp.zeros((n,), self.dtype)
             scale = jnp.asarray(0.1, self.dtype)
 
-            def _grad(score):
+            def _grad(score, tgt):
                 return score - tgt, jnp.full((n,), 0.25, self.dtype)
 
             if cell.fused:
-                def _step(score):
-                    g, h = _grad(score)
-                    tree, leaf_id = core(self.X, g, h, rm, mask, meta,
-                                         bund, Xt=xt)
+                # every dataset-sized array is a program argument, as in
+                # ops/fused_iter.py
+                def _step(X, Xt, rm, tgt, score):
+                    g, h = _grad(score, tgt)
+                    tree, leaf_id = core(X, g, h, rm, mask, meta,
+                                         bund, Xt=Xt)
                     return score_update_impl(score, leaf_id,
                                              tree.leaf_value, scale)
 
@@ -720,7 +727,7 @@ class SerialTreeLearner:
                     # asserts a zero fence-count delta); every probe
                     # sync goes through obs/timers.fence so that audit
                     # has a single counted choke point.
-                    fence(step(score0))
+                    fence(step(self.X, xt, rm, tgt, score0))
             else:
                 grad_fn = jax.jit(_grad)
                 upd = jax.jit(score_update_impl)
@@ -729,7 +736,7 @@ class SerialTreeLearner:
                     # the staged chain the booster submits: three
                     # separate dispatches with the host glue between
                     # them inside the timed window
-                    g, h = grad_fn(score0)
+                    g, h = grad_fn(score0, tgt)
                     tree, leaf_id = core(self.X, g, h, rm, mask, meta,
                                          bund, Xt=xt)
                     fence(upd(score0, leaf_id, tree.leaf_value, scale))
@@ -785,6 +792,15 @@ class SerialTreeLearner:
         return jnp.asarray(mask)
 
     # ----------------------------------------------------------------- train
+    def grow_args(self, grad, hess, row_mult, feature_mask) -> tuple:
+        """Positional arguments of ``self._grow``: the device bin matrix,
+        the per-row vectors, the feature mask and, where a transposed
+        Pallas kernel runs, the per-booster Xt."""
+        args = (self.X, grad, hess, row_mult, feature_mask)
+        if self._Xt is not None:
+            args += (self._Xt,)
+        return args
+
     def train_device(self, grad, hess, row_mult=None,
                      feature_mask=None) -> Tuple[TreeArrays, jnp.ndarray]:
         """Grow one tree fully on device; no host synchronization."""
@@ -805,10 +821,10 @@ class SerialTreeLearner:
             hess = jnp.concatenate(
                 [hess, jnp.zeros(self._row_pad, self.dtype)])
         obs = self._obs
-        args = (self.X, grad, hess, row_mult, feature_mask)
+        args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs.entry_args("tree_grow", self._grow, args,
                        names=("X", "grad", "hess", "row_mult",
-                              "feature_mask"))
+                              "feature_mask", "Xt")[:len(args)])
         t0 = obs.entry_start()
         tree, leaf_id = self._grow(*args)
         obs.entry_end("tree_grow", t0, (tree, leaf_id))

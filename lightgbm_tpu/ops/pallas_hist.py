@@ -43,12 +43,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    HAS_PALLAS = True
-except ImportError:                                    # pragma: no cover
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl
+
+from .grow import vma_struct
 
 # the one-hot tile + resident accumulator must fit here; the lanes
 # (row-chunk) axis of the block can shrink no further than the TPU's
@@ -155,7 +152,7 @@ def _hist_pallas(xt, w, num_bins: int, interpret: bool):
             pl.BlockSpec((3, row_chunk), lambda i, c: (0, c)),
         ],
         out_specs=pl.BlockSpec((f_blk, num_bins, 3), lambda i, c: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((f, num_bins, 3), jnp.float32),
+        out_shape=vma_struct((f, num_bins, 3), jnp.float32, xt, w),
         interpret=interpret,
     )(xt, w)
 

@@ -34,12 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernels run (compiled or interpreted) across the jax versions we see
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
+from .grow import vma_struct
 from .wave import WAVE_ONLY_MODES, _bin_pad  # noqa: F401  (shared policy
 # lives in wave.py, which stays importable without jax.experimental.pallas)
 
@@ -296,6 +291,7 @@ def wave_histogram_pallas(X, leaf_id, w3, child_id, num_bins: int,
     kernel = functools.partial(_wave_hist_kernel, bp=bp, fc=fc, k=k,
                                bsub=bsub, packed=bool(logical_cols),
                                hilo=hilo)
+    operands = (X, lid2, w3t, child_id[:, None])
     flat = pl.pallas_call(
         kernel,
         grid=(nch,),
@@ -311,11 +307,11 @@ def wave_histogram_pallas(X, leaf_id, w3, child_id, num_bins: int,
         ],
         out_specs=pl.BlockSpec((fc * bp, 3 * k), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((fc * bp, 3 * k), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=vma_struct((fc * bp, 3 * k), jnp.float32, *operands),
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(X, lid2, w3t, child_id[:, None])
+    )(*operands)
     # (Bp*Fc, 3K) bin-major rows, channel-major cols -> (K, Fc, B, 3)
     h = flat.reshape(bp, fc, 3, k)[:num_bins]
     return jnp.transpose(h, (3, 1, 0, 2))
@@ -388,6 +384,7 @@ def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
     kernel = functools.partial(_wave_hist_kernel_t, bp=bp, fc=fc, k=k,
                                bsub=bsub, packed=bool(logical_cols),
                                hilo=hilo)
+    operands = (X_t, lid2, w3t, child_id[:, None])
     flat = pl.pallas_call(
         kernel,
         grid=(nch,),
@@ -403,11 +400,11 @@ def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
         ],
         out_specs=pl.BlockSpec((fc * bp, 3 * k), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((fc * bp, 3 * k), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=vma_struct((fc * bp, 3 * k), jnp.float32, *operands),
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(X_t, lid2, w3t, child_id[:, None])
+    )(*operands)
     h = flat.reshape(bp, fc, 3, k)[:num_bins]
     return jnp.transpose(h, (3, 1, 0, 2))
 
@@ -539,6 +536,7 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
     kernel = functools.partial(_wave_fused_kernel_ct, bp=bp, fc=fc, k=k,
                                bsub=bsub, packed=bool(logical_cols),
                                bundled=bundled, hilo=hilo)
+    operands = (X_t, lid2, w3t, child_id[:, None], tblt, psrc[:, None])
     newlid, flat = pl.pallas_call(
         kernel,
         grid=(nch,),
@@ -563,12 +561,12 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, n + pad), jnp.int32),
-            jax.ShapeDtypeStruct((fc * bp, 3 * k), jnp.float32),
+            vma_struct((1, n + pad), jnp.int32, *operands),
+            vma_struct((fc * bp, 3 * k), jnp.float32, *operands),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(X_t, lid2, w3t, child_id[:, None], tblt, psrc[:, None])
+    )(*operands)
     h = flat.reshape(bp, fc, 3, k)[:num_bins]
     return newlid[0, :n], jnp.transpose(h, (3, 1, 0, 2))

@@ -160,6 +160,7 @@ def _update_score_pallas(score, leaf_id, vals, interpret=False):
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from .grow import vma_struct
     n = score.shape[0]
     L = int(vals.shape[0])
     c = 4096
@@ -172,6 +173,7 @@ def _update_score_pallas(score, leaf_id, vals, interpret=False):
     l2 = (jnp.pad(leaf_id, (0, npad)) if npad else leaf_id).reshape(8, -1)
     m = s2.shape[1]
     kernel = functools.partial(_score_update_kernel, L=L)
+    operands = (vals[None, :].astype(jnp.float32), l2, s2)
     out = pl.pallas_call(
         kernel,
         grid=(m // c,),
@@ -184,9 +186,9 @@ def _update_score_pallas(score, leaf_id, vals, interpret=False):
         ],
         out_specs=pl.BlockSpec((8, c), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(s2.shape, score.dtype),
+        out_shape=vma_struct(s2.shape, score.dtype, *operands),
         interpret=interpret,
-    )(vals[None, :].astype(jnp.float32), l2, s2)
+    )(*operands)
     return out.reshape(-1)[:n]
 
 
@@ -204,10 +206,15 @@ def update_score_from_partition(score, leaf_id, leaf_value, scale,
     f32-only: with tpu_use_dp=true the score/leaf values are f64 and the
     kernel's f32 table cast would break the bit-equality claim (and f64
     VMEM blocks don't lower on TPU) — those configs use the gather.
+    One device only: outside shard_map a Mosaic kernel cannot be
+    partitioned automatically ("Mosaic kernels cannot be automatically
+    partitioned" at lowering), so the mesh learners' row-sharded
+    leaf_id / score take the gather, which XLA partitions by itself.
     """
     if (engine == "pallas" and jax.default_backend() == "tpu"
             and leaf_value.shape[0] <= 512
-            and score.dtype == jnp.float32):
+            and score.dtype == jnp.float32
+            and len(score.devices() | leaf_id.devices()) == 1):
         vals = jnp.clip(leaf_value * scale, -kMaxTreeOutput,
                         kMaxTreeOutput)
         return _update_score_pallas(score, leaf_id, vals)
@@ -473,8 +480,7 @@ def _sharded_predict_ctx(rp: "RankedPredictor", num_class: int, devices):
     on the RankedPredictor so the chunk loop pays one model broadcast
     per predict call, not one per chunk."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ..parallel.mesh import (DATA_AXIS, _shard_map_compat,
-                                 make_data_mesh)
+    from ..parallel.mesh import DATA_AXIS, make_data_mesh
 
     key = (tuple(devices), num_class)
     cached = getattr(rp, "_shard_ctx", None)
@@ -493,15 +499,10 @@ def _sharded_predict_ctx(rp: "RankedPredictor", num_class: int, devices):
         return _ranked_predict_impl(dev_, V_, D_, num_class,
                                     vary_axis=DATA_AXIS)
 
-    # jax lines without pcast/pvary have no replication rule for the
-    # traversal while_loop either — the checker cannot run there, and
-    # the unchecked form is safe (outputs are row-sharded by
-    # construction, no cross-shard reductions anywhere)
-    checked = hasattr(lax, "pcast") or hasattr(lax, "pvary")
-    fn = jax.jit(_shard_map_compat(
-        _local, mesh,
+    fn = jax.jit(jax.shard_map(
+        _local, mesh=mesh,
         in_specs=(P(), P(DATA_AXIS, None), P(DATA_AXIS, None)),
-        out_specs=P(DATA_AXIS, None), checked=checked))
+        out_specs=P(DATA_AXIS, None)))
     ctx = (rows_sh, dev_repl, fn)
     rp._shard_ctx = (key, ctx)
     return ctx

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .grow import vma_struct
 from .wave import _bin_pad
 
 ENTRY_CHUNK = 512     # entries per chunk (kernel lanes dim)
@@ -235,6 +236,8 @@ def sparse_wave_histogram_mxu(store: ChunkedSparseStore, leaf_id, w3,
     g_e, h_e, m_e = entry_weights
 
     kernel = functools.partial(_chunk_hist_kernel, bp=bp, gc=gc, hilo=hilo)
+    operands = (store.ent_bin, lid_e, g_e, h_e, m_e, child_id[:, None],
+                store.chunk_col)
     flat = pl.pallas_call(
         kernel,
         grid=(nc // gc,),
@@ -256,16 +259,12 @@ def sparse_wave_histogram_mxu(store: ChunkedSparseStore, leaf_id, w3,
         ],
         out_specs=pl.BlockSpec((num_cols * bp, 3 * k), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((num_cols * bp, 3 * k),
-                                       jnp.float32),
-        # jax renamed TPUCompilerParams -> CompilerParams; accept either
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams",
-                                        None))(
+        out_shape=vma_struct((num_cols * bp, 3 * k), jnp.float32,
+                             *operands),
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(store.ent_bin, lid_e, g_e, h_e, m_e, child_id[:, None],
-      store.chunk_col)
+    )(*operands)
     h = flat.reshape(num_cols, bp, 3, k)[:, :num_bins]
     return jnp.transpose(h, (3, 0, 1, 2))
 

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .grow import TreeArrays, feature_hist_view, pvary_for
+from .grow import TreeArrays, feature_hist_view, pvary_for, vary_like
 from .histogram import leaf_histogram_onehot, leaf_histogram_scatter
 from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
                            LEFT_COUNT, LEFT_OUTPUT, LEFT_SUM_G, LEFT_SUM_H,
@@ -445,6 +445,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 flat, lid2 = step(init, (xb[0], lb[0], wb3[0]))
                 new_leaf_id = lid2[:n]
             else:
+                if not use_pallas_hist:
+                    init = vary_like(init, xb, lb, wb3)
                 flat, lid2 = lax.scan(step, init, (xb, lb, wb3))
                 new_leaf_id = lid2.reshape(-1)[:n]
             if use_pallas_hist:
@@ -581,7 +583,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             if nch == 1:
                 flat, _ = step(init, (xb[0], lb[0], wb3[0]))
             else:
-                flat, _ = lax.scan(step, init, (xb, lb, wb3))
+                flat, _ = lax.scan(step, vary_like(init, xb, lb, wb3),
+                                   (xb, lb, wb3))
             return flat.reshape(Fc, hist_bins, W, 3).transpose(2, 0, 1, 3)
 
         def best_of_many(hists_k, sums_k, depths_k, feature_mask, meta,

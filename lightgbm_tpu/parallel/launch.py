@@ -17,6 +17,12 @@ process boundary), calls ``fn(comm, payload)`` and prints its
 JSON-serializable return as a final ``MPRESULT {...}`` line.  The PR-4
 ``LGBM_MP_*`` fault hooks ride through the inherited environment.
 
+This launcher is a CPU fixture for ``jax.distributed`` and cannot reach
+a chip: every rank is forced onto the CPU platform (``_worker_env``,
+``_child``), and a chip belongs to one process at a time anyway.  On a
+four-chip host the supported layout is ONE process driving the four
+devices (``tree_learner=data`` under ``lgb.train``, parallel/mesh.py).
+
 jaxlib's CPU client only grew cross-process collectives in some builds;
 on hosts without them workers die with "Multiprocess computations
 aren't implemented" and the launcher raises ``MultiprocessUnsupported``
@@ -42,10 +48,6 @@ REPO_ROOT = os.path.dirname(os.path.dirname(HERE))
 _UNSUPPORTED_MARKERS = (
     "Multiprocess computations aren't implemented",
     "multiprocess computations aren't implemented",
-    # older shard_map cannot trace the mesh grow programs' while loops
-    # on CPU (the same jaxlib limit tests/test_parallel.py carries at
-    # the seed) — an environment limit of the runner, not a code bug
-    "No replication rule for while",
 )
 
 DEFAULT_WORKER_TIMEOUT = 540.0

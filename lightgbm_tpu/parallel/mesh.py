@@ -30,10 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..io.dataset import TrainingData
 from ..ops.grow import make_grow_fn
@@ -57,28 +53,6 @@ def make_feature_mesh(devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     # Device HANDLES (host metadata), not a device array — no transfer
     return Mesh(np.asarray(devices), (FEATURE_AXIS,))  # lint: ignore[sync-asarray]
-
-
-def _shard_map_compat(fn, mesh, in_specs, out_specs, checked=True):
-    """shard_map across jax versions (check_rep renamed check_vma, removed).
-
-    checked=False disables the varying-manual-axes checker: the
-    feature-parallel grower's all_gather'd SplitInfo fold is replicated by
-    construction but the VMA analysis cannot prove it.
-    """
-    if not checked:
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def pad_rows(n: int, num_shards: int) -> int:
@@ -230,7 +204,6 @@ class DataParallelTreeLearner(SerialTreeLearner):
         caps = (default_row_capacities(local_rows)
                 if self.row_capacities else ())   # same gate, per-shard rows
         voting = bool(self._grow_kwargs(n_shards).get("voting_k", 0))
-        self._Xt = None
         if self.growth == "wave" and not voting:
             # wave schedule under the data mesh: the per-wave histogram
             # block is psum'd ONCE (W splits per collective instead of one)
@@ -288,7 +261,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
                     P(DATA_AXIS), P())
         if self._Xt is not None:
             in_specs += (P(None, DATA_AXIS),)
-        sharded_grow = _shard_map_compat(
+        sharded_grow = jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=in_specs,
             out_specs=(jax.tree_util.tree_map(lambda _: P(),
@@ -380,9 +353,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
             row_mult = self._pad_rows_dev(row_mult)
         if feature_mask is None:
             feature_mask = self.sample_feature_mask()
-        args = (self.X, grad, hess, row_mult, feature_mask)
-        if self._Xt is not None:
-            args += (self._Xt,)
+        args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs = self._obs
         obs.entry_args("tree_grow", self._grow, args,
                        names=("X", "grad", "hess", "row_mult",
@@ -477,10 +448,12 @@ class FeatureParallelTreeLearner(SerialTreeLearner):
         from ..ops.grow import TreeArrays
         tree_specs = jax.tree_util.tree_map(
             lambda _: P(), TreeArrays(*([0] * len(TreeArrays._fields))))
-        sharded_grow = _shard_map_compat(
+        # the all_gather'd SplitInfo fold is replicated by construction
+        # but the varying-axes analysis cannot prove it
+        sharded_grow = jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(None, FEATURE_AXIS), P(), P(), P(), P()),
-            out_specs=(tree_specs, P()), checked=False)
+            out_specs=(tree_specs, P()), check_vma=False)
         self._grow = jax.jit(sharded_grow)
         Log.info("Feature-parallel learner over %d devices "
                  "(%d padded features)", n_shards, fpad)

@@ -184,8 +184,7 @@ class PredictExecutableCache:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self._dev)
         if self._mesh_ctx is not None:
             from jax.sharding import PartitionSpec as P
-            from jax import lax
-            from ..parallel.mesh import DATA_AXIS, _shard_map_compat
+            from ..parallel.mesh import DATA_AXIS
             mesh, repl, rows_sh = self._mesh_ctx
 
             def local(dev, V, D):
@@ -199,11 +198,10 @@ class PredictExecutableCache:
                         score = jax.nn.softmax(score, axis=-1)
                 return score
 
-            checked = hasattr(lax, "pcast") or hasattr(lax, "pvary")
-            fn = jax.jit(_shard_map_compat(
-                local, mesh,
+            fn = jax.jit(jax.shard_map(
+                local, mesh=mesh,
                 in_specs=(P(), P(DATA_AXIS, None), P(DATA_AXIS, None)),
-                out_specs=P(DATA_AXIS, None), checked=checked),
+                out_specs=P(DATA_AXIS, None)),
                 donate_argnums=donate)
             dev_avals = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,

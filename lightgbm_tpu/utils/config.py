@@ -575,8 +575,8 @@ class Config:
         # compaction) cells for the shape bucket on-device and persist
         # the winner.  force = always re-probe, overwriting the cache.
         "tpu_autotune": ("str", "off"),
-        # autotune cache file; empty = autotune_cache.json next to the
-        # XLA compile cache (LGBM_TPU_COMPILE_CACHE, utils/common.py)
+        # autotune cache file; empty = autotune_cache.json in the XLA
+        # compile-cache directory (utils/common.py compilation_cache_dir)
         "tpu_autotune_cache": ("str", ""),
         # timed waves per probed cell (compile + one warmup wave are
         # always excluded from the timing window)

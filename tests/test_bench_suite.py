@@ -56,69 +56,3 @@ def test_suite_shapes_are_process_stable(monkeypatch):
     np.testing.assert_array_equal(X, Xe)
     ye = ((Xe @ w * 0.4 + 0.6 * rng.normal(size=2000)) > 0)
     np.testing.assert_array_equal(y, ye.astype(np.float64))
-
-
-def _run_main(monkeypatch, tmp_path, probe_results, child_behavior=None,
-              names=("alpha", "beta"), deadline="30"):
-    """Drive bench_suite.main() with a scripted probe and child."""
-    import subprocess
-    import sys
-    import time
-
-    import tools.bench_suite as bs
-    import tools.tpu_ab2 as ab2
-
-    for name in names:
-        monkeypatch.setitem(bs.SHAPES, name, dict(n=100, f=2, params={},
-                                                  warmup=0, measured=1,
-                                                  timeout=5))
-    out = tmp_path / "results.md"
-    monkeypatch.setattr(bs, "OUT", str(out))
-    monkeypatch.setenv("SUITE_DEADLINE_S", deadline)
-    # fail fast on exhaustion: sleep is a no-op here, so a regression
-    # that consumes probes off-pattern must raise, not hot-spin until
-    # the deadline
-    seq = iter(probe_results)
-    monkeypatch.setattr(ab2, "probe_with_retries", lambda: next(seq))
-    monkeypatch.setattr(time, "sleep", lambda s: None)
-
-    calls = []
-
-    def fake_run(args, **kw):
-        name = args[-1]
-        calls.append(name)
-        if child_behavior == "timeout":
-            raise subprocess.TimeoutExpired(args, 5)
-        r = subprocess.CompletedProcess(args, 0)
-        r.stdout = ('{"dt": 0.5, "metric": 0.9, "mode": "onehot", '
-                    '"growth": "wave", "order": "batched", "W": 8}')
-        r.stderr = ""
-        return r
-    monkeypatch.setattr(bs.subprocess, "run", fake_run)
-    monkeypatch.setattr(sys, "argv", ["bench_suite.py"] + list(names))
-    bs.main()
-    return out.read_text(), calls
-
-
-def test_suite_non_tpu_backend_counts_as_unreachable(monkeypatch,
-                                                     tmp_path):
-    """A transient CPU fallback must NOT start a measurement, and the
-    outage line names the backend; one line per outage (dedup)."""
-    monkeypatch.delenv("SUITE_ALLOW_CPU", raising=False)
-    text, calls = _run_main(monkeypatch, tmp_path,
-                            probe_results=["cpu", "cpu", None, None,
-                                           "tpu", "tpu"])
-    assert calls == ["alpha", "beta"]          # only after tpu came up
-    assert text.count("non-tpu backend 'cpu'") == 1      # deduped
-    assert "device back after" in text
-
-
-def test_suite_timeout_gives_up_after_two(monkeypatch, tmp_path):
-    """A deterministically-hanging shape re-queues once, then gives up
-    instead of starving the shapes behind it."""
-    text, calls = _run_main(monkeypatch, tmp_path,
-                            probe_results=["tpu"] * 10,
-                            child_behavior="timeout",
-                            names=("alpha",))
-    assert calls == ["alpha", "alpha"]         # exactly two attempts
-    assert "TIMEOUT x2" in text and "giving up" in text

@@ -228,6 +228,38 @@ def test_score_update_pallas_bit_equal():
         assert np.array_equal(np.asarray(want), np.asarray(got)), (n, L)
 
 
+def test_score_update_pallas_needs_one_device(monkeypatch):
+    """A Mosaic kernel outside shard_map cannot be partitioned: with the
+    row->leaf map sharded over a mesh (the data-parallel learner on real
+    chips) the dispatch must take the XLA gather.  Found on the way to
+    the first four-chip run: lowered for tpu with a sharded leaf_id the
+    kernel raises NotImplementedError."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.ops import predict as dev_predict
+    from lightgbm_tpu.parallel.mesh import DATA_AXIS, make_data_mesh
+    n, L = 4096, 31
+    rng = np.random.default_rng(12)
+    score = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    lv = jnp.asarray(rng.normal(size=L).astype(np.float32))
+    lid = rng.integers(0, L, size=n).astype(np.int32)
+    sharded = jax.device_put(
+        lid, NamedSharding(make_data_mesh(jax.devices()[:4]), P(DATA_AXIS)))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        dev_predict._update_score_pallas.trace(score, sharded, lv).lower(
+            lowering_platforms=("tpu",))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        dev_predict, "_update_score_pallas",
+        lambda *a, **k: pytest.fail("pallas engine on a sharded leaf_id"))
+    got = dev_predict.update_score_from_partition(
+        score, sharded, lv, jnp.float32(0.1), engine="pallas")
+    want = dev_predict._update_score_gather(score, jnp.asarray(lid), lv,
+                                            jnp.float32(0.1))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_score_update_engine_validation():
     rng = np.random.default_rng(3)
     X = rng.normal(size=(300, 4))

@@ -189,3 +189,91 @@ def test_band_probe_reproduces_and_fixes_the_degeneracy():
     assert rep["live_new"] <= rep["overlap_window"] < rep["live_old"]
     chunked = tile_plan_vmem_report(1 << 20, 968, 64, 64)
     assert chunked["chunked_rmw"] and not chunked["pathological_old"]
+
+
+# ------------------------------------------- dataset arrays are arguments
+
+def test_fused_step_lowered_for_tpu_holds_no_dataset_literal(monkeypatch):
+    """Every dataset-sized array (X, Xt, labels, weights) is an ARGUMENT
+    of the fused program.  Lowered for tpu at 20,000 x 28 the module is
+    under 1 MB of text; with Xt and the labels baked in as literals it
+    was 1.6 MB here and grew with the row count (13.1 MB at 200,000)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.wave import make_wave_core, make_wave_jit
+
+    n, f = 20_000, 28
+    X, y = _xy(n, f, 3)
+    w = np.random.default_rng(4).uniform(0.5, 2.0, n).astype(np.float32)
+    p = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+         "min_data_in_leaf": 1, "verbose": -1}
+    make_wave_core.cache_clear(); make_wave_jit.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        bst = lgb.Booster(params=p, train_set=lgb.Dataset(
+            X, label=y, weight=w, params=p))
+        gbdt = bst._gbdt
+        fused = gbdt._resolve_fused_iter()
+        assert fused is not None and gbdt.learner.hist_mode == "pallas_ct"
+        assert gbdt.learner._Xt is not None
+        args = fused.step_args(gbdt._score_dev[0], gbdt.learner._ones,
+                               gbdt.learner._full_mask,
+                               jnp.asarray(0.1, gbdt.score_dtype))
+        # X, Xt, sign, label_weight, label, weights, score, row_mult
+        big = [a for a in jax.tree_util.tree_leaves(args) if a.size >= n]
+        assert len(big) == 8
+        text = fused._step.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        monkeypatch.undo()
+        make_wave_core.cache_clear(); make_wave_jit.cache_clear()
+    assert len(text) < 1_000_000, len(text)
+
+
+def test_fused_matches_staged_with_nested_objective_state():
+    """lambdarank keeps its per-query tables as a list of dicts of device
+    arrays next to plain ints; they all ride into the fused program as
+    arguments and the trees stay those of the staged chain."""
+    rng = np.random.default_rng(5)
+    n, f = 300, 6
+    X = rng.standard_normal((n, f)).astype(np.float32)
+    y = np.clip(np.round(X[:, 0] + 2), 0, 4).astype(np.float32)
+    group = np.full(n // 20, 20)
+    p = {"objective": "lambdarank", "num_leaves": 7, "verbose": -1,
+         "min_data_in_leaf": 5}
+    boosters = []
+    for mode in ("on", "off"):
+        pm = dict(p, tpu_fused_iter=mode)
+        boosters.append(lgb.train(
+            pm, lgb.Dataset(X, label=y, group=group, params=pm),
+            num_boost_round=2))
+    _assert_identical(*boosters, X)
+
+
+def test_fused_build_hides_no_trace_error(monkeypatch):
+    """A built-in objective that fails to trace raises out of the fused
+    build; only a user-defined objective running host code on the score
+    sends the booster back to the staged chain."""
+    import jax
+    from lightgbm_tpu import objectives
+    from lightgbm_tpu.ops.fused_iter import FusedIteration
+
+    def host_gradients(self, score):
+        return np.asarray(score) - 1.0, np.ones(len(score))
+
+    class HostObjective(objectives.RegressionL2loss):
+        get_gradients = host_gradients
+
+    X, y = _xy(300, 6, 6, classification=False)
+    p = {"objective": "regression", "num_leaves": 7, "verbose": -1}
+    gbdt = lgb.Booster(params=p,
+                       train_set=lgb.Dataset(X, label=y, params=p))._gbdt
+    host = HostObjective()
+    host.init(gbdt.train_data.metadata, gbdt.num_data)
+    assert FusedIteration.build(gbdt.learner, host, gbdt.num_data,
+                                gbdt.score_dtype) is None
+    monkeypatch.setattr(objectives.RegressionL2loss, "get_gradients",
+                        host_gradients)
+    with pytest.raises(jax.errors.TracerArrayConversionError):
+        FusedIteration.build(gbdt.learner, gbdt.objective, gbdt.num_data,
+                             gbdt.score_dtype)

@@ -4,8 +4,8 @@ Covers:
   * run_header provenance (schema 10): emitted by RunObserver, required
     by strict validation for new-schema headers, absent-but-valid on
     old-schema records;
-  * ingest — record shape, idempotent re-ingest (events, timelines and
-    the backfill tool), the comparability key (suite/shape/device);
+  * ingest — record shape, idempotent re-ingest (events and
+    timelines), the comparability key (suite/shape/device);
   * crash-safety — corrupt index lines are skipped and the full run
     records under runs/ recover history the index lost;
   * rolling statistics — median/MAD with the noise floor, thin-history
@@ -476,24 +476,6 @@ def test_rolling_mode_missing_ledger_is_thin_not_fatal(tmp_path):
     base = _candidate_timeline(tmp_path / "base.jsonl", 5.0)
     assert bc.main([base, base, "--baseline", "rolling", "--ledger",
                     str(tmp_path / "nothing")]) == 0
-
-
-# -------------------------------------------------------------- backfill
-
-def test_ledger_backfill_is_idempotent(tmp_path):
-    spec = importlib.util.spec_from_file_location(
-        "ledger_backfill",
-        os.path.join(REPO, "tools", "ledger_backfill.py"))
-    bf = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bf)
-    led_dir = str(tmp_path / "led")
-    assert bf.main(["--ledger", led_dir]) == 0
-    n = len(Ledger(led_dir).entries())
-    assert n >= 10            # 5 bench + 5 multichip rounds minimum
-    assert bf.main(["--ledger", led_dir]) == 0
-    assert len(Ledger(led_dir).entries()) == n
-    suites = {r["suite"] for r in Ledger(led_dir).entries()}
-    assert suites >= {"flagship", "multichip"}
 
 
 def test_observer_ingests_on_clean_close(tmp_path):

@@ -1,7 +1,7 @@
 """Roofline attribution (obs/roofline.py, schema 13).
 
 Covers the device-peak registry (table lookup, alias/prefix resolution,
-the unknown-kind CPU fallback, JSON overrides), the per-entry roofline
+the unknown-kind error, JSON overrides), the per-entry roofline
 join and its bound classification edges (compute / memory / collective /
 host-orchestration, the ORCH_FLOOR regime), the per-iteration
 ``utilization`` rollup math and its end-to-end emission from a real
@@ -40,7 +40,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 
 # a known-profile peak set for exact-math assertions: 100 GFLOP/s,
-# 25 GB/s HBM, 10 GB/s ICI (the built-in CPU fallback figures)
+# 25 GB/s HBM, 10 GB/s ICI (the built-in cpu row)
 CPU_PEAKS = dict(DEFAULT_PEAKS["cpu"], kind="cpu", source="table")
 
 PROV = {"git_rev": "feedc0ffee12", "git_dirty": False,
@@ -83,17 +83,20 @@ def test_normalize_kind_and_aliases():
     assert normalize_kind("") == ""
 
 
-def test_peaks_exact_prefix_and_fallback():
+def test_peaks_exact_prefix_and_unknown_kind_raises():
     p = peaks_for("TPU v4")
     assert p["kind"] == "tpu_v4" and p["source"] == "table"
     assert p["flops_bf16"] == DEFAULT_PEAKS["tpu_v4"]["flops_bf16"]
+    # the string a v5e chip reports (jax.devices()[0].device_kind)
+    assert peaks_for("TPU v5 lite")["kind"] == "tpu_v5_lite"
     # prefix resolution: a pod-suffixed kind still finds its generation
     assert peaks_for("tpu_v5p_pod")["kind"] == "tpu_v5p"
-    # unknown chip degrades to the labelled CPU fallback, never a crash
-    q = peaks_for("warp_drive_9000")
-    assert q["source"] == "fallback"
-    assert q["flops_f32"] == DEFAULT_PEAKS["cpu"]["flops_f32"]
-    assert peaks_for("")["source"] == "fallback"
+    # the CPU backend keeps its own row for CPU tests
+    assert peaks_for("cpu")["kind"] == "cpu"
+    # a device that is not in the table is an error, not a default
+    for unknown in ("warp_drive_9000", ""):
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            peaks_for(unknown)
     # every profile carries the full field set
     for prof in DEFAULT_PEAKS.values():
         assert set(prof) == {"flops_f32", "flops_bf16", "hbm_bytes_per_s",
@@ -112,10 +115,13 @@ def test_peak_overrides_merge_over_defaults(tmp_path):
     assert p["hbm_bytes_per_s"] == 999e9
     # un-overridden fields keep the table figure (merge, not replace)
     assert p["flops_f32"] == DEFAULT_PEAKS["tpu_v4"]["flops_f32"]
-    q = peaks_for("mychip", ov)
+    # a kind the table lacks has no base profile to merge over: an
+    # override that leaves fields out is an error
+    with pytest.raises(ValueError, match="lack"):
+        peaks_for("mychip", ov)
+    full = {"mychip": dict(DEFAULT_PEAKS["cpu"], flops_f32=1e12)}
+    q = peaks_for("mychip", full)
     assert q["source"] == "override" and q["flops_f32"] == 1e12
-    # unknown chip's remaining fields come from the CPU base profile
-    assert q["hbm_bytes_per_s"] == DEFAULT_PEAKS["cpu"]["hbm_bytes_per_s"]
 
 
 def test_unreadable_overrides_warn_and_disable(tmp_path):
@@ -212,8 +218,7 @@ def test_timeline_roofline_ranks_by_headroom():
     res = timeline_roofline(_timeline())
     assert res["problems"] == []
     assert res["device_kind"] == "cpu"
-    assert res["peaks"]["source"] == "fallback" or \
-        res["peaks"]["kind"] == "cpu"
+    assert res["peaks"]["kind"] == "cpu"
     rows = res["rows"]
     assert [r["entry"] for r in rows] == ["tree_grow", "boost"]
     grow, boost = rows
@@ -305,7 +310,7 @@ def test_utilization_event_emitted_from_training(tmp_path):
         assert u["bound"] in BOUNDS
         assert u["entries"]
         assert all(v["bound"] in BOUNDS for v in u["entries"].values())
-        assert u["roof_source"] in ("table", "override", "fallback")
+        assert u["roof_source"] in ("table", "override")
         assert u["device_kind"]
     # the timeline must also satisfy the CLI gate it feeds in CI
     assert obs_main(["roofline", path, "--check"]) == 0

@@ -5,9 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.ops.histogram import leaf_histogram_scatter
-from lightgbm_tpu.ops.pallas_hist import HAS_PALLAS, leaf_histogram_pallas
-
-pytestmark = pytest.mark.skipif(not HAS_PALLAS, reason="pallas unavailable")
+from lightgbm_tpu.ops.pallas_hist import leaf_histogram_pallas
 
 
 @pytest.mark.parametrize("n,f,B", [(1000, 5, 16), (3000, 13, 63)])

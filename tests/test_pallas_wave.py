@@ -321,6 +321,13 @@ def test_mesh_precomputes_xt_for_transposed_kernels(monkeypatch):
     # column-sharded: each device holds the transpose of its row shard
     spec = dp._Xt.sharding.spec
     assert tuple(spec) == (None, "data")
+    # and the grow program TRACES under shard_map with the varying-axes
+    # check on: each pallas_call declares how its outputs vary over the
+    # mesh (ops/grow.py vma_struct).  Off-TPU the mesh path runs the XLA
+    # engine, so only a faked backend reaches the kernels' out_shape.
+    jaxpr = jax.make_jaxpr(dp._grow)(*dp.grow_args(
+        dp._ones, dp._ones, dp._ones, dp.sample_feature_mask()))
+    assert "pallas_call" in str(jaxpr)
 
     # off-TPU (real backend): no Xt is pinned.  Cache-clear first: the
     # faked-backend cores above share static keys with real-CPU ones.

@@ -54,6 +54,35 @@ def test_data_parallel_tree_matches_serial(data):
     np.testing.assert_array_equal(np.asarray(leaf_d), np.asarray(leaf_s))
 
 
+def test_data_parallel_wave_with_several_chunks_per_shard():
+    """A shard holding more rows than one chunk makes the root one-hot,
+    the wave pass and the no-cache rehist accumulate in a lax.scan, whose
+    zero carry must start out shard-varying (ops/grow.py vary_like).  The
+    default 16,384-row chunk never loops at test sizes; the first
+    four-chip run (500,000 rows a shard) failed to trace here.  A
+    histogram pool too small for the cache puts all three scans in one
+    program."""
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(4096, 8))
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
+              "min_data_in_leaf": 5, "tpu_growth": "wave",
+              "tpu_histogram_mode": "onehot", "tpu_wave_chunk": 256,
+              "histogram_pool_size": 0.001}
+    trees = {}
+    for learner in ("serial", "data"):
+        p = dict(params, tree_learner=learner)
+        bst = lgb.train(p, lgb.Dataset(X, label=y, params=p),
+                        num_boost_round=1)
+        assert not bst._gbdt.learner.cache_hists
+        bst._gbdt._materialize()
+        trees[learner] = bst._gbdt.models[0]
+    assert trees["data"].num_leaves == trees["serial"].num_leaves == 7
+    assert (trees["data"].split_feature[0], trees["data"].threshold_in_bin[0]) \
+        == (trees["serial"].split_feature[0],
+            trees["serial"].threshold_in_bin[0])
+
+
 def test_end_to_end_data_parallel_training(data):
     X, y = data
     train = lgb.Dataset(X, label=y)

@@ -26,12 +26,12 @@ def run(X, y, mode, wave_width=32, warmup=3, measured=10,
     """Time one engine config; X/y are ignored when a prebuilt train_set
     (e.g. loaded from a .bin dataset cache) is passed instead.  The ONE
     copy of the measurement protocol (warmup -> block -> timed loop ->
-    block) — tpu_ab2 and bench_suite both go through it.  details=True
+    block) — bench_suite goes through it.  details=True
     additionally returns the trained GBDT for learner introspection."""
     import jax
     import lightgbm_tpu as lgb
     from lightgbm_tpu.utils.common import enable_compilation_cache
-    enable_compilation_cache()   # wedge retries skip recompiles
+    enable_compilation_cache()
     params = {"objective": "binary", "num_leaves": 255, "max_bin": 63,
               "learning_rate": 0.1, "min_data_in_leaf": 1, "verbose": -1,
               "metric": "auc", "tpu_growth": "wave",

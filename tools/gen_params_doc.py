@@ -251,8 +251,8 @@ NOTES = {
     "obs_roofline_peaks": "JSON file overriding the device-peak "
                           "registry (per device_kind: peak_flops_f32/"
                           "bf16, peak_hbm_bytes, peak_ici_bytes, "
-                          "vmem_bytes); empty = built-in table with "
-                          "CPU fallback",
+                          "vmem_bytes); empty = built-in table (an "
+                          "unknown device_kind is an error)",
     "obs_http_port": "live telemetry plane: serve /metrics, /healthz, "
                      "/statusz and /events?after=N over HTTP from a "
                      "daemon thread for the life of the run (-1 = off, "

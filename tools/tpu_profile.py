@@ -80,10 +80,10 @@ def main():
         n = 999_424                      # unused; the shape sizes itself
         outdir = args[-1] if args else "/tmp/tpu_trace"
 
-    from lightgbm_tpu.utils.common import honor_jax_platforms
-    honor_jax_platforms()
     import jax
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.utils.common import enable_compilation_cache
+    enable_compilation_cache()
 
     if shape is not None:
         from tools.bench_suite import SHAPES, cached_dataset
