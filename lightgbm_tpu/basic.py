@@ -20,6 +20,7 @@ from .metrics import create_metric
 from .models.gbdt import GBDT
 from .models.factory import create_boosting
 from .objectives import create_objective
+from .obs import timers
 from .obs.metrics import observe_predict
 from .utils.config import Config
 from .utils.log import LightGBMError, Log
@@ -183,7 +184,8 @@ class Dataset:
             ref_td = self.reference._handle if self.reference is not None else None
             if TrainingData.can_load_binned(self.data):
                 # pre-binned mmap directory: zero re-binning work
-                self._handle = TrainingData.from_binned(self.data)
+                with timers.span("dataset_open"):
+                    self._handle = TrainingData.from_binned(self.data)
             elif TrainingData.can_load_binary(self.data):
                 self._handle = TrainingData.load_binary(self.data)
             else:
@@ -438,8 +440,9 @@ class Dataset:
         rank-sharded: this process maps only its own row range and the
         dataset trains over the global mesh (docs/Distributed.md)."""
         ds = cls(path, params=params)
-        ds._handle = TrainingData.from_binned(path, comm=comm,
-                                              row_range=row_range)
+        with timers.span("dataset_open"):
+            ds._handle = TrainingData.from_binned(path, comm=comm,
+                                                  row_range=row_range)
         return ds
 
 
@@ -507,9 +510,10 @@ class Booster:
                     m.init(train_set._handle.metadata,
                            train_set._handle.num_data)
                     training_metrics.append(m)
-            self._gbdt = create_boosting(cfg.boosting_type, cfg,
-                                         train_set._handle, objective,
-                                         training_metrics)
+            with timers.span("booster_init"):
+                self._gbdt = create_boosting(cfg.boosting_type, cfg,
+                                             train_set._handle, objective,
+                                             training_metrics)
             self._cfg = cfg
             # continuation: fold loaded models in
             if train_set._predictor is not None:

@@ -36,7 +36,7 @@ import numpy as np
 
 from ..io.dataset import TrainingData
 from ..metrics import Metric
-from ..obs import NULL_OBSERVER, observer_from_config
+from ..obs import NULL_OBSERVER, observer_from_config, timers
 from ..obs.timers import OrchestrationClock, fenced_get
 from ..objectives import ObjectiveFunction, load_objective_from_string
 from ..ops.learner import SerialTreeLearner, materialize_tree
@@ -287,7 +287,8 @@ class GBDT:
         self.train_data = train_data
         self.num_data = train_data.num_data
         from ..parallel.mesh import create_tree_learner
-        self.learner = create_tree_learner(config, train_data)
+        with timers.span("learner_build"):
+            self.learner = create_tree_learner(config, train_data)
         self.score_dtype = self.learner.dtype
         self._resolve_score_engine(config)
         self._reset_observer(config)
@@ -449,6 +450,10 @@ class GBDT:
         pending = [i for i, m in enumerate(self.models) if m is None]
         if not pending:
             return
+        with timers.span("materialize"):
+            self._materialize_pending(pending)
+
+    def _materialize_pending(self, pending) -> None:
         devs = [self._models_dev[i] for i in pending]
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *devs) \
             if len(devs) > 1 else devs[0]
@@ -461,6 +466,12 @@ class GBDT:
             tree.shrink(self._models_shrink[i])
             self.models[i] = tree
             self._models_dev[i] = None
+            # the grow loop's counters came with the tree: one record a
+            # tree, under the iteration that grew it
+            c = dict(zip(timers.COUNTERS, (int(v) for v in ht.counters)))
+            it, tid = self._tree_iteration(i)
+            timers.count("tree", it=it, tree=tid,
+                         rows_visited=(c["waves"] + 1) * c["rows"], **c)
         if self._metrics is not None:
             # host num_leaves is free here — trees just landed on host
             self._metrics["leaves"].inc(
@@ -468,6 +479,14 @@ class GBDT:
         # release device buffers
         self._models_shrink = [0.0 if m is not None else s
                                for m, s in zip(self.models, self._models_shrink)]
+
+    def _tree_iteration(self, i: int) -> tuple:
+        """(iteration of this run, tree within it) that grew
+        ``self.models[i]``: loaded models and the boost-from-average stub
+        come first."""
+        k = max(self.num_tree_per_iteration, 1)
+        first = self.num_init_iteration * k + int(self.boost_from_average_used)
+        return divmod(i - first, k)
 
     def _append_host_tree(self, tree: Tree) -> None:
         self.models.append(tree)
@@ -536,6 +555,10 @@ class GBDT:
     def train_one_iter(self, gradients=None, hessians=None,
                        is_eval: bool = True) -> bool:
         """GBDT::TrainOneIter (gbdt.cpp:339-458); returns True to stop."""
+        with timers.span("iteration", it=self.iter):
+            return self._train_one_iter(gradients, hessians, is_eval)
+
+    def _train_one_iter(self, gradients, hessians, is_eval: bool) -> bool:
         cfg = self.config
         k = self.num_tree_per_iteration
         obs = self._obs
@@ -633,10 +656,11 @@ class GBDT:
                     dev_tree, leaf_id, new_score = fused.run(
                         self._score_dev[tid], self.row_mult, None,
                         jnp.asarray(self.shrinkage_rate, self.score_dtype))
-                    obs.lap("grow", leaf_id)
                     self._score_dev = self._score_dev.at[tid].set(new_score)
                     self._invalidate_train()
-                    obs.lap("partition", self._score_dev)
+                    # one dispatch, one lap: gradients, grow and the score
+                    # update are one program and have no host boundary
+                    obs.lap("step", self._score_dev)
                     oc.exit()
                     last_leaf_id = leaf_id
                 else:

@@ -9,7 +9,12 @@ monkeypatch them and exercise the window logic without a real profiler.
 """
 from __future__ import annotations
 
+import os
+
+from . import timers
 from ..utils.log import Log
+
+SPANS_FILE = "lgbm_spans.json"
 
 
 def parse_trace_iters(spec):
@@ -80,4 +85,12 @@ class TraceWindow:
             Log.warning("obs: could not stop profiler trace: %s", exc)
         self.active = False
         self.done = True
+        # the program's own spans, counters and scope tables, beside the
+        # xplane that was just written (its lgbm_* host events carry the
+        # same ``seq``)
+        try:
+            os.makedirs(self.trace_dir, exist_ok=True)
+            timers.write_spans(os.path.join(self.trace_dir, SPANS_FILE))
+        except OSError as exc:
+            Log.warning("obs: could not write %s: %s", SPANS_FILE, exc)
         obs.event("trace_window", action="stop", dir=self.trace_dir, it=it)

@@ -7,9 +7,21 @@ also breaks the async pipeline, so every timer here takes fencing as a
 parameter and the caller (RunObserver) decides per the ``obs_timing``
 mode.  All timers are plain-Python and allocation-light; none of them is
 on the disabled path (NULL_OBSERVER never constructs one).
+
+The span ring at the end of this module is the one part that is always
+on: ``span``/``count`` records from inside the program (set-up, one
+iteration, one tree's grow counters), the step program's table from HLO
+instruction name to ``jax.named_scope``, and the fold from a device
+trace's times by name to seconds per scope.  It never fences and it
+changes no program: docs/Observability.md "Spans, scopes and counters".
 """
 from __future__ import annotations
 
+import collections
+import itertools
+import json
+import re
+import threading
 import time
 
 # process-global count of host<->device synchronizations issued through
@@ -195,3 +207,255 @@ class OrchestrationClock:
     def host_seconds(self) -> float:
         """Elapsed since construction minus time spent inside dispatches."""
         return max(0.0, (time.perf_counter() - self._t0) - self._inside)
+
+
+# --------------------------------------------------------------------------
+# The span ring: always on, process-global, bounded.  Not owned by a
+# booster, so a reader that runs after ``del bst`` still finds the run.
+
+RING_SIZE = 4096
+# the jax.named_scope names inside the step program (ops/fused_iter.py,
+# ops/wave.py); an HLO instruction belongs to the first of these found in
+# its op_name, or to UNSCOPED
+SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_histogram",
+          "hist_allreduce", "split_search", "tree_commit", "score_update")
+UNSCOPED = "unscoped"
+# the grow loop's counter vector (ops/wave.py), in order; a tree's record
+# in the ring adds ``rows_visited = (waves + 1) * rows`` as a host integer
+COUNTERS = ("waves", "slots", "attempted", "committed", "hist_rows", "rows")
+# jax.monitoring durations that become child spans of the open span
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+_ring = collections.deque(maxlen=RING_SIZE)
+_ring_lock = threading.Lock()   # appends, and every walk over the ring
+_seq = itertools.count(1)
+_open = threading.local()       # .stack: this thread's open span records
+_scopes = {}                    # HLO module name -> {instruction: scope}
+_annotation = None              # jax.profiler.TraceAnnotation, once hooked
+
+
+def _hook_jax():
+    """Resolve TraceAnnotation and register the one duration listener, on
+    the first span (importing this module touches nothing)."""
+    global _annotation
+    import jax
+    from jax import monitoring
+    with _ring_lock:
+        if _annotation is None:
+            monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _stack():
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _on_jax_duration(event, seconds, fun_name=None, **_kw):
+    """A trace / lower / compile / cache-load that JAX reports in a thread
+    with an open span becomes that span's child: it ends now and started
+    ``seconds`` ago.  JAX reports the inner of two nested intervals first
+    (a jit traced inside a jit, the cache load inside the compile), so
+    the later, outer one adopts what it covers and replaces what it
+    covers of its own name: one ``trace`` for a step, not one a primitive."""
+    name = _JAX_DURATIONS.get(event)
+    stack = _stack()
+    if name is None or not stack:
+        return
+    parent = stack[-1]
+    t1 = time.perf_counter_ns()
+    t0 = t1 - int(seconds * 1e9)
+    rec = {"kind": "span", "name": name, "seq": next(_seq),
+           "cause": parent["seq"], "t0": t0, "t1": t1,
+           "ids": dict(parent["ids"])}
+    if fun_name is not None:
+        rec["ids"]["entry"] = str(fun_name)
+    with _ring_lock:
+        kept = []
+        while _ring and _ring[-1]["kind"] == "span" \
+                and _ring[-1]["t1"] >= t0:
+            older = _ring.pop()
+            if older["cause"] == parent["seq"] and older["t0"] >= t0:
+                if older["name"] == name:
+                    continue    # a jit traced inside this one: covered
+                older["cause"] = rec["seq"]
+            kept.append(older)
+        _ring.extend(reversed(kept))
+        _ring.append(rec)
+
+
+class span:
+    """``with span("upload", shard=3): ...`` records name, start and end
+    (``perf_counter_ns``), the span open in this thread when it started
+    (``cause``), a process-wide ``seq`` and its ids; ids are inherited, so
+    the spans of one iteration share ``it``.  It also enters a
+    ``TraceAnnotation("lgbm_<name>", seq=...)``: inside a profiler window
+    the span lands on the xplane's host lines, on the device trace's
+    clock, and ``seq`` ties it to its ring record."""
+
+    __slots__ = ("rec", "_ann")
+
+    def __init__(self, name, **ids):
+        self.rec = {"kind": "span", "name": name, "seq": 0, "cause": None,
+                    "t0": 0, "t1": 0, "ids": ids}
+
+    def __enter__(self):
+        rec, stack = self.rec, _stack()
+        if stack:
+            parent = stack[-1]
+            rec["cause"] = parent["seq"]
+            rec["ids"] = dict(parent["ids"], **rec["ids"])
+        rec["seq"] = next(_seq)
+        ann = _annotation or _hook_jax()
+        self._ann = ann("lgbm_" + rec["name"], seq=rec["seq"], **rec["ids"])
+        self._ann.__enter__()
+        stack.append(rec)
+        rec["t0"] = time.perf_counter_ns()
+        return rec
+
+    def __exit__(self, *exc):
+        rec = self.rec
+        rec["t1"] = time.perf_counter_ns()
+        _stack().pop()
+        self._ann.__exit__(*exc)
+        with _ring_lock:
+            _ring.append(rec)
+        return False
+
+
+def count(name, **fields):
+    """One counter record (``kind="count"``) at this instant, caused by
+    the span open in this thread."""
+    stack = _stack()
+    rec = {"kind": "count", "name": name, "seq": next(_seq),
+           "cause": stack[-1]["seq"] if stack else None,
+           "t": time.perf_counter_ns(), "fields": fields}
+    with _ring_lock:
+        _ring.append(rec)
+
+
+def snapshot():
+    """The ring's records, oldest first (copies: safe to keep)."""
+    with _ring_lock:
+        return [dict(r) for r in _ring]
+
+
+def clear():
+    """Empty the ring.  Scope tables stay: they describe programs that are
+    still loaded."""
+    with _ring_lock:
+        _ring.clear()
+
+
+def self_seconds(records):
+    """``{seq: seconds}`` for the spans among `records`: a span's duration
+    less the part of it that its child spans cover."""
+    spans = [r for r in records if r["kind"] == "span"]
+    kids = collections.defaultdict(list)
+    for r in spans:
+        kids[r["cause"]].append((r["t0"], r["t1"]))
+    out = {}
+    for r in spans:
+        covered, end = 0, r["t0"]
+        for t0, t1 in sorted(kids.get(r["seq"], ())):
+            t0, t1 = max(t0, end), min(t1, r["t1"])
+            if t1 > t0:
+                covered += t1 - t0
+                end = t1
+        out[r["seq"]] = (r["t1"] - r["t0"] - covered) * 1e-9
+    return out
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _scope_of(op_name):
+    for part in op_name.split("/"):
+        if part in SCOPES:
+            return part
+    return None
+
+
+def scope_table(hlo_text):
+    """``(module name, {instruction name: scope})`` of one optimised HLO
+    module's text.  An instruction takes the first declared scope in its
+    own ``op_name``; a fusion whose own metadata names none takes the
+    first one found inside the computation it calls.  Instructions with
+    neither are left out (they fold into UNSCOPED)."""
+    module, table = None, {}
+    inside = {}                 # computation -> first scope seen inside it
+    calls = {}                  # unscoped instruction -> computation called
+    computation = None
+    for line in hlo_text.splitlines():
+        if module is None:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+                continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                computation = m.group(1)
+            continue
+        op = _HLO_OP_NAME.search(line)
+        scope = _scope_of(op.group(1)) if op else None
+        if scope is not None:
+            table[m.group(1)] = scope
+            inside.setdefault(computation, scope)
+        else:
+            called = _HLO_CALLS.search(line)
+            if called:
+                calls[m.group(1)] = called.group(1)
+    for name, called in calls.items():
+        if called in inside:
+            table[name] = inside[called]
+    return module, table
+
+
+def register_device_scopes(hlo_text):
+    """Keep the scope table of a step program that was just compiled or
+    loaded (ops/fused_iter.py); returns the module's name."""
+    module, table = scope_table(hlo_text)
+    if module is not None:
+        _scopes[module] = table
+    return module
+
+
+def device_scopes():
+    """``{HLO module name: {instruction name: scope}}`` of every step
+    program registered in this process."""
+    return {m: dict(t) for m, t in _scopes.items()}
+
+
+def device_time_by_scope(device_ops, table):
+    """Fold ``[instruction name, seconds]`` pairs (a device trace's self
+    times by short name) into ``{scope: seconds}`` by one module's table;
+    what the table does not name is UNSCOPED."""
+    out = {}
+    for name, seconds in device_ops:
+        scope = table.get(name, UNSCOPED)
+        out[scope] = out.get(scope, 0.0) + float(seconds)
+    return out
+
+
+def write_spans(path):
+    """The ring and the scope tables as one JSON file: what a profiler
+    window writes beside its xplane (obs/profile.py)."""
+    with open(path, "w") as f:
+        json.dump({"records": snapshot(), "scopes": device_scopes(),
+                   "declared_scopes": list(SCOPES)}, f)

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from .partition import score_update_impl
+from ..obs import timers
 from ..utils.log import Log
 
 
@@ -110,13 +111,14 @@ class FusedIteration:
             # stage 1: objective gradients in-graph — same ops the staged
             # path dispatches as its own entry (reshape to (1, N) and the
             # [0] slice are identities at k=1, so they are elided)
-            g, h = rebind(obj_arrays).get_gradients(score)
-            g = jnp.asarray(g, dtype)
-            h = jnp.asarray(h, dtype)
-            if pad:
-                z = jnp.zeros(pad, dtype)
-                g = jnp.concatenate([g, z])
-                h = jnp.concatenate([h, z])
+            with jax.named_scope("gradients"):
+                g, h = rebind(obj_arrays).get_gradients(score)
+                g = jnp.asarray(g, dtype)
+                h = jnp.asarray(h, dtype)
+                if pad:
+                    z = jnp.zeros(pad, dtype)
+                    g = jnp.concatenate([g, z])
+                    h = jnp.concatenate([h, z])
             # stage 2: the learner's own grow program, inlined — the
             # lax.while_loop over the leaf frontier (hist accumulation,
             # FindBestThreshold, row->leaf partition) never touches host
@@ -124,15 +126,20 @@ class FusedIteration:
                 tree, leaf_id = grow(X, g, h, row_mult, feature_mask)
             else:
                 tree, leaf_id = grow(X, g, h, row_mult, feature_mask, Xt)
-            if pad:
-                leaf_id = leaf_id[: self._num_data]
             # stage 3: partition-side score update, shared impl with the
             # staged gather engine (bit-identity single source)
-            new_score = score_update_impl(score, leaf_id, tree.leaf_value,
-                                          scale)
+            with jax.named_scope("score_update"):
+                if pad:
+                    leaf_id = leaf_id[: self._num_data]
+                new_score = score_update_impl(score, leaf_id,
+                                              tree.leaf_value, scale)
             return tree, leaf_id, new_score
 
         self._step = jax.jit(step)
+        # the step compiled ahead of time, one executable per argument
+        # signature: holding the Compiled is what lets its own HLO text be
+        # read (timers.register_device_scopes) at no second compile
+        self._compiled = {}
 
     def step_args(self, score, row_mult, feature_mask, scale) -> tuple:
         """The positional arguments of the jitted step, in order."""
@@ -191,6 +198,22 @@ class FusedIteration:
                        names=("X", "Xt", "objective", "score", "row_mult",
                               "feature_mask", "scale"))
         t0 = obs.entry_start()
-        tree, leaf_id, new_score = self._step(*args)
+        with timers.span("dispatch"):
+            tree, leaf_id, new_score = self._executable(args)(*args)
         obs.entry_end("fused_iter", t0, (tree, leaf_id, new_score))
         return tree, leaf_id, new_score
+
+    def _executable(self, args):
+        """The step compiled for these arguments' shapes and dtypes.  The
+        first call with a signature lowers and compiles (or loads from the
+        compile cache) exactly as the jitted call would, and registers the
+        executable's instruction-to-scope table."""
+        key = jax.tree_util.tree_structure(args), tuple(
+            (a.shape, a.dtype, getattr(a, "weak_type", False))
+            for a in jax.tree_util.tree_leaves(args))
+        compiled = self._compiled.get(key)
+        if compiled is None:
+            compiled = self._step.lower(*args).compile()
+            timers.register_device_scopes(compiled.as_text())
+            self._compiled[key] = compiled
+        return compiled

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.timers import COUNTERS
 from .histogram import (compact_rows, compact_rows_topk, gathered_histogram,
                         leaf_histogram_onehot, leaf_histogram_scatter)
 from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
@@ -79,6 +80,9 @@ class TreeArrays(NamedTuple):
     # gain (-1 / 0 when the winner was the only valid candidate)
     second_feature: jnp.ndarray      # (L-1,) i32
     second_gain: jnp.ndarray         # (L-1,) f
+    # what the grow loop did (obs/timers.py COUNTERS, i32): filled by the
+    # wave grower, zeros from this module's leaf-wise grower
+    counters: jnp.ndarray            # (len(COUNTERS),) i32
 
 
 def feature_hist_view(ghist, sums, meta, bundle, has_bundle: bool,
@@ -549,6 +553,7 @@ def make_grow_core(num_leaves: int, num_bins: int,
             leaf_depth=jnp.zeros(L, jnp.int32),
             second_feature=jnp.full(L - 1, -1, jnp.int32),
             second_gain=jnp.zeros(L - 1, hist_dtype),
+            counters=jnp.zeros(len(COUNTERS), jnp.int32),
         )
 
         def cond(carry):

@@ -20,7 +20,7 @@ import numpy as np
 
 from ..io.dataset import TrainingData
 from ..models.tree import Tree
-from ..obs import NULL_OBSERVER
+from ..obs import NULL_OBSERVER, timers
 from ..utils.config import Config
 from ..utils.random import Random
 from .grow import (BundleArrays, TreeArrays, default_row_capacities,
@@ -117,12 +117,21 @@ def paged_device_matrix(train_data, row_pad: int = 0):
     # iter_rows restricts paging to the reader's row_range — on a
     # rank-sharded open (io/dataset.py from_binned(comm=...)) this rank
     # uploads only its own rows and never maps a foreign shard
-    parts = [jnp.asarray(np.ascontiguousarray(view))
-             for _, view in reader.iter_rows()]
-    if row_pad:
-        parts.append(jnp.zeros((int(row_pad), reader.num_columns),
-                               parts[0].dtype))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    with timers.span("upload"):
+        parts = []
+        for shard, (_, view) in enumerate(reader.iter_rows()):
+            with timers.span("upload_shard", shard=shard):
+                with timers.span("host_copy"):
+                    page = np.ascontiguousarray(view)
+                with timers.span("h2d"):    # the call; the copy is async
+                    parts.append(jnp.asarray(page))
+        if row_pad:
+            parts.append(jnp.zeros((int(row_pad), reader.num_columns),
+                                   parts[0].dtype))
+        if len(parts) == 1:
+            return parts[0]
+        with timers.span("concat"):         # dispatch only
+            return jnp.concatenate(parts, axis=0)
 
 
 class SerialTreeLearner:
@@ -600,7 +609,8 @@ class SerialTreeLearner:
             # entry and a second HBM copy with the dataset
             from .wave import transposed_wave_active
             if transposed_wave_active(hist_mode, self.dtype):
-                self._Xt = jnp.transpose(self.X)
+                with timers.span("transpose_xt"):   # dispatch only
+                    self._Xt = jnp.transpose(self.X)
 
             def _grow(X, g, h, rm, m, Xt=None, _core=core, _meta=meta,
                       _bund=bund):
@@ -826,7 +836,8 @@ class SerialTreeLearner:
                        names=("X", "grad", "hess", "row_mult",
                               "feature_mask", "Xt")[:len(args)])
         t0 = obs.entry_start()
-        tree, leaf_id = self._grow(*args)
+        with timers.span("dispatch"):
+            tree, leaf_id = self._grow(*args)
         obs.entry_end("tree_grow", t0, (tree, leaf_id))
         if self._row_pad:
             leaf_id = leaf_id[:self.train_data.num_data]

@@ -294,6 +294,7 @@ def wave_histogram_pallas(X, leaf_id, w3, child_id, num_bins: int,
     operands = (X, lid2, w3t, child_id[:, None])
     flat = pl.pallas_call(
         kernel,
+        name="wave_histogram_pallas",
         grid=(nch,),
         in_specs=[
             pl.BlockSpec((c, fdev), lambda i: (i, 0),
@@ -387,6 +388,7 @@ def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
     operands = (X_t, lid2, w3t, child_id[:, None])
     flat = pl.pallas_call(
         kernel,
+        name="wave_histogram_pallas_t",
         grid=(nch,),
         in_specs=[
             pl.BlockSpec((fdev, c), lambda i: (0, i),
@@ -539,6 +541,7 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
     operands = (X_t, lid2, w3t, child_id[:, None], tblt, psrc[:, None])
     newlid, flat = pl.pallas_call(
         kernel,
+        name="wave_partition_hist_pallas_ct",
         grid=(nch,),
         in_specs=[
             pl.BlockSpec((fdev, c), lambda i: (0, i),

@@ -134,7 +134,8 @@ def _update_score_gather(score, leaf_id, leaf_value, scale):
     # single-source arithmetic shared with the fused iteration program
     # (ops/fused_iter.py) — bit-identity depends on both paths tracing
     # the same impl
-    return score_update_impl(score, leaf_id, leaf_value, scale)
+    with jax.named_scope("score_update"):
+        return score_update_impl(score, leaf_id, leaf_value, scale)
 
 
 def _score_update_kernel(tbl_ref, lid_ref, score_ref, out_ref, *, L):
@@ -148,6 +149,7 @@ def _score_update_kernel(tbl_ref, lid_ref, score_ref, out_ref, *, L):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.named_scope("score_update")
 def _update_score_pallas(score, leaf_id, vals, interpret=False):
     """Pallas form of the partition score update.
 

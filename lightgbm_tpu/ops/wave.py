@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.timers import COUNTERS
 from .grow import TreeArrays, feature_hist_view, pvary_for, vary_like
 from .histogram import leaf_histogram_onehot, leaf_histogram_scatter
 from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
@@ -54,6 +55,10 @@ from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
 # one-line change.  Lives here (not pallas_wave.py) so CPU-only installs
 # never import jax.experimental.pallas just to validate a config.
 WAVE_ONLY_MODES = ("pallas_t", "pallas_ct")
+
+# the grow program's phases, by the names obs/timers.py SCOPES declares:
+# the step program's HLO instructions are read back by them
+scope = jax.named_scope
 
 
 def _bin_pad(num_bins: int) -> int:
@@ -223,7 +228,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
 
     def maybe_psum(x):
         if psum_axis is not None:
-            return lax.psum(x, psum_axis)
+            with scope("hist_allreduce"):
+                return lax.psum(x, psum_axis)
         return x
 
     def to_feature_hist(ghist, sums, meta, bundle):
@@ -249,11 +255,12 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             unpack = lambda xc: unpack4(xc, Fc)  # noqa: E731
         else:
             unpack = lambda xc: xc               # noqa: E731
-        grad = grad.astype(hist_dtype)
-        hess = hess.astype(hist_dtype)
-        row_mult = row_mult.astype(hist_dtype)
-        w3 = jnp.stack([grad * row_mult, hess * row_mult, row_mult],
-                       axis=-1)           # (N, 3) per-row weight channels
+        with scope("gradients"):
+            grad = grad.astype(hist_dtype)
+            hess = hess.astype(hist_dtype)
+            row_mult = row_mult.astype(hist_dtype)
+            w3 = jnp.stack([grad * row_mult, hess * row_mult, row_mult],
+                           axis=-1)       # (N, 3) per-row weight channels
         leaf_id = jnp.zeros(n, dtype=jnp.int32)
         if psum_axis is not None:
             leaf_id = pvary_for(leaf_id, psum_axis)
@@ -262,14 +269,16 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         pad = (-n) % c
         nch = (n + pad) // c
         if not sparse_mode:
-            Xp = jnp.pad(X, ((0, pad), (0, 0))) if pad else X
-            xb = Xp.reshape(nch, c, Fdev)
+            with scope("wave_partition"):
+                Xp = jnp.pad(X, ((0, pad), (0, 0))) if pad else X
+                xb = Xp.reshape(nch, c, Fdev)
         # transposed matrix for the v2 kernel (MXU-native dot orientation):
         # callers that hold X for many trees pass a precomputed Xt (the
         # learner materializes it once per booster); otherwise fall back to
         # one (F, N) materialization per tree dispatch
         if use_pallas_hist and pallas_transposed and Xt is None:
-            Xt = jnp.transpose(X)
+            with scope("wave_histogram"):
+                Xt = jnp.transpose(X)
 
         # ---- sparse (coordinate-store) variants: partition reads ONLY
         # the W chosen split columns; all W child histograms are ONE
@@ -280,10 +289,12 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         if mxu_sparse and (jax.default_backend() == "tpu"
                            and hist_dtype == jnp.float32):
             from .sparse_mxu import gather_entry_weights
-            mxu_entry_w = gather_entry_weights(X, w3)
+            with scope("wave_histogram"):
+                mxu_entry_w = gather_entry_weights(X, w3)
         else:
             mxu_entry_w = None
 
+        @scope("wave_histogram")
         def sparse_child_hists(lid, ids, valid):
             if mxu_sparse:
                 from .sparse_mxu import (chunked_child_hists_ref,
@@ -336,15 +347,17 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 from .sparse_mxu import chunked_split_column as _colfn
             else:
                 from .sparse_store import sparse_split_column as _colfn
-            r = jnp.take(tbl, lid, axis=0)                 # (N, 10)
-            cj = r[:, 1].astype(jnp.int32)
-            colv = jnp.zeros(n, jnp.int32)
-            for w in range(W):                             # static W
-                vals = _colfn(X, col_ids[w], n, sparse_col_cap)
-                colv = jnp.where(cj == col_ids[w], vals, colv)
-            new_lid = route_rows(r, colv, lid)
+            with scope("wave_partition"):
+                r = jnp.take(tbl, lid, axis=0)             # (N, 10)
+                cj = r[:, 1].astype(jnp.int32)
+                colv = jnp.zeros(n, jnp.int32)
+                for w in range(W):                         # static W
+                    vals = _colfn(X, col_ids[w], n, sparse_col_cap)
+                    colv = jnp.where(cj == col_ids[w], vals, colv)
+                new_lid = route_rows(r, colv, lid)
             return new_lid, sparse_child_hists(new_lid, small_id, valid)
 
+        @scope("wave_histogram")
         def pallas_hist(lid, cid):
             """Dispatch to the fused kernel in the configured layout —
             the single call site for both wave_pass and rehist."""
@@ -387,55 +400,60 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             """
             if use_pallas_hist and pallas_fused:
                 from .pallas_wave import wave_partition_hist_pallas_ct
-                return wave_partition_hist_pallas_ct(
-                    Xt, leaf_id, w3,
-                    jnp.where(valid, small_id, -1), cols, psrc,
-                    hist_bins, bundled=has_bundle,
-                    logical_cols=packed_cols, hilo=hist_hilo,
-                    interpret=pallas_interpret)
-            lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
-                else leaf_id.reshape(nch, c)
-            wpad = jnp.pad(w3, ((0, pad), (0, 0))) if pad else w3
-            wb3 = wpad.reshape(nch, c, 3)
-            l_iota = jnp.arange(L, dtype=jnp.int32)
-            f_iota = jnp.arange(Fc, dtype=jnp.int32)
+                # one kernel routes and histograms: it reads as histogram
+                with scope("wave_histogram"):
+                    return wave_partition_hist_pallas_ct(
+                        Xt, leaf_id, w3,
+                        jnp.where(valid, small_id, -1), cols, psrc,
+                        hist_bins, bundled=has_bundle,
+                        logical_cols=packed_cols, hilo=hist_hilo,
+                        interpret=pallas_interpret)
+            with scope("wave_partition"):
+                lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
+                    else leaf_id.reshape(nch, c)
+                wpad = jnp.pad(w3, ((0, pad), (0, 0))) if pad else w3
+                wb3 = wpad.reshape(nch, c, 3)
+                l_iota = jnp.arange(L, dtype=jnp.int32)
+                f_iota = jnp.arange(Fc, dtype=jnp.int32)
 
             def step(acc, args):
                 xc, lc, wc = args                   # (C,Fdev) (C,) (C,3)
-                xc = unpack(xc)                     # (C, Fc) logical bins
-                if lookup == "compact":
-                    # <=1 match per row, so the sum is exact and XLA can
-                    # fuse the (C, W, 10) broadcast into the reduction —
-                    # no (C, L) one-hot ever exists
-                    pm = lc[:, None] == psrc[None, :]          # (C, W)
-                    r = jnp.sum(
-                        jnp.where(pm[:, :, None], cols[None, :, :], 0.0),
-                        axis=1)                     # (C, 10)
-                elif lookup == "gather":
-                    r = jnp.take(tbl, jnp.clip(lc, 0, L - 1), axis=0)
-                else:
-                    leaf_oh = (lc[:, None] == l_iota[None, :]).astype(
-                        jnp.float32)                # (C, L)
-                    # HIGHEST: TPU's default matmul precision is bf16,
-                    # which rounds integer table entries above 256 (column
-                    # ids, thresholds, leaf ids) — the lookup must be
-                    # exact f32
-                    r = jnp.matmul(leaf_oh, tbl,
-                                   precision=lax.Precision.HIGHEST)
-                cj = r[:, 1].astype(jnp.int32)
-                colv = jnp.sum(
-                    jnp.where(cj[:, None] == f_iota[None, :], xc, 0)
-                    .astype(jnp.int32), axis=1)     # (C,) split-column bin
-                lc2 = route_rows(r, colv, lc)
+                with scope("wave_partition"):
+                    xc = unpack(xc)                 # (C, Fc) logical bins
+                    if lookup == "compact":
+                        # <=1 match per row, so the sum is exact and XLA
+                        # can fuse the (C, W, 10) broadcast into the
+                        # reduction — no (C, L) one-hot ever exists
+                        pm = lc[:, None] == psrc[None, :]      # (C, W)
+                        r = jnp.sum(
+                            jnp.where(pm[:, :, None], cols[None, :, :],
+                                      0.0), axis=1)            # (C, 10)
+                    elif lookup == "gather":
+                        r = jnp.take(tbl, jnp.clip(lc, 0, L - 1), axis=0)
+                    else:
+                        leaf_oh = (lc[:, None] == l_iota[None, :]).astype(
+                            jnp.float32)            # (C, L)
+                        # HIGHEST: TPU's default matmul precision is bf16,
+                        # which rounds integer table entries above 256
+                        # (column ids, thresholds, leaf ids) — the lookup
+                        # must be exact f32
+                        r = jnp.matmul(leaf_oh, tbl,
+                                       precision=lax.Precision.HIGHEST)
+                    cj = r[:, 1].astype(jnp.int32)
+                    colv = jnp.sum(
+                        jnp.where(cj[:, None] == f_iota[None, :], xc, 0)
+                        .astype(jnp.int32), axis=1)  # (C,) split-column bin
+                    lc2 = route_rows(r, colv, lc)
                 if not use_pallas_hist:
-                    # child-masked weights: (C, W) match x (C, 3) channels
-                    match = ((lc2[:, None] == small_id[None, :])
-                             & valid[None, :]).astype(hist_dtype)
-                    oh = jax.nn.one_hot(xc.astype(jnp.int32), hist_bins,
-                                        dtype=oh_dtype)      # (C, Fc, B)
-                    acc = acc + _slot_hist(
-                        oh.reshape(c, Fc * hist_bins), match, wc, W,
-                        hist_dtype, exact_order)
+                    with scope("wave_histogram"):
+                        # child-masked weights: (C, W) match x (C, 3)
+                        match = ((lc2[:, None] == small_id[None, :])
+                                 & valid[None, :]).astype(hist_dtype)
+                        oh = jax.nn.one_hot(xc.astype(jnp.int32), hist_bins,
+                                            dtype=oh_dtype)  # (C, Fc, B)
+                        acc = acc + _slot_hist(
+                            oh.reshape(c, Fc * hist_bins), match, wc, W,
+                            hist_dtype, exact_order)
                 return acc, lc2
 
             acc_shape = ((Fc * hist_bins, 3 * W) if not use_pallas_hist
@@ -454,8 +472,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                                    jnp.where(valid, small_id, -1))
             else:
                 # (Fc*B, W*3) -> (W, Fc, B, 3)
-                hist = flat.reshape(Fc, hist_bins, W, 3).transpose(2, 0, 1,
-                                                                   3)
+                with scope("wave_histogram"):
+                    hist = flat.reshape(Fc, hist_bins, W, 3).transpose(
+                        2, 0, 1, 3)
             return new_leaf_id, hist
 
         # ---- spectator-row compaction (tpu_wave_compact): capacity
@@ -495,29 +514,32 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             stable-compact index build (cumsum), and the row gathers —
             against kernel row work shrinking from N to the tier."""
             from .pallas_wave import wave_partition_hist_pallas_ct
-            act_tbl = jnp.zeros(L, bool).at[
-                jnp.where(valid, psrc, L)].set(True, mode="drop")
-            mask = jnp.take(act_tbl, leaf_id)            # (N,)
-            active_n = jnp.sum(mask.astype(jnp.int32))   # TRUE row count
-            cid = jnp.where(valid, small_id, -1)
+            with scope("wave_partition"):
+                act_tbl = jnp.zeros(L, bool).at[
+                    jnp.where(valid, psrc, L)].set(True, mode="drop")
+                mask = jnp.take(act_tbl, leaf_id)            # (N,)
+                active_n = jnp.sum(mask.astype(jnp.int32))   # TRUE row count
+                cid = jnp.where(valid, small_id, -1)
 
             def tier(cap):
                 def run():
-                    idx = jnp.nonzero(mask, size=cap, fill_value=n)[0]
-                    # fill semantics mirror the kernel's own padding:
-                    # leaf -2 matches nothing, weight 0 adds nothing
-                    xt_c = jnp.take(Xt, idx, axis=1, mode="fill",
-                                    fill_value=0)
-                    lid_c = jnp.take(leaf_id, idx, mode="fill",
-                                     fill_value=-2)
-                    w3_c = jnp.take(w3, idx, axis=0, mode="fill",
-                                    fill_value=0.0)
+                    with scope("wave_partition"):
+                        idx = jnp.nonzero(mask, size=cap, fill_value=n)[0]
+                        # fill semantics mirror the kernel's own padding:
+                        # leaf -2 matches nothing, weight 0 adds nothing
+                        xt_c = jnp.take(Xt, idx, axis=1, mode="fill",
+                                        fill_value=0)
+                        lid_c = jnp.take(leaf_id, idx, mode="fill",
+                                         fill_value=-2)
+                        w3_c = jnp.take(w3, idx, axis=0, mode="fill",
+                                        fill_value=0.0)
                     if pallas_fused:
-                        new_c, hist = wave_partition_hist_pallas_ct(
-                            xt_c, lid_c, w3_c, cid, cols, psrc,
-                            hist_bins, bundled=has_bundle,
-                            logical_cols=packed_cols, hilo=hist_hilo,
-                            interpret=pallas_interpret)
+                        with scope("wave_histogram"):
+                            new_c, hist = wave_partition_hist_pallas_ct(
+                                xt_c, lid_c, w3_c, cid, cols, psrc,
+                                hist_bins, bundled=has_bundle,
+                                logical_cols=packed_cols, hilo=hist_hilo,
+                                interpret=pallas_interpret)
                     else:
                         # pallas_t tier: the partition over the
                         # gathered slab is ONE masked reduction — the
@@ -528,25 +550,28 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         # histogram kernel on the updated ids
                         from .pallas_wave import (_unpack4_t,
                                                   wave_histogram_pallas_t)
-                        pm = lid_c[None, :] == psrc[:, None]   # (W,cap)
-                        r = jnp.sum(
-                            jnp.where(pm[:, :, None], cols[:, None, :],
-                                      0.0), axis=0)            # (cap,10)
-                        xi = xt_c.astype(jnp.int32)
-                        if packed_cols:
-                            xi = _unpack4_t(xi, Fc)
-                        cj = r[:, 1].astype(jnp.int32)
-                        f_io = jnp.arange(Fc, dtype=jnp.int32)
-                        colv = jnp.sum(
-                            jnp.where(cj[None, :] == f_io[:, None],
-                                      xi, 0), axis=0)          # (cap,)
-                        new_c = route_rows(r, colv, lid_c)
-                        hist = wave_histogram_pallas_t(
-                            xt_c, new_c, w3_c, cid, hist_bins,
-                            logical_cols=packed_cols, hilo=hist_hilo,
-                            interpret=pallas_interpret)
-                    return (leaf_id.at[idx].set(new_c, mode="drop"),
-                            hist)
+                        with scope("wave_partition"):
+                            pm = lid_c[None, :] == psrc[:, None]  # (W,cap)
+                            r = jnp.sum(
+                                jnp.where(pm[:, :, None], cols[:, None, :],
+                                          0.0), axis=0)           # (cap,10)
+                            xi = xt_c.astype(jnp.int32)
+                            if packed_cols:
+                                xi = _unpack4_t(xi, Fc)
+                            cj = r[:, 1].astype(jnp.int32)
+                            f_io = jnp.arange(Fc, dtype=jnp.int32)
+                            colv = jnp.sum(
+                                jnp.where(cj[None, :] == f_io[:, None],
+                                          xi, 0), axis=0)         # (cap,)
+                            new_c = route_rows(r, colv, lid_c)
+                        with scope("wave_histogram"):
+                            hist = wave_histogram_pallas_t(
+                                xt_c, new_c, w3_c, cid, hist_bins,
+                                logical_cols=packed_cols, hilo=hist_hilo,
+                                interpret=pallas_interpret)
+                    with scope("wave_partition"):
+                        return (leaf_id.at[idx].set(new_c, mode="drop"),
+                                hist)
                 return run
 
             def ladder(caps):
@@ -557,6 +582,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                                 lambda: ladder(caps[1:]))
             return ladder(compact_caps)
 
+        @scope("wave_histogram")
         def rehist(leaf_id, ids, valid):
             """Histograms of `ids` children only (no partition) — the
             no-cache larger-child pass."""
@@ -587,6 +613,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                                    (xb, lb, wb3))
             return flat.reshape(Fc, hist_bins, W, 3).transpose(2, 0, 1, 3)
 
+        @scope("split_search")
         def best_of_many(hists_k, sums_k, depths_k, feature_mask, meta,
                          bundle):
             """vmapped packed best-split search over K children — the
@@ -598,34 +625,42 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 hist_view=lambda h, s: to_feature_hist(h, s, meta, bundle))
 
         # ---- root
-        root_sums = maybe_psum(jnp.sum(w3, axis=0))
-        if mxu_sparse:
-            # root histogram through the same kernel call shape as the
-            # wave passes (one compiled executable): slot 0 targets the
-            # root, the other W-1 slots are inactive
-            hist0 = maybe_psum(sparse_child_hists(
-                leaf_id, jnp.zeros(W, jnp.int32),
-                jnp.arange(W) == 0)[0])
-        elif sparse_mode:
-            from .sparse_store import leaf_histogram_sparse
-            hist0 = maybe_psum(leaf_histogram_sparse(
-                X, grad, hess, leaf_id, 0, row_mult, hist_bins, Fc))
-        else:
-            root_kw = ({"chunk": chunk}
-                       if root_hist_fn is leaf_histogram_onehot else {})
-            hist0 = maybe_psum(root_hist_fn(
-                X, grad, hess, leaf_id, 0, row_mult, num_bins=hist_bins,
-                logical_cols=packed_cols, **root_kw))
+        with scope("root_histogram"):
+            root_sums = jnp.sum(w3, axis=0)
+            if mxu_sparse:
+                # root histogram through the same kernel call shape as the
+                # wave passes (one compiled executable): slot 0 targets the
+                # root, the other W-1 slots are inactive
+                hist0 = sparse_child_hists(
+                    leaf_id, jnp.zeros(W, jnp.int32),
+                    jnp.arange(W) == 0)[0]
+            elif sparse_mode:
+                from .sparse_store import leaf_histogram_sparse
+                hist0 = leaf_histogram_sparse(
+                    X, grad, hess, leaf_id, 0, row_mult, hist_bins, Fc)
+            else:
+                root_kw = ({"chunk": chunk}
+                           if root_hist_fn is leaf_histogram_onehot else {})
+                hist0 = root_hist_fn(
+                    X, grad, hess, leaf_id, 0, row_mult, num_bins=hist_bins,
+                    logical_cols=packed_cols, **root_kw)
+        root_sums = maybe_psum(root_sums)
+        hist0 = maybe_psum(hist0)
         Fh, B = hist0.shape[0], hist0.shape[1]
-        if cache_hists:
-            hists = jnp.zeros((L, Fh, B, 3), hist_dtype).at[0].set(hist0)
-        else:
-            hists = jnp.zeros((0,), hist_dtype)
+        with scope("root_histogram"):
+            if cache_hists:
+                hists = jnp.zeros((L, Fh, B, 3), hist_dtype).at[0].set(hist0)
+            else:
+                hists = jnp.zeros((0,), hist_dtype)
         bests = jnp.full((L, SPLIT_VEC_SIZE), -jnp.inf, dtype=hist_dtype)
         bests = bests.at[0].set(best_of_many(
             hist0[None], root_sums[None], jnp.zeros(1, jnp.int32),
             feature_mask, meta, bundle)[0])
         sums = jnp.zeros((L, 3), hist_dtype).at[0].set(root_sums)
+        # what the loop counts (obs/timers.py COUNTERS): each wave adds
+        # [1, W, k, kc, rows of the committed smaller children, 0]; the
+        # last entry is the rows every pass visits
+        counters = jnp.zeros(len(COUNTERS), jnp.int32).at[-1].set(n)
         tree = TreeArrays(
             num_leaves=jnp.asarray(1, jnp.int32),
             split_feature=jnp.zeros(L - 1, jnp.int32),
@@ -645,6 +680,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             leaf_depth=jnp.zeros(L, jnp.int32),
             second_feature=jnp.full(L - 1, -1, jnp.int32),
             second_gain=jnp.zeros(L - 1, hist_dtype),
+            counters=counters,
         )
 
         def cond(carry):
@@ -653,62 +689,64 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
 
         def body(carry):
             nn, done, leaf_id, hists, bests, sums, tree = carry
-            gains = bests[:, GAIN]
-            budget = (L - 1) - nn
-            gw, lw = lax.top_k(gains, W)
-            rank = jnp.arange(W, dtype=jnp.int32)
-            valid = (gw > 0.0) & (rank < budget)
-            k = jnp.sum(valid.astype(jnp.int32))
-            parent = lw.astype(jnp.int32)          # distinct leaf ids
-            info = bests[parent]                   # (W, V)
-            node = nn + rank                       # internal node ids
-            newleaf = node + 1                     # right-child leaf ids
+            with scope("split_search"):     # the frontier: top W by gain
+                gains = bests[:, GAIN]
+                budget = (L - 1) - nn
+                gw, lw = lax.top_k(gains, W)
+                rank = jnp.arange(W, dtype=jnp.int32)
+                valid = (gw > 0.0) & (rank < budget)
+                k = jnp.sum(valid.astype(jnp.int32))
+                parent = lw.astype(jnp.int32)          # distinct leaf ids
+                info = bests[parent]                   # (W, V)
+                node = nn + rank                       # internal node ids
+                newleaf = node + 1                     # right-child leaf ids
 
-            f_w = info[:, FEATURE].astype(jnp.int32)
-            thr_w = info[:, THRESHOLD].astype(jnp.int32)
-            dbz_w = info[:, DEFAULT_BIN_FOR_ZERO].astype(jnp.int32)
-            cat_w = info[:, IS_CAT] > 0.5
-            fdef_w = meta.default_bin[f_w]
-            dleft_w = jnp.where(cat_w, dbz_w == thr_w, dbz_w <= thr_w)
+            with scope("wave_partition"):   # the wave's split tables
+                f_w = info[:, FEATURE].astype(jnp.int32)
+                thr_w = info[:, THRESHOLD].astype(jnp.int32)
+                dbz_w = info[:, DEFAULT_BIN_FOR_ZERO].astype(jnp.int32)
+                cat_w = info[:, IS_CAT] > 0.5
+                fdef_w = meta.default_bin[f_w]
+                dleft_w = jnp.where(cat_w, dbz_w == thr_w, dbz_w <= thr_w)
 
-            # ---- per-leaf split tables, fused into one (L, K) f32 matrix
-            # (all entries < 2^24, exact in f32) looked up per row by a
-            # one-hot contraction — no row gathers anywhere
-            src = jnp.where(valid, parent, L)      # L -> dropped
-            if has_bundle:
-                col_w = bundle.group_of[f_w]
-                goff_w = bundle.bin_off[f_w]
-                adj_w = bundle.bin_adj[f_w]
-                span_w = bundle.bin_span[f_w]
-            else:
-                col_w = f_w
-                goff_w = jnp.zeros(W, jnp.int32)
-                adj_w = jnp.zeros(W, jnp.int32)
-                span_w = jnp.full(W, num_bins, jnp.int32)
-            cols = jnp.stack([
-                jnp.ones(W, jnp.float32),                  # 0: active
-                col_w.astype(jnp.float32),                 # 1: device column
-                thr_w.astype(jnp.float32),                 # 2: threshold bin
-                cat_w.astype(jnp.float32),                 # 3: categorical
-                fdef_w.astype(jnp.float32),                # 4: default bin
-                dleft_w.astype(jnp.float32),               # 5: default left
-                newleaf.astype(jnp.float32),               # 6: right leaf id
-                goff_w.astype(jnp.float32),                # 7: group offset
-                adj_w.astype(jnp.float32),                 # 8: bin adjust
-                span_w.astype(jnp.float32),                # 9: bin span
-            ], axis=-1)                                    # (W, 10)
-            tbl = jnp.zeros((L, 10), jnp.float32).at[src].set(
-                cols, mode="drop")
-            # compact-lookup operands: the W parent ids (invalid slots
-            # get -3, which no real/padded leaf id ever equals) and the
-            # raw (W, 10) rows — invalid rows can hold garbage, they
-            # never match
-            psrc = jnp.where(valid, parent, -3)
+                # ---- per-leaf split tables, fused into one (L, K) f32
+                # matrix (all entries < 2^24, exact in f32) looked up per
+                # row by a one-hot contraction — no row gathers anywhere
+                src = jnp.where(valid, parent, L)      # L -> dropped
+                if has_bundle:
+                    col_w = bundle.group_of[f_w]
+                    goff_w = bundle.bin_off[f_w]
+                    adj_w = bundle.bin_adj[f_w]
+                    span_w = bundle.bin_span[f_w]
+                else:
+                    col_w = f_w
+                    goff_w = jnp.zeros(W, jnp.int32)
+                    adj_w = jnp.zeros(W, jnp.int32)
+                    span_w = jnp.full(W, num_bins, jnp.int32)
+                cols = jnp.stack([
+                    jnp.ones(W, jnp.float32),              # 0: active
+                    col_w.astype(jnp.float32),             # 1: device column
+                    thr_w.astype(jnp.float32),             # 2: threshold bin
+                    cat_w.astype(jnp.float32),             # 3: categorical
+                    fdef_w.astype(jnp.float32),            # 4: default bin
+                    dleft_w.astype(jnp.float32),           # 5: default left
+                    newleaf.astype(jnp.float32),           # 6: right leaf id
+                    goff_w.astype(jnp.float32),            # 7: group offset
+                    adj_w.astype(jnp.float32),             # 8: bin adjust
+                    span_w.astype(jnp.float32),            # 9: bin span
+                ], axis=-1)                                # (W, 10)
+                tbl = jnp.zeros((L, 10), jnp.float32).at[src].set(
+                    cols, mode="drop")
+                # compact-lookup operands: the W parent ids (invalid slots
+                # get -3, which no real/padded leaf id ever equals) and the
+                # raw (W, 10) rows — invalid rows can hold garbage, they
+                # never match
+                psrc = jnp.where(valid, parent, -3)
 
-            # ---- fused partition + children histograms (one sweep)
-            left_small = info[:, LEFT_COUNT] < info[:, RIGHT_COUNT]
-            small_id = jnp.where(left_small, parent, newleaf)
-            large_id = jnp.where(left_small, newleaf, parent)
+                # ---- fused partition + children histograms (one sweep)
+                left_small = info[:, LEFT_COUNT] < info[:, RIGHT_COUNT]
+                small_id = jnp.where(left_small, parent, newleaf)
+                large_id = jnp.where(left_small, newleaf, parent)
             if sparse_mode:
                 leaf_id, hist_small = sparse_wave_pass(
                     leaf_id, tbl, small_id, valid, col_w)
@@ -720,146 +758,160 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                                                 small_id, valid)
             hist_small = maybe_psum(hist_small)             # (W, F, B, 3)
             if cache_hists:
-                hist_large = hists[parent] - hist_small
+                with scope("wave_histogram"):
+                    hist_large = hists[parent] - hist_small
             else:
                 hist_large = maybe_psum(
                     sparse_child_hists(leaf_id, large_id, valid)
                     if sparse_mode else rehist(leaf_id, large_id, valid))
 
-            left_sums = jnp.stack([info[:, LEFT_SUM_G], info[:, LEFT_SUM_H],
-                                   info[:, LEFT_COUNT]], axis=-1)
-            right_sums = jnp.stack([info[:, RIGHT_SUM_G],
-                                    info[:, RIGHT_SUM_H],
-                                    info[:, RIGHT_COUNT]], axis=-1)
-            small_sums = jnp.where(left_small[:, None], left_sums,
-                                   right_sums)
-            large_sums = jnp.where(left_small[:, None], right_sums,
-                                   left_sums)
-
             # ---- vectorized split search for all 2W children
-            depth = tree.leaf_depth[parent] + 1             # (W,)
-            hists_k = jnp.concatenate([hist_small, hist_large])
-            sums_k = jnp.concatenate([small_sums, large_sums])
-            depths_k = jnp.concatenate([depth, depth])
-            bests_k = best_of_many(hists_k, sums_k, depths_k, feature_mask,
-                                   meta, bundle)            # (2W, V)
+            with scope("split_search"):
+                left_sums = jnp.stack([info[:, LEFT_SUM_G],
+                                       info[:, LEFT_SUM_H],
+                                       info[:, LEFT_COUNT]], axis=-1)
+                right_sums = jnp.stack([info[:, RIGHT_SUM_G],
+                                        info[:, RIGHT_SUM_H],
+                                        info[:, RIGHT_COUNT]], axis=-1)
+                small_sums = jnp.where(left_small[:, None], left_sums,
+                                       right_sums)
+                large_sums = jnp.where(left_small[:, None], right_sums,
+                                       left_sums)
+                depth = tree.leaf_depth[parent] + 1             # (W,)
+                hists_k = jnp.concatenate([hist_small, hist_large])
+                sums_k = jnp.concatenate([small_sums, large_sums])
+                depths_k = jnp.concatenate([depth, depth])
+                bests_k = best_of_many(hists_k, sums_k, depths_k,
+                                       feature_mask, meta, bundle)  # (2W, V)
 
-            if exact_order:
-                # ---- EXACT leaf-wise order: the candidates were ranked
-                # by pre-wave gain, so leaf-wise would commit them in rank
-                # order UNTIL a child created earlier in the wave outranks
-                # the next candidate (the reference would split that child
-                # next, serial_tree_learner.cpp:203).  Commit exactly that
-                # prefix; roll the rest back below.  Histograms are
-                # reduction-order-identical across wave widths, so trees
-                # match tpu_wave_width=1 (the pinned leaf-wise order)
-                # bit-for-bit (the per-candidate contractions below
-                # keep reductions W=1-shaped) — tests/test_wave_exact_order.py.
-                sg, lg = bests_k[:W, GAIN], bests_k[W:, GAIN]
-                cg = jnp.maximum(sg, lg)
-                cg = jnp.where(valid, cg, -jnp.inf)
-                # leaf id attaining each candidate's child max (ties ->
-                # smaller id, matching top_k's first-occurrence pick)
-                cid = jnp.where(
-                    (sg > lg) | ((sg == lg) & (small_id <= large_id)),
-                    small_id, large_id)
-                # running (max gain, smallest id attaining it) over the
-                # committed prefix — W=1's top_k breaks exact gain ties
-                # by LOWEST LEAF ID, so the stop rule must too
-                def pairmax(a, b):
-                    ga, ia = a
-                    gb, ib = b
-                    take_a = (ga > gb) | ((ga == gb) & (ia <= ib))
-                    return (jnp.where(take_a, ga, gb),
-                            jnp.where(take_a, ia, ib))
-                run, rid = lax.associative_scan(pairmax, (cg, cid))
-                mx = jnp.concatenate([jnp.full((1,), -jnp.inf, cg.dtype),
-                                      run[:-1]])              # before t
-                mid = jnp.concatenate([jnp.zeros((1,), cid.dtype),
-                                       rid[:-1]])
-                stop = (mx > gw) | ((mx == gw) & (mid < parent))  # (W,)
-                t_idx = jnp.where(jnp.any(stop),
-                                  jnp.argmax(stop).astype(jnp.int32),
-                                  jnp.asarray(W, jnp.int32))
-                kc = jnp.minimum(t_idx, k)
-                commit = rank < kc
-                # rollback: rows provisionally routed to an uncommitted
-                # right child return to the parent — ONE (L,)-table gather
-                # over leaf ids, no pass over X
-                undo = valid & ~commit
-                remap = jnp.arange(L, dtype=jnp.int32).at[
-                    jnp.where(undo, newleaf, L)].set(parent, mode="drop")
-                leaf_id = jnp.take(remap, leaf_id)
-            else:
-                commit, kc = valid, k
+            with scope("tree_commit"):
+                if exact_order:
+                    # ---- EXACT leaf-wise order: the candidates were
+                    # ranked by pre-wave gain, so leaf-wise would commit
+                    # them in rank order UNTIL a child created earlier in
+                    # the wave outranks the next candidate (the reference
+                    # would split that child next,
+                    # serial_tree_learner.cpp:203).  Commit exactly that
+                    # prefix; roll the rest back below.  Histograms are
+                    # reduction-order-identical across wave widths, so
+                    # trees match tpu_wave_width=1 (the pinned leaf-wise
+                    # order) bit-for-bit (the per-candidate contractions
+                    # below keep reductions W=1-shaped) —
+                    # tests/test_wave_exact_order.py.
+                    sg, lg = bests_k[:W, GAIN], bests_k[W:, GAIN]
+                    cg = jnp.maximum(sg, lg)
+                    cg = jnp.where(valid, cg, -jnp.inf)
+                    # leaf id attaining each candidate's child max (ties ->
+                    # smaller id, matching top_k's first-occurrence pick)
+                    cid = jnp.where(
+                        (sg > lg) | ((sg == lg) & (small_id <= large_id)),
+                        small_id, large_id)
+                    # running (max gain, smallest id attaining it) over the
+                    # committed prefix — W=1's top_k breaks exact gain ties
+                    # by LOWEST LEAF ID, so the stop rule must too
+                    def pairmax(a, b):
+                        ga, ia = a
+                        gb, ib = b
+                        take_a = (ga > gb) | ((ga == gb) & (ia <= ib))
+                        return (jnp.where(take_a, ga, gb),
+                                jnp.where(take_a, ia, ib))
+                    run, rid = lax.associative_scan(pairmax, (cg, cid))
+                    mx = jnp.concatenate([jnp.full((1,), -jnp.inf, cg.dtype),
+                                          run[:-1]])              # before t
+                    mid = jnp.concatenate([jnp.zeros((1,), cid.dtype),
+                                           rid[:-1]])
+                    stop = (mx > gw) | ((mx == gw) & (mid < parent))  # (W,)
+                    t_idx = jnp.where(jnp.any(stop),
+                                      jnp.argmax(stop).astype(jnp.int32),
+                                      jnp.asarray(W, jnp.int32))
+                    kc = jnp.minimum(t_idx, k)
+                    commit = rank < kc
+                    # rollback: rows provisionally routed to an
+                    # uncommitted right child return to the parent — ONE
+                    # (L,)-table gather over leaf ids, no pass over X
+                    undo = valid & ~commit
+                    remap = jnp.arange(L, dtype=jnp.int32).at[
+                        jnp.where(undo, newleaf, L)].set(parent, mode="drop")
+                    leaf_id = jnp.take(remap, leaf_id)
+                else:
+                    commit, kc = valid, k
 
-            if cache_hists:
-                hsrc = jnp.where(commit, small_id, L)
-                hists = hists.at[hsrc].set(hist_small, mode="drop")
-                lsrc = jnp.where(commit, large_id, L)
-                hists = hists.at[lsrc].set(hist_large, mode="drop")
-            ssrc = jnp.where(commit, small_id, L)
-            lsrc2 = jnp.where(commit, large_id, L)
-            bests = bests.at[ssrc].set(bests_k[:W], mode="drop")
-            bests = bests.at[lsrc2].set(bests_k[W:], mode="drop")
-            sums = sums.at[ssrc].set(small_sums, mode="drop")
-            sums = sums.at[lsrc2].set(large_sums, mode="drop")
+                if cache_hists:
+                    hsrc = jnp.where(commit, small_id, L)
+                    hists = hists.at[hsrc].set(hist_small, mode="drop")
+                    lsrc = jnp.where(commit, large_id, L)
+                    hists = hists.at[lsrc].set(hist_large, mode="drop")
+                ssrc = jnp.where(commit, small_id, L)
+                lsrc2 = jnp.where(commit, large_id, L)
+                bests = bests.at[ssrc].set(bests_k[:W], mode="drop")
+                bests = bests.at[lsrc2].set(bests_k[W:], mode="drop")
+                sums = sums.at[ssrc].set(small_sums, mode="drop")
+                sums = sums.at[lsrc2].set(large_sums, mode="drop")
 
-            # ---- tree bookkeeping, vectorized over the wave
-            nsrc = jnp.where(commit, node, L - 1 + 64)      # drop sentinel
-            tparent = tree.leaf_parent[parent]              # (W,)
-            # grandparent child-pointer fix: each split's (parent node,
-            # side) slot is unique, so the W scatters cannot collide
-            gp = jnp.maximum(tparent, 0)
-            was_left = tree.left_child[gp] == ~parent
-            fix = commit & (tparent >= 0)
-            lc = tree.left_child.at[jnp.where(fix & was_left, gp, L + 63)
-                                    ].set(node, mode="drop")
-            rc = tree.right_child.at[jnp.where(fix & ~was_left, gp, L + 63)
-                                     ].set(node, mode="drop")
-            lc = lc.at[nsrc].set(~parent, mode="drop")
-            rc = rc.at[nsrc].set(~newleaf, mode="drop")
-            lsrc3 = jnp.where(commit, parent, L)
-            rsrc3 = jnp.where(commit, newleaf, L)
-            tree = tree._replace(
-                num_leaves=tree.num_leaves + kc,
-                split_feature=tree.split_feature.at[nsrc].set(
-                    f_w, mode="drop"),
-                threshold_bin=tree.threshold_bin.at[nsrc].set(
-                    thr_w, mode="drop"),
-                default_bin_for_zero=tree.default_bin_for_zero.at[nsrc].set(
-                    dbz_w, mode="drop"),
-                default_bin=tree.default_bin.at[nsrc].set(
-                    fdef_w, mode="drop"),
-                is_cat=tree.is_cat.at[nsrc].set(
-                    cat_w.astype(jnp.int32), mode="drop"),
-                left_child=lc,
-                right_child=rc,
-                split_gain=tree.split_gain.at[nsrc].set(
-                    info[:, GAIN], mode="drop"),
-                internal_value=tree.internal_value.at[nsrc].set(
-                    tree.leaf_value[parent], mode="drop"),
-                internal_count=tree.internal_count.at[nsrc].set(
-                    (info[:, LEFT_COUNT]
-                     + info[:, RIGHT_COUNT]).astype(jnp.int32),
-                    mode="drop"),
-                leaf_parent=tree.leaf_parent.at[lsrc3].set(
-                    node, mode="drop").at[rsrc3].set(node, mode="drop"),
-                leaf_value=tree.leaf_value.at[lsrc3].set(
-                    info[:, LEFT_OUTPUT], mode="drop").at[rsrc3].set(
-                        info[:, RIGHT_OUTPUT], mode="drop"),
-                leaf_count=tree.leaf_count.at[lsrc3].set(
-                    info[:, LEFT_COUNT].astype(jnp.int32),
-                    mode="drop").at[rsrc3].set(
-                        info[:, RIGHT_COUNT].astype(jnp.int32), mode="drop"),
-                leaf_depth=tree.leaf_depth.at[lsrc3].set(
-                    depth, mode="drop").at[rsrc3].set(depth, mode="drop"),
-                second_feature=tree.second_feature.at[nsrc].set(
-                    info[:, SECOND_FEATURE].astype(jnp.int32), mode="drop"),
-                second_gain=tree.second_gain.at[nsrc].set(
-                    jnp.where(jnp.isfinite(info[:, SECOND_GAIN]),
-                              info[:, SECOND_GAIN], 0.0), mode="drop"),
-            )
+                # ---- tree bookkeeping, vectorized over the wave
+                nsrc = jnp.where(commit, node, L - 1 + 64)      # drop sentinel
+                tparent = tree.leaf_parent[parent]              # (W,)
+                # grandparent child-pointer fix: each split's (parent node,
+                # side) slot is unique, so the W scatters cannot collide
+                gp = jnp.maximum(tparent, 0)
+                was_left = tree.left_child[gp] == ~parent
+                fix = commit & (tparent >= 0)
+                lc = tree.left_child.at[jnp.where(fix & was_left, gp, L + 63)
+                                        ].set(node, mode="drop")
+                rc = tree.right_child.at[jnp.where(fix & ~was_left, gp, L + 63)
+                                         ].set(node, mode="drop")
+                lc = lc.at[nsrc].set(~parent, mode="drop")
+                rc = rc.at[nsrc].set(~newleaf, mode="drop")
+                lsrc3 = jnp.where(commit, parent, L)
+                rsrc3 = jnp.where(commit, newleaf, L)
+                tree = tree._replace(
+                    num_leaves=tree.num_leaves + kc,
+                    split_feature=tree.split_feature.at[nsrc].set(
+                        f_w, mode="drop"),
+                    threshold_bin=tree.threshold_bin.at[nsrc].set(
+                        thr_w, mode="drop"),
+                    default_bin_for_zero=tree.default_bin_for_zero.at[
+                        nsrc].set(dbz_w, mode="drop"),
+                    default_bin=tree.default_bin.at[nsrc].set(
+                        fdef_w, mode="drop"),
+                    is_cat=tree.is_cat.at[nsrc].set(
+                        cat_w.astype(jnp.int32), mode="drop"),
+                    left_child=lc,
+                    right_child=rc,
+                    split_gain=tree.split_gain.at[nsrc].set(
+                        info[:, GAIN], mode="drop"),
+                    internal_value=tree.internal_value.at[nsrc].set(
+                        tree.leaf_value[parent], mode="drop"),
+                    internal_count=tree.internal_count.at[nsrc].set(
+                        (info[:, LEFT_COUNT]
+                         + info[:, RIGHT_COUNT]).astype(jnp.int32),
+                        mode="drop"),
+                    leaf_parent=tree.leaf_parent.at[lsrc3].set(
+                        node, mode="drop").at[rsrc3].set(node, mode="drop"),
+                    leaf_value=tree.leaf_value.at[lsrc3].set(
+                        info[:, LEFT_OUTPUT], mode="drop").at[rsrc3].set(
+                            info[:, RIGHT_OUTPUT], mode="drop"),
+                    leaf_count=tree.leaf_count.at[lsrc3].set(
+                        info[:, LEFT_COUNT].astype(jnp.int32),
+                        mode="drop").at[rsrc3].set(
+                            info[:, RIGHT_COUNT].astype(jnp.int32),
+                            mode="drop"),
+                    leaf_depth=tree.leaf_depth.at[lsrc3].set(
+                        depth, mode="drop").at[rsrc3].set(depth, mode="drop"),
+                    second_feature=tree.second_feature.at[nsrc].set(
+                        info[:, SECOND_FEATURE].astype(jnp.int32),
+                        mode="drop"),
+                    second_gain=tree.second_gain.at[nsrc].set(
+                        jnp.where(jnp.isfinite(info[:, SECOND_GAIN]),
+                                  info[:, SECOND_GAIN], 0.0), mode="drop"),
+                    counters=tree.counters + jnp.stack([
+                        jnp.asarray(1, jnp.int32), jnp.asarray(W, jnp.int32),
+                        k, kc,
+                        jnp.sum(jnp.where(commit, jnp.minimum(
+                            info[:, LEFT_COUNT],
+                            info[:, RIGHT_COUNT]).astype(jnp.int32), 0)),
+                        jnp.asarray(0, jnp.int32)]),
+                )
             return (nn + kc, kc == 0, leaf_id, hists, bests, sums, tree)
 
         carry = (jnp.asarray(0, jnp.int32), jnp.asarray(False), leaf_id,

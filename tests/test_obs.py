@@ -355,7 +355,9 @@ def test_bench_dry_smoke():
 def test_disabled_path_allocates_no_event_objects():
     """With telemetry off, training must not touch the obs subsystem:
     no observer construction, no fencing, no per-iteration allocations
-    attributable to lightgbm_tpu/obs."""
+    attributable to lightgbm_tpu/obs -- but for the span ring of
+    obs/timers.py, which is always on and bounded: two records an
+    iteration (`iteration`, `dispatch`)."""
     import tracemalloc
     X, y = _data()
     bst = lgb.Booster(params={"objective": "binary", "num_leaves": 7,
@@ -375,6 +377,10 @@ def test_disabled_path_allocates_no_event_objects():
         tracemalloc.stop()
     obs_allocs = snap.filter_traces(
         [tracemalloc.Filter(True, os.path.join(obs_dir, "*"))])
-    assert sum(st.size for st in obs_allocs.statistics("filename")) == 0
+    by_file = {st.traceback[0].filename: st.size
+               for st in obs_allocs.statistics("filename")}
+    ring = by_file.pop(os.path.join(obs_dir, "timers.py"), 0)
+    assert by_file == {}
+    assert 0 < ring <= 3 * 2 * 1024
     assert NULL_OBSERVER.timeline == ()
     assert NULL_OBSERVER.entry_start() == 0.0
