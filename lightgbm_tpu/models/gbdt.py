@@ -469,9 +469,11 @@ class GBDT:
             # the grow loop's counters came with the tree: one record a
             # tree, under the iteration that grew it
             c = dict(zip(timers.COUNTERS, (int(v) for v in ht.counters)))
+            # a wave that ran no row slab visited every row
+            c["kernel_rows"] += (c["waves"] - c["compacted"]) * c["rows"]
             it, tid = self._tree_iteration(i)
             timers.count("tree", it=it, tree=tid,
-                         rows_visited=(c["waves"] + 1) * c["rows"], **c)
+                         rows_visited=c["rows"] + c["kernel_rows"], **c)
         if self._metrics is not None:
             # host num_leaves is free here — trees just landed on host
             self._metrics["leaves"].inc(

@@ -311,6 +311,7 @@ def render_summary(events, out=None):
           % (e.get("mode", "?"), e.get("source", "?"),
              c.get("hist_mode", "?"), c.get("wave_width", "?"),
              "hilo" if c.get("hist_hilo", True) else "bf16",
+             # timelines from before the row slab carry the old knob
              " compact" if c.get("compact") else ""))
     rr = m.get("rank_report")
     if rr:

@@ -217,12 +217,17 @@ RING_SIZE = 4096
 # the jax.named_scope names inside the step program (ops/fused_iter.py,
 # ops/wave.py); an HLO instruction belongs to the first of these found in
 # its op_name, or to UNSCOPED
-SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_histogram",
-          "hist_allreduce", "split_search", "tree_commit", "score_update")
+SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_compact",
+          "wave_histogram", "hist_allreduce", "split_search", "tree_commit",
+          "score_update")
 UNSCOPED = "unscoped"
-# the grow loop's counter vector (ops/wave.py), in order; a tree's record
-# in the ring adds ``rows_visited = (waves + 1) * rows`` as a host integer
-COUNTERS = ("waves", "slots", "attempted", "committed", "hist_rows", "rows")
+# the grow loop's counter vector (ops/wave.py), in order.  On the device
+# `kernel_rows` holds what the row-slab launches visited (`compacted`
+# waves); a tree's record in the ring (models/gbdt.py) adds the other
+# waves' `rows` each and ``rows_visited = rows + kernel_rows``, as host
+# integers: waves x rows passes int32 at real sizes
+COUNTERS = ("waves", "slots", "attempted", "committed", "hist_rows", "rows",
+            "kernel_rows", "compacted")
 # jax.monitoring durations that become child spans of the open span
 _JAX_DURATIONS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",

@@ -8,7 +8,7 @@ of pre-registered but never-applied promotion rules (BENCH_NOTES.md
 "Armed decks").  This module inverts that architecture: selection is a
 single decision function (`decide`) that treats the old heuristics as
 the *prior*, enumerates the 3-6 viable (hist_kernel, wave_width,
-precision, compaction, fused-iteration) cells for the actual shape, microbenches each
+precision, fused-iteration) cells for the actual shape, microbenches each
 cell for a few waves on the real device with real-shaped data, picks
 the winner, and persists it in an on-disk cache keyed by
 (shape-bucket, device-kind, schema rev) next to the XLA compile cache
@@ -270,7 +270,6 @@ class Cell(NamedTuple):
     hist_mode: str      # pallas_t / pallas_ct
     wave_width: int     # W
     hist_hilo: bool     # True = hi/lo f32 pair, False = single-bf16
-    compact: bool       # frontier compaction (tpu_wave_compact)
     # rev 2: run the whole iteration as one fused device program
     # (ops/fused_iter.py) instead of the staged gradient/grow/score
     # entry chain — a measured dimension because fusion trades XLA
@@ -281,14 +280,14 @@ class Cell(NamedTuple):
         return {"hist_mode": self.hist_mode,
                 "wave_width": int(self.wave_width),
                 "hist_hilo": bool(self.hist_hilo),
-                "compact": bool(self.compact),
                 "fused": bool(self.fused)}
 
     @classmethod
     def from_dict(cls, d: dict) -> "Cell":
+        # an older cache's "compact" key is ignored: the row slab is not
+        # a dimension (ops/wave.py decides it a wave from a row count)
         return cls(str(d["hist_mode"]), int(d["wave_width"]),
-                   bool(d["hist_hilo"]), bool(d["compact"]),
-                   bool(d.get("fused", False)))
+                   bool(d["hist_hilo"]), bool(d.get("fused", False)))
 
 
 class ShapeBucket(NamedTuple):
@@ -313,7 +312,6 @@ class Pins(NamedTuple):
     kernel: bool = False
     width: bool = False
     precision: bool = False
-    compact: bool = False
     fused: bool = False
 
 
@@ -413,7 +411,6 @@ def apply_pins(cell: Cell, prior: Cell, pins: Pins) -> Cell:
         hist_mode=prior.hist_mode if pins.kernel else cell.hist_mode,
         wave_width=prior.wave_width if pins.width else cell.wave_width,
         hist_hilo=prior.hist_hilo if pins.precision else cell.hist_hilo,
-        compact=prior.compact if pins.compact else cell.compact,
         fused=prior.fused if pins.fused else cell.fused)
 
 
@@ -426,12 +423,12 @@ def enumerate_cells(prior: Cell, bucket: ShapeBucket, pins: Pins,
     or down (the band pathology and the W-ladder cap were both
     width-tier effects), the alternate transposed kernel (the round-5
     "ct-bound widening" arm — ct beyond 2560 is a candidate here, not
-    a dead comment), the flipped precision (the bf16 armed deck), and
-    compaction-on (the compaction auto-on armed deck).  The prior is
-    always candidate 0 so a tie keeps the measured-by-default choice.
+    a dead comment) and the flipped precision (the bf16 armed deck).
+    The prior is always candidate 0 so a tie keeps the
+    measured-by-default choice.
     """
     if prior.hist_mode not in WAVE_ONLY_MODES:
-        # width/precision/compaction are wave-kernel dimensions; other
+        # width and precision are wave-kernel dimensions; other
         # engines have no neighbours to probe
         return [prior]
     cands: List[Cell] = [prior]
@@ -454,8 +451,6 @@ def enumerate_cells(prior: Cell, bucket: ShapeBucket, pins: Pins,
             cands.append(prior._replace(hist_mode=alt))
     if not pins.precision:
         cands.append(prior._replace(hist_hilo=not prior.hist_hilo))
-    if not pins.compact and not prior.compact:
-        cands.append(prior._replace(compact=True))
     out: List[Cell] = []
     for c in cands:
         if c in out:

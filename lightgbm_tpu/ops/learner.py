@@ -309,21 +309,6 @@ class SerialTreeLearner:
         # tests/test_fused_iter.py, post-mortem in
         # docs/FusedIteration.md).  Old timelines may still carry
         # wave_band_escape events; the schema keeps accepting them.
-        if bool(config.tpu_wave_compact):
-            from .wave import pallas_wave_active as _pwa2
-            if not (growth == "wave"
-                    and self.hist_mode in ("pallas_ct", "pallas_t")
-                    and _pwa2(self.hist_mode, self.dtype)):
-                # explicit opt-ins must not be dropped silently (same
-                # policy as tpu_sparse / tpu_bin_pack); the kernel gate
-                # (_pwa2) also covers non-TPU backends and f64
-                Log.warning("tpu_wave_compact=true ignored: requires "
-                            "wave growth with a transposed Pallas wave "
-                            "kernel (pallas_ct/pallas_t) on TPU with "
-                            "f32 accumulation (resolved growth=%s, "
-                            "histogram mode=%s, backend=%s)",
-                            growth, self.hist_mode,
-                            jax.default_backend())
         hp = str(config.tpu_hist_precision).strip().lower()
         if hp not in ("auto", "hilo", "bf16"):
             Log.fatal("Unknown tpu_hist_precision %s (expected auto/"
@@ -337,10 +322,6 @@ class SerialTreeLearner:
                                              self.hist_mode, self.dtype)
         else:
             self.hist_hilo = hp != "bf16"
-        # resolved compaction flag — a plain config passthrough today,
-        # but an autotune-tunable dimension, so it lives on the learner
-        # (the wave jit below reads THIS, never the raw config)
-        self.wave_compact = bool(config.tpu_wave_compact)
         lk = str(config.tpu_wave_lookup).strip().lower()
         # validate unconditionally (like tpu_histogram_mode): a typo'd
         # value must not be silently ignored just because growth resolved
@@ -525,8 +506,8 @@ class SerialTreeLearner:
                         "compiled Pallas kernels run)")
             self.pallas_interpret = False
         # ---- measured kernel autotune (ops/autotune.py).  Everything
-        # resolved above — hist_mode, wave_width, hist_hilo,
-        # wave_compact — is the heuristic PRIOR; under
+        # resolved above — hist_mode, wave_width, hist_hilo — is the
+        # heuristic PRIOR; under
         # tpu_autotune=measure/force on a real device, decide() probes
         # the 3-5 candidate cells for this shape bucket on the uploaded
         # bin matrix and the measured winner overrides the prior (the
@@ -538,8 +519,7 @@ class SerialTreeLearner:
                                    int(self.num_leaves),
                                    _at.row_bucket(train_data.num_data))
         at_prior = _at.Cell(self.hist_mode, int(self.wave_width),
-                            bool(self.hist_hilo), self.wave_compact,
-                            fused=False)
+                            bool(self.hist_hilo), fused=False)
         at_pins = _at.Pins(
             # pins = explicit user choices + quality gates, never tuned
             kernel=str(config.tpu_histogram_mode) != "auto",
@@ -547,7 +527,6 @@ class SerialTreeLearner:
                    or (_order_sensitive(config)
                        and self.wave_order != "exact")),
             precision=hp != "auto",
-            compact="tpu_wave_compact" in config.raw,
             # an explicit tpu_fused_iter=on/off is a user decision the
             # tuner must not second-guess; auto leaves the staged/fused
             # flip a measured dimension (rev-2 cells)
@@ -570,7 +549,14 @@ class SerialTreeLearner:
             self.hist_mode = hist_mode = dec.cell.hist_mode
             self.wave_width = int(dec.cell.wave_width)
             self.hist_hilo = bool(dec.cell.hist_hilo)
-            self.wave_compact = bool(dec.cell.compact)
+        # whether a wave's histogram launch reads the row slab of its
+        # smaller children (ops/wave.py slab_active): not a key, decided
+        # from the kernel, the store and the execution that resolved
+        from .wave import slab_active
+        self.wave_compact = (growth == "wave" and not sparse_on
+                             and slab_active(True, hist_mode, self.dtype,
+                                             psum_axis,
+                                             self.pallas_interpret))
         # Ordered-partition growth (grow.py): per-split cost is O(parent
         # segment) for the partition and O(child segment * F) for the
         # histogram — the reference's DataPartition + ordered-iteration
@@ -596,8 +582,8 @@ class SerialTreeLearner:
                 self.cache_hists, hist_mode,
                 int(config.tpu_wave_chunk), self.packed_cols,
                 self.sparse_col_cap, self.wave_order == "exact",
-                self.wave_lookup, self.hist_hilo,
-                self.wave_compact, self.pallas_interpret)
+                self.wave_lookup, self.hist_hilo, True,
+                self.pallas_interpret)
             meta, bund = self.meta, self.bundle_arrays
             # the transposed kernel's (F, N) matrix: materialized ONCE per
             # booster (X never changes across trees), not per dispatch;
@@ -699,8 +685,8 @@ class SerialTreeLearner:
                 self.cache_hists, cell.hist_mode,
                 int(config.tpu_wave_chunk), self.packed_cols,
                 self.sparse_col_cap, self.wave_order == "exact",
-                self.wave_lookup, bool(cell.hist_hilo),
-                bool(cell.compact), self.pallas_interpret)
+                self.wave_lookup, bool(cell.hist_hilo), True,
+                self.pallas_interpret)
             xt = (jnp.transpose(self.X)
                   if transposed_wave_active(cell.hist_mode, self.dtype)
                   else None)

@@ -335,27 +335,48 @@ def wave_histogram_reference(X, leaf_id, w3, child_id, num_bins: int):
 # scan keeps the row-major X; X_t is a one-time device-side copy.
 # --------------------------------------------------------------------------
 
-def _wave_hist_kernel_t(xt_ref, lid_ref, w3_ref, cid_ref, out_ref,
-                        *, bp, fc, k, bsub, packed, hilo=True):
+def _wave_hist_kernel_t(*refs, bp, fc, bsub, packed, hilo=True):
+    """refs: [tiles,] xt, lid, w3, cid, out.  With the scalar-prefetched
+    `tiles` (a row slab whose rows past the first `tiles[0]` tiles are
+    fill: leaf -2, weight 0) those grid steps add nothing, so their body
+    is skipped, and the wrapper's clamped index maps fetch no new block
+    for them."""
+    *tiles, xt_ref, lid_ref, w3_ref, cid_ref, out_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    xi = xt_ref[:].astype(jnp.int32)                 # (Fdev, Cg)
-    if packed:
-        xi = _unpack4_t(xi, fc)
-    xt = xi.astype(jnp.float32)                      # (Fc, Cg)
-    cg = xt.shape[1]
+    def tile():
+        xi = xt_ref[:].astype(jnp.int32)                 # (Fdev, Cg)
+        if packed:
+            xi = _unpack4_t(xi, fc)
+        xt = xi.astype(jnp.float32)                      # (Fc, Cg)
+        cg = xt.shape[1]
 
-    wh, wl = _split_weights_t(lid_ref, w3_ref, cid_ref, hilo)  # (3K, Cg)
+        wh, wl = _split_weights_t(lid_ref, w3_ref, cid_ref, hilo)  # (3K, Cg)
 
-    xr = pltpu.repeat(xt, bsub, axis=0)              # (bsub*Fc, Cg) tiled
-    base = (jax.lax.broadcasted_iota(jnp.int32, (bsub * fc, cg), 0)
-            // fc).astype(jnp.float32)               # bin-within-subblock
-    _accum_hist(out_ref, xr, base, wh, wl, bp=bp, fc=fc, bsub=bsub,
-                dims=(((1,), (1,)), ((), ())))       # A @ B^T — both Cg
+        xr = pltpu.repeat(xt, bsub, axis=0)              # (bsub*Fc, Cg) tiled
+        base = (jax.lax.broadcasted_iota(jnp.int32, (bsub * fc, cg), 0)
+                // fc).astype(jnp.float32)               # bin-within-subblock
+        _accum_hist(out_ref, xr, base, wh, wl, bp=bp, fc=fc, bsub=bsub,
+                    dims=(((1,), (1,)), ((), ())))       # A @ B^T — both Cg
+
+    if tiles:
+        pl.when(i < tiles[0][0])(tile)
+    else:
+        tile()
+
+
+def slab_plan(n, fc, num_bins, k, packed=False, row_tile=8192):
+    """``(cap, c)`` of the wave's row slab (ops/wave.py): half of the n
+    rows, rounded up to the row tile c that the slab's own launch plans,
+    so that launch needs no pad."""
+    half = -(-n // 2)
+    _, c = _tile_plan(half, fc, _bin_pad(num_bins), row_tile, k=k,
+                      packed=packed)
+    return -(-half // c) * c, c
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "row_tile",
@@ -363,9 +384,16 @@ def _wave_hist_kernel_t(xt_ref, lid_ref, w3_ref, cid_ref, out_ref,
                                              "hilo"))
 def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
                             row_tile: int = 8192, interpret: bool = False,
-                            logical_cols: int = 0, hilo: bool = True):
+                            logical_cols: int = 0, hilo: bool = True,
+                            n_active=None):
     """Same contract as wave_histogram_pallas, but takes the TRANSPOSED bin
-    matrix X_t (F, N) (packed: (ceil(F/2), N) with logical_cols set)."""
+    matrix X_t (F, N) (packed: (ceil(F/2), N) with logical_cols set).
+
+    n_active (a traced int32 scalar): the caller promises that every row
+    from n_active on is fill (leaf id matching no child, or weight 0), as
+    in the row slab of ops/wave.py.  The grid keeps its static length;
+    the tiles past ceil(n_active / c) are neither fetched nor computed.
+    That changes no sum, only the time."""
     fdev, n = X_t.shape
     fc = logical_cols or fdev
     k = child_id.shape[0]
@@ -382,31 +410,41 @@ def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
         w3t = jnp.pad(w3t, ((0, 0), (0, pad)))
     nch = (n + pad) // c
 
-    kernel = functools.partial(_wave_hist_kernel_t, bp=bp, fc=fc, k=k,
+    kernel = functools.partial(_wave_hist_kernel_t, bp=bp, fc=fc,
                                bsub=bsub, packed=bool(logical_cols),
                                hilo=hilo)
     operands = (X_t, lid2, w3t, child_id[:, None])
+    if n_active is None:
+        prefetch = ()
+
+        def row_block(i):
+            return 0, i
+    else:
+        tiles = (jnp.asarray(n_active, jnp.int32) + (c - 1)) // c
+        prefetch = (jnp.clip(tiles, 0, nch).reshape(1),)
+
+        def row_block(i, t):    # past the last full tile: stay on it
+            return 0, jnp.minimum(i, jnp.maximum(t[0] - 1, 0))
+
+    specs = dict(
+        grid=(nch,),
+        in_specs=[pl.BlockSpec(shape, row_block, memory_space=pltpu.VMEM)
+                  for shape in ((fdev, c), (1, c), (3, c))]
+        + [pl.BlockSpec((k, 1), lambda i, *t: (0, 0),
+                        memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((fc * bp, 3 * k), lambda i, *t: (0, 0),
+                               memory_space=pltpu.VMEM))
+    if prefetch:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
     flat = pl.pallas_call(
         kernel,
         name="wave_histogram_pallas_t",
-        grid=(nch,),
-        in_specs=[
-            pl.BlockSpec((fdev, c), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, c), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, c), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((fc * bp, 3 * k), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
         out_shape=vma_struct((fc * bp, 3 * k), jnp.float32, *operands),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(*operands)
+        **specs)(*prefetch, *operands)
     h = flat.reshape(bp, fc, 3, k)[:num_bins]
     return jnp.transpose(h, (3, 1, 0, 2))
 

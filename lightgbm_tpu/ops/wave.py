@@ -114,6 +114,17 @@ def transposed_wave_active(hist_mode: str, hist_dtype=jnp.float32) -> bool:
             and pallas_wave_active(hist_mode, hist_dtype))
 
 
+def slab_active(compact: bool, hist_mode: str, hist_dtype, psum_axis,
+                pallas_interpret: bool = False) -> bool:
+    """True when a wave's histogram launch reads the row slab of its
+    smaller children in place of all N rows: the pallas_t kernel really
+    runs (or its interpreter), on one device.  Decided from what the
+    program can observe; the serial learner reports it (obs_info)."""
+    runs = pallas_wave_active(hist_mode, hist_dtype) or (
+        pallas_interpret and hist_dtype == jnp.float32)
+    return bool(compact and hist_mode == "pallas_t" and runs
+                and psum_axis is None)
+
 
 def make_wave_grow_fn(num_leaves: int, num_bins: int, meta: FeatureMeta,
                       params: SplitParams, max_depth: int,
@@ -124,7 +135,7 @@ def make_wave_grow_fn(num_leaves: int, num_bins: int, meta: FeatureMeta,
                       packed_cols: int = 0, sparse_col_cap: int = 0,
                       with_xt: bool = False, exact_order: bool = False,
                       lookup: str = "onehot", hist_hilo: bool = True,
-                      compact: bool = False,
+                      compact: bool = True,
                       pallas_interpret: bool = False):
     """Bind meta/bundle onto the cached wave-grow program (same contract as
     ops/grow.make_grow_fn: grow(X, grad, hess, row_mult, feature_mask) ->
@@ -169,7 +180,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                    cache_hists: bool, hist_mode: str, chunk: int,
                    packed_cols: int = 0, sparse_col_cap: int = 0,
                    exact_order: bool = False, lookup: str = "onehot",
-                   hist_hilo: bool = True, compact: bool = False,
+                   hist_hilo: bool = True, compact: bool = True,
                    pallas_interpret: bool = False):
     """packed_cols > 0: X is 4-bit packed (ops/pack.py, two columns per
     byte) and packed_cols is the LOGICAL column count; every chunk is
@@ -206,8 +217,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
     # their end-to-end win is validated; precision is handled by the bf16
     # hi/lo weight split (manual rounding — Mosaic's cast truncates).
     # pallas_interpret=True (tests only) runs the Pallas kernels in
-    # interpret mode on any backend, so the ct engine path — including
-    # spectator-row compaction — is CPU-testable end-to-end
+    # interpret mode on any backend, so the kernel engine paths — the
+    # row slab included — are CPU-testable end-to-end
     use_pallas_hist = pallas_wave_active(hist_mode, hist_dtype) or (
         pallas_interpret and hist_dtype == jnp.float32
         and hist_mode in ("pallas",) + WAVE_ONLY_MODES)
@@ -217,14 +228,13 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
     # on-chip A/B to pallas_t (tools/AB_RESULTS.md, BENCH_NOTES.md r4)
     pallas_transposed = hist_mode in ("pallas_t", "pallas_ct")
     pallas_fused = hist_mode == "pallas_ct"
-    # spectator-row compaction rides the transposed kernels (the fused
-    # ct tier calls the fused kernel; the t tier runs a vectorized
-    # partition over the gathered slab then the t kernel), and only
-    # under serial execution (per-shard divergent tier choices inside
-    # shard_map would be legal — no collectives in the branches — but
-    # have no measurement yet)
-    compact = bool(compact and pallas_transposed and use_pallas_hist
-                   and psum_axis is None)
+    # the row slab (slab_hist below) serves the split pipeline, partition
+    # scan then pallas_t, in one program on one device: the fused ct
+    # kernel routes and histograms in one read, and a mesh shard's slab
+    # has no measurement yet.  `compact` is no knob (no config key
+    # reaches it): False lets a test grow the same tree without the slab
+    compact = slab_active(compact, hist_mode, hist_dtype, psum_axis,
+                          pallas_interpret)
 
     def maybe_psum(x):
         if psum_axis is not None:
@@ -272,11 +282,31 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             with scope("wave_partition"):
                 Xp = jnp.pad(X, ((0, pad), (0, 0))) if pad else X
                 xb = Xp.reshape(nch, c, Fdev)
+        # ---- the row slab: a wave keeps the histograms of its smaller
+        # children only, and by count those hold at most half the rows
+        # (0.28 N on average over a 255-leaf tree).  The partition has
+        # already run when the kernel starts, so the rows it needs are
+        # known: gather them, in row order, into a slab of N/2 rows and
+        # let the kernel stop at the slab's last full tile — the
+        # reference's leaf-ordered economics (touch only the rows of the
+        # leaves being split, ordered_sparse_bin.hpp:26-209) with ONE
+        # static shape.  cap is the slab launch's own tile multiple, so
+        # that launch pads nothing.
+        slab_cap = slab_tile = 0
+        if compact and not sparse_mode:
+            from .pallas_wave import slab_plan
+            slab_cap, slab_tile = slab_plan(n, Fc, hist_bins, W,
+                                            packed=bool(packed_cols))
+            if slab_cap >= n:          # a single row: nothing to skip
+                slab_cap = 0
         # transposed matrix for the v2 kernel (MXU-native dot orientation):
         # callers that hold X for many trees pass a precomputed Xt (the
         # learner materializes it once per booster); otherwise fall back to
-        # one (F, N) materialization per tree dispatch
-        if use_pallas_hist and pallas_transposed and Xt is None:
+        # one (F, N) materialization per tree dispatch.  The slab is
+        # gathered from the row-major X: with it only the no-cache
+        # `rehist` reads Xt
+        if (use_pallas_hist and pallas_transposed and Xt is None
+                and not (slab_cap and cache_hists)):
             with scope("wave_histogram"):
                 Xt = jnp.transpose(X)
 
@@ -357,6 +387,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 new_lid = route_rows(r, colv, lid)
             return new_lid, sparse_child_hists(new_lid, small_id, valid)
 
+        no_slab = jnp.zeros(2, jnp.int32)   # a wave that ran no slab
+
         @scope("wave_histogram")
         def pallas_hist(lid, cid):
             """Dispatch to the fused kernel in the configured layout —
@@ -397,6 +429,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             generated in VMEM, ops/pallas_wave.py) and the scan below
             only partitions; 'pallas_ct' fuses BOTH halves into one
             kernel — a single read of Xt per wave.
+
+            Returns (new leaf ids, (W, Fc, B, 3) histograms, the slabs'
+            two counts: rows their launches visited and 1 if they ran).
             """
             if use_pallas_hist and pallas_fused:
                 from .pallas_wave import wave_partition_hist_pallas_ct
@@ -407,7 +442,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.where(valid, small_id, -1), cols, psrc,
                         hist_bins, bundled=has_bundle,
                         logical_cols=packed_cols, hilo=hist_hilo,
-                        interpret=pallas_interpret)
+                        interpret=pallas_interpret) + (no_slab,)
             with scope("wave_partition"):
                 lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
                     else leaf_id.reshape(nch, c)
@@ -467,120 +502,84 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                     init = vary_like(init, xb, lb, wb3)
                 flat, lid2 = lax.scan(step, init, (xb, lb, wb3))
                 new_leaf_id = lid2.reshape(-1)[:n]
+            slab = no_slab
             if use_pallas_hist:
-                hist = pallas_hist(new_leaf_id,
-                                   jnp.where(valid, small_id, -1))
+                cid = jnp.where(valid, small_id, -1)
+                if slab_cap:
+                    hist, slab = slab_hist(new_leaf_id, cid)
+                else:
+                    hist = pallas_hist(new_leaf_id, cid)
             else:
                 # (Fc*B, W*3) -> (W, Fc, B, 3)
                 with scope("wave_histogram"):
                     hist = flat.reshape(Fc, hist_bins, W, 3).transpose(
                         2, 0, 1, 3)
-            return new_leaf_id, hist
+            return new_leaf_id, hist, slab
 
-        # ---- spectator-row compaction (tpu_wave_compact): capacity
-        # tiers at 1/2, 1/4, 1/8 of N, 512-aligned, ascending.  Late
-        # waves split leaves holding a shrinking fraction of rows
-        # (measured frontier occupancy at 300k x 28/255 leaves: waves 7+
-        # touch 17-49% of rows — ~35% of ALL kernel row work is rows
-        # whose leaf is final, ROADMAP r4), the same economics as the
-        # reference's leaf-ordered bin iteration
-        # (ordered_sparse_bin.hpp:26-209): touch only the rows of the
-        # leaves being split.
-        compact_caps = []
-        if compact and not sparse_mode:
-            for frac in (2, 4, 8):
-                cap = -(-min(n, max(1024, -(-n // frac))) // 512) * 512
-                if cap < n and cap not in compact_caps:
-                    compact_caps.append(cap)
-            compact_caps.sort()
+        def slab_hist(leaf_id, cid):
+            """Histograms of the children `cid` from slabs of their rows
+            -> (hist, [rows the launches visited, 1]).
 
-        def compact_wave_pass(leaf_id, tbl, cols, psrc, small_id, valid):
-            """Fused wave pass over the ACTIVE rows only (leaf in the
-            wave's parent set), gathered into the smallest tier that
-            holds them; full-N fallback when none does.
+            One sort puts the children's rows first, in row order; a
+            slab is the next `slab_cap` of them, gathered from X.  With
+            all weights 1 one slab holds them all (a smaller child by
+            count has at most half its parent's rows).  The count is
+            WEIGHTED and the slab holds ROWS, so under bagging, GOSS or
+            row_mult 0 rows they can be more than half: then a second
+            slab follows, and nothing is ever truncated.
 
-            Exactness: a spectator row matches no parent (routes
-            nowhere) and no child (zero histogram weight), so routing
-            and SPLIT STRUCTURE are identical to the full-N pass.
-            Histogram sums are identical under strictly sequential f32
-            accumulation (adding 0.0 anywhere is the identity) — but
-            compaction shifts active rows across kernel row-tile
-            boundaries, so reductions that pair per-tile partial sums
-            non-sequentially reassociate and float fields (gains, leaf
-            values) can drift by f32 ulps.  Pinned in
-            tests/test_wave_compact.py: bit-equal trees at single-tile
-            N, equal structure + ~1e-5-close floats at multi-tile N.
-            Cost per wave: one (L,)-table membership gather, a
-            stable-compact index build (cumsum), and the row gathers —
-            against kernel row work shrinking from N to the tier."""
-            from .pallas_wave import wave_partition_hist_pallas_ct
-            with scope("wave_partition"):
-                act_tbl = jnp.zeros(L, bool).at[
-                    jnp.where(valid, psrc, L)].set(True, mode="drop")
-                mask = jnp.take(act_tbl, leaf_id)            # (N,)
-                active_n = jnp.sum(mask.astype(jnp.int32))   # TRUE row count
-                cid = jnp.where(valid, small_id, -1)
+            Exactness: a row outside the slabs matches no child, so it
+            adds 0.0 to every sum; the rows keep their order, so each
+            slot's rows enter its sums in the order the full pass adds
+            them and only tile (and slab) boundaries move: bit-equal at
+            one tile, f32 ulps across tiles (tests/test_wave_compact.py).
+            Fill rows carry leaf -2 and weight 0, as the kernel's own
+            padding does."""
+            from .pallas_wave import wave_histogram_pallas_t
+            with scope("wave_compact"):
+                mask = jnp.any(leaf_id[:, None] == cid[None, :], axis=1)
+                n_active = jnp.sum(mask.astype(jnp.int32))
+                # ONE sort moves the per-row operands: its keys are the
+                # active rows' numbers, in row order, then the others'
+                # (>= n).  On the v5e (PERF.md, PR 27) it takes 5.8 /
+                # 12.6 ms a wave at 1.2M / 2.5M rows, where a
+                # one-operand sort and three element gathers take 10.7
+                # / 33.8 ms and jnp.nonzero alone 11.5 / 23.6 ms.
+                # Padded to two slabs, so a slab's window never runs off
+                rows = jnp.arange(n, dtype=jnp.int32)
+                order = [jnp.pad(x, (0, 2 * slab_cap - n)) for x in lax.sort(
+                    (jnp.where(mask, rows, n + rows), leaf_id,
+                     w3[:, 0], w3[:, 1], w3[:, 2]), num_keys=1)]
 
-            def tier(cap):
-                def run():
-                    with scope("wave_partition"):
-                        idx = jnp.nonzero(mask, size=cap, fill_value=n)[0]
-                        # fill semantics mirror the kernel's own padding:
-                        # leaf -2 matches nothing, weight 0 adds nothing
-                        xt_c = jnp.take(Xt, idx, axis=1, mode="fill",
-                                        fill_value=0)
-                        lid_c = jnp.take(leaf_id, idx, mode="fill",
-                                         fill_value=-2)
-                        w3_c = jnp.take(w3, idx, axis=0, mode="fill",
-                                        fill_value=0.0)
-                    if pallas_fused:
-                        with scope("wave_histogram"):
-                            new_c, hist = wave_partition_hist_pallas_ct(
-                                xt_c, lid_c, w3_c, cid, cols, psrc,
-                                hist_bins, bundled=has_bundle,
-                                logical_cols=packed_cols, hilo=hist_hilo,
-                                interpret=pallas_interpret)
-                    else:
-                        # pallas_t tier: the partition over the
-                        # gathered slab is ONE masked reduction — the
-                        # compact (W, 10) lookup per row, the split
-                        # column from a (Fc, cap) masked sum over Xt_c
-                        # (unpacked in place when 4-bit), then the
-                        # shared routing algebra — followed by the t
-                        # histogram kernel on the updated ids
-                        from .pallas_wave import (_unpack4_t,
-                                                  wave_histogram_pallas_t)
-                        with scope("wave_partition"):
-                            pm = lid_c[None, :] == psrc[:, None]  # (W,cap)
-                            r = jnp.sum(
-                                jnp.where(pm[:, :, None], cols[:, None, :],
-                                          0.0), axis=0)           # (cap,10)
-                            xi = xt_c.astype(jnp.int32)
-                            if packed_cols:
-                                xi = _unpack4_t(xi, Fc)
-                            cj = r[:, 1].astype(jnp.int32)
-                            f_io = jnp.arange(Fc, dtype=jnp.int32)
-                            colv = jnp.sum(
-                                jnp.where(cj[None, :] == f_io[:, None],
-                                          xi, 0), axis=0)         # (cap,)
-                            new_c = route_rows(r, colv, lid_c)
-                        with scope("wave_histogram"):
-                            hist = wave_histogram_pallas_t(
-                                xt_c, new_c, w3_c, cid, hist_bins,
-                                logical_cols=packed_cols, hilo=hist_hilo,
-                                interpret=pallas_interpret)
-                    with scope("wave_partition"):
-                        return (leaf_id.at[idx].set(new_c, mode="drop"),
-                                hist)
-                return run
+            def slab(j, acc):
+                hist, visited = acc
+                with scope("wave_compact"):
+                    key, lid_c, *w_c = (
+                        lax.dynamic_slice(x, (j * slab_cap,), (slab_cap,))
+                        for x in order)
+                    left = n_active - j * slab_cap     # active from here on
+                    live = jnp.arange(slab_cap, dtype=jnp.int32) < left
+                    lid_c = jnp.where(live, lid_c, -2)
+                    w3_c = jnp.where(live[:, None], jnp.stack(w_c, -1), 0.0)
+                    # fill rows' bins may be any row's (their weight is
+                    # 0): clip, and spare the fill's select over the slab
+                    xt_c = jnp.transpose(jnp.take(X, key, axis=0,
+                                                  mode="clip"))
+                with scope("wave_histogram"):
+                    hist = hist + wave_histogram_pallas_t(
+                        xt_c, lid_c, w3_c, cid, hist_bins,
+                        logical_cols=packed_cols, hilo=hist_hilo,
+                        interpret=pallas_interpret, n_active=left)
+                tiles = (jnp.minimum(left, slab_cap)
+                         + (slab_tile - 1)) // slab_tile
+                return hist, visited + tiles * slab_tile
 
-            def ladder(caps):
-                if not caps:
-                    return wave_pass(leaf_id, tbl, cols, psrc, small_id,
-                                     valid)
-                return lax.cond(active_n <= caps[0], tier(caps[0]),
-                                lambda: ladder(caps[1:]))
-            return ladder(compact_caps)
+            with scope("wave_histogram"):
+                zero = jnp.zeros((W, Fc, hist_bins, 3), hist_dtype)
+            hist, visited = lax.fori_loop(
+                0, (n_active + (slab_cap - 1)) // slab_cap, slab,
+                (zero, jnp.asarray(0, jnp.int32)))
+            return hist, jnp.stack([visited, jnp.asarray(1, jnp.int32)])
 
         @scope("wave_histogram")
         def rehist(leaf_id, ids, valid):
@@ -658,9 +657,11 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             feature_mask, meta, bundle)[0])
         sums = jnp.zeros((L, 3), hist_dtype).at[0].set(root_sums)
         # what the loop counts (obs/timers.py COUNTERS): each wave adds
-        # [1, W, k, kc, rows of the committed smaller children, 0]; the
-        # last entry is the rows every pass visits
-        counters = jnp.zeros(len(COUNTERS), jnp.int32).at[-1].set(n)
+        # [1, W, k, kc, rows of the committed smaller children, 0, rows
+        # its slab launches visited, 1 if it ran any]; `rows` is the rows
+        # every full pass visits
+        counters = jnp.zeros(len(COUNTERS), jnp.int32).at[
+            COUNTERS.index("rows")].set(n)
         tree = TreeArrays(
             num_leaves=jnp.asarray(1, jnp.int32),
             split_feature=jnp.zeros(L - 1, jnp.int32),
@@ -748,14 +749,12 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 small_id = jnp.where(left_small, parent, newleaf)
                 large_id = jnp.where(left_small, newleaf, parent)
             if sparse_mode:
+                slab = no_slab
                 leaf_id, hist_small = sparse_wave_pass(
                     leaf_id, tbl, small_id, valid, col_w)
-            elif compact_caps:
-                leaf_id, hist_small = compact_wave_pass(
-                    leaf_id, tbl, cols, psrc, small_id, valid)
             else:
-                leaf_id, hist_small = wave_pass(leaf_id, tbl, cols, psrc,
-                                                small_id, valid)
+                leaf_id, hist_small, slab = wave_pass(
+                    leaf_id, tbl, cols, psrc, small_id, valid)
             hist_small = maybe_psum(hist_small)             # (W, F, B, 3)
             if cache_hists:
                 with scope("wave_histogram"):
@@ -910,7 +909,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.sum(jnp.where(commit, jnp.minimum(
                             info[:, LEFT_COUNT],
                             info[:, RIGHT_COUNT]).astype(jnp.int32), 0)),
-                        jnp.asarray(0, jnp.int32)]),
+                        jnp.asarray(0, jnp.int32), slab[0], slab[1]]),
                 )
             return (nn + kc, kc == 0, leaf_id, hists, bests, sums, tree)
 

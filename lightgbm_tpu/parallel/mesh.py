@@ -215,12 +215,6 @@ class DataParallelTreeLearner(SerialTreeLearner):
             from ..ops.wave import transposed_wave_active
             needs_xt = (transposed_wave_active(self.hist_mode, self.dtype)
                         and not self.sparse_on)
-            if bool(config.tpu_wave_compact):
-                # the compaction tiers are serial-execution only (no DP
-                # measurement yet, ops/wave.py) — an explicit opt-in
-                # must not be dropped silently
-                Log.warning("tpu_wave_compact=true ignored: not "
-                            "supported under the distributed learners")
             grow = make_wave_grow_fn(
                 self.num_leaves, self.num_bins, self.meta, self.params,
                 config.max_depth, wave_width=self.wave_width,

@@ -221,7 +221,6 @@ PARAMETER_SET = {
     "tpu_growth", "tpu_wave_width", "tpu_bin_pack", "tpu_wave_chunk",
     "tpu_sparse", "tpu_wave_order", "tpu_predict", "tpu_wave_lookup",
     "tpu_sparse_kernel", "tpu_hist_precision", "tpu_score_update",
-    "tpu_wave_compact",
     # measured kernel autotuner (ops/autotune.py)
     "tpu_autotune", "tpu_autotune_cache", "tpu_autotune_waves",
     # fused boosting iteration (ops/fused_iter.py)
@@ -554,26 +553,14 @@ class Config:
         # falls back to the gather off-TPU, above 512 leaves, or on
         # f64 scores (tpu_use_dp).
         "tpu_score_update": ("str", "auto"),
-        # spectator-row compaction for the transposed wave kernels
-        # (tpu_histogram_mode=pallas_ct/pallas_t): late waves touch only the rows
-        # whose leaf is still splitting (~35% of row work at the flagship
-        # recipe is rows whose leaf is final — measured frontier
-        # occupancy, ROADMAP.md r4), so the wave gathers the active rows
-        # into a capacity tier (1/2, 1/4, 1/8 of N) and runs the kernel
-        # on the compacted slab.  Split structure is exact (spectator
-        # rows route nowhere and carry zero histogram weight); float
-        # fields can drift by f32 ulps at multi-tile N (tile-boundary
-        # reassociation) — pinned vs the full-N pass in
-        # tests/test_wave_compact.py.  Off until the on-chip A/B lands.
-        "tpu_wave_compact": ("bool", False),
         # 'off' | 'prior' | 'measure' | 'force' — the measured kernel
         # autotuner (ops/autotune.py, docs/Autotuning.md).  off = the
         # heuristic prior only (bit-identical to the legacy inline
         # selection; the CPU-CI default).  prior = adopt a cached
         # winner when one exists, never probe.  measure = on cache miss
-        # microbench the 3-5 candidate (kernel, W, precision,
-        # compaction) cells for the shape bucket on-device and persist
-        # the winner.  force = always re-probe, overwriting the cache.
+        # microbench the 3-5 candidate (kernel, W, precision) cells
+        # for the shape bucket on-device and persist the winner.  force
+        # = always re-probe, overwriting the cache.
         "tpu_autotune": ("str", "off"),
         # autotune cache file; empty = autotune_cache.json in the XLA
         # compile-cache directory (utils/common.py compilation_cache_dir)

@@ -83,7 +83,7 @@ def test_off_mode_matches_legacy_heuristics(name, ncols, bin_pad, leaves,
 def test_off_mode_decide_is_identity():
     """tpu_autotune=off returns the prior cell untouched — no cache
     read, no probes — while still recording the decision."""
-    prior = Cell("pallas_t", 32, True, False)
+    prior = Cell("pallas_t", 32, True)
     d = decide(_cfg(255), ShapeBucket(28, 256, 255, 1 << 20), prior,
                Pins(), eligible=True)
     assert d.cell == prior and d.source == "off" and not d.probes
@@ -94,7 +94,7 @@ def test_off_mode_decide_is_identity():
 def test_ineligible_decide_keeps_prior(tmp_path):
     cfg = _cfg(31, tpu_autotune="measure",
                tpu_autotune_cache=str(tmp_path / "c.json"))
-    prior = Cell("onehot", 1, True, False)
+    prior = Cell("onehot", 1, True)
     d = decide(cfg, ShapeBucket(28, 256, 31, 4096), prior, Pins(),
                eligible=False)
     assert d.cell == prior and d.source == "ineligible" and not d.probes
@@ -104,14 +104,12 @@ def test_ineligible_decide_keeps_prior(tmp_path):
 
 def _bench(cell, bucket):
     """Deterministic synthetic cost: wider faster, bf16 beats hilo, ct
-    pays a tax, compaction a small win."""
+    pays a tax."""
     s = 1.0 / max(1, cell.wave_width)
     if cell.hist_hilo:
         s += 0.1
     if cell.hist_mode == "pallas_ct":
         s += 0.5
-    if cell.compact:
-        s -= 0.01
     return s
 
 
@@ -119,13 +117,13 @@ def test_measure_mode_deterministic_winner(tmp_path):
     autotune.install_probe_hooks(bench=_bench)
     cfg = _cfg(15, tpu_autotune="measure",
                tpu_autotune_cache=str(tmp_path / "c.json"))
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     d = decide(cfg, ShapeBucket(8, 64, 15, 2048), prior, Pins(),
                eligible=True)
     assert d.source == "measured" and not d.cache_hit
     # bf16 at the prior width wins under the synthetic costs (which are
     # fused-agnostic, so the fused arm ties the prior and loses the tie)
-    assert d.cell == Cell("pallas_t", 8, False, False)
+    assert d.cell == Cell("pallas_t", 8, False)
     assert len(d.probes) == 6 and d.margin > 0 and d.overhead_s > 0
     probe_evs = [f for ev, f in d.events if ev == "autotune_probe"]
     assert len(probe_evs) == 6
@@ -137,7 +135,7 @@ def test_cache_round_trip_skips_probing(tmp_path):
     autotune.install_probe_hooks(bench=_bench)
     cache = str(tmp_path / "c.json")
     cfg = _cfg(15, tpu_autotune="measure", tpu_autotune_cache=cache)
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     bucket = ShapeBucket(8, 64, 15, 2048)
     d1 = decide(cfg, bucket, prior, Pins(), eligible=True)
     assert d1.source == "measured"
@@ -161,7 +159,7 @@ def test_cache_invalidated_by_schema_rev_bump(tmp_path, monkeypatch):
     autotune.install_probe_hooks(bench=_bench)
     cfg = _cfg(15, tpu_autotune="measure",
                tpu_autotune_cache=str(tmp_path / "c.json"))
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     bucket = ShapeBucket(8, 64, 15, 2048)
     assert decide(cfg, bucket, prior, Pins(),
                   eligible=True).source == "measured"
@@ -180,12 +178,12 @@ def test_cached_winner_respects_pins(tmp_path):
     cfg = _cfg(15, tpu_autotune="measure",
                tpu_autotune_cache=str(tmp_path / "c.json"))
     bucket = ShapeBucket(8, 64, 15, 2048)
-    d1 = decide(cfg, bucket, Cell("pallas_t", 8, True, False), Pins(),
+    d1 = decide(cfg, bucket, Cell("pallas_t", 8, True), Pins(),
                 eligible=True)
     assert d1.cell.wave_width == 8  # cached winner: W=8 bf16
     # now the same bucket with width pinned at 4: the cached cell's
     # width must be replaced by the prior's
-    prior = Cell("pallas_t", 4, True, False)
+    prior = Cell("pallas_t", 4, True)
     d2 = decide(cfg, bucket, prior, Pins(width=True), eligible=True)
     assert d2.source == "cache" and d2.cell.wave_width == 4
 
@@ -196,7 +194,7 @@ def test_corrupt_cache_is_empty_cache(tmp_path):
     cache.write_text("{not json")
     cfg = _cfg(15, tpu_autotune="measure", tpu_autotune_cache=str(cache))
     d = decide(cfg, ShapeBucket(8, 64, 15, 2048),
-               Cell("pallas_t", 8, True, False), Pins(), eligible=True)
+               Cell("pallas_t", 8, True), Pins(), eligible=True)
     assert d.source == "measured"   # re-probed, did not raise
 
 
@@ -218,12 +216,12 @@ def test_stale_rev_cache_file_dropped_whole(tmp_path):
             stale_key.replace("|v%d|" % autotune.CACHE_SCHEMA_REV,
                               "|v1|"): {
                 "cell": {"hist_mode": "pallas_ct", "wave_width": 64,
-                         "hist_hilo": False, "compact": True},
+                         "hist_hilo": False},
                 "s_per_wave": 1e-9, "waves": 3},
         }}))
     assert autotune.load_cache(str(cache)) == {}
     cfg = _cfg(15, tpu_autotune="measure", tpu_autotune_cache=str(cache))
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     d = decide(cfg, bucket, prior, Pins(), eligible=True)
     assert d.source == "measured" and not d.cache_hit
     with open(cache) as f:
@@ -241,7 +239,7 @@ def test_force_mode_ignores_cache(tmp_path):
     autotune.install_probe_hooks(bench=_bench)
     cache = str(tmp_path / "c.json")
     bucket = ShapeBucket(8, 64, 15, 2048)
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     decide(_cfg(15, tpu_autotune="measure", tpu_autotune_cache=cache),
            bucket, prior, Pins(), eligible=True)
     d = decide(_cfg(15, tpu_autotune="force", tpu_autotune_cache=cache),
@@ -254,7 +252,7 @@ def test_measure_off_tpu_is_noop(tmp_path):
     with zero probes (CPU CI must not pay wave compiles)."""
     cfg = _cfg(15, tpu_autotune="measure",
                tpu_autotune_cache=str(tmp_path / "c.json"))
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     d = decide(cfg, ShapeBucket(8, 64, 15, 2048), prior, Pins(),
                eligible=True, probe=lambda cell: (lambda: None))
     assert d.cell == prior and d.source == "prior" and not d.probes
@@ -271,8 +269,8 @@ def test_measure_cells_injectable_timer():
         return ticks[0]
 
     autotune.install_probe_hooks(timer=timer)
-    cells = [Cell("pallas_t", 8, True, False),
-             Cell("pallas_t", 16, True, False)]
+    cells = [Cell("pallas_t", 8, True),
+             Cell("pallas_t", 16, True)]
     events = []
     out = measure_cells(cells, ShapeBucket(8, 64, 15, 2048),
                         lambda cell: (lambda: None), waves=4,
@@ -292,8 +290,8 @@ def test_failed_probe_drops_candidate_not_training():
         return lambda: None
 
     events = []
-    out = measure_cells([Cell("pallas_t", 8, True, False),
-                         Cell("pallas_t", 16, True, False)],
+    out = measure_cells([Cell("pallas_t", 8, True),
+                         Cell("pallas_t", 16, True)],
                         ShapeBucket(8, 64, 15, 2048), probe, 1, events)
     assert [c.wave_width for c, _ in out] == [8]
 
@@ -302,26 +300,26 @@ def test_failed_probe_drops_candidate_not_training():
 
 def test_enumerate_cells_respects_pins_and_gates():
     bucket = ShapeBucket(8, 64, 15, 2048)
-    prior = Cell("pallas_t", 8, True, False)
+    prior = Cell("pallas_t", 8, True)
     cells = enumerate_cells(prior, bucket, Pins())
     assert cells[0] == prior and len(cells) <= autotune.MAX_CELLS
     widths = {c.wave_width for c in cells}
     assert {4, 8, 16} <= widths
     # the staged/fused flip (rev 2) is a candidate when unpinned ...
     assert any(c.fused for c in cells)
-    # ... and fully pinned (all five dimensions) only the prior survives
+    # ... and fully pinned (all four dimensions) only the prior survives
     assert enumerate_cells(
-        prior, bucket, Pins(True, True, True, True, True)) == [prior]
+        prior, bucket, Pins(True, True, True, True)) == [prior]
     # pinning fused alone removes exactly the fused arm
     assert all(not c.fused
                for c in enumerate_cells(prior, bucket, Pins(fused=True)))
     # non-wave kernels have no neighbours
-    assert enumerate_cells(Cell("onehot", 1, True, False), bucket,
-                           Pins()) == [Cell("onehot", 1, True, False)]
+    assert enumerate_cells(Cell("onehot", 1, True), bucket,
+                           Pins()) == [Cell("onehot", 1, True)]
     # VMEM hard gate: a W*2 neighbour whose block exceeds the budget is
     # not enumerated (bosch-wide: W64 at 968x256 would be 190 MB)
     wide = ShapeBucket(968, 256, 255, 1 << 20)
-    big = enumerate_cells(Cell("pallas_t", 32, True, False), wide, Pins())
+    big = enumerate_cells(Cell("pallas_t", 32, True), wide, Pins())
     assert all(c.wave_width <= 32 for c in big)
     # ct cells are only candidates where ct may run (serial execution)
     no_ct = enumerate_cells(prior, bucket, Pins(), ct_allowed=False)
@@ -332,7 +330,7 @@ def test_ct_beyond_promotion_bound_is_a_candidate():
     """The 2560 ct bound is a PRIOR, not a hard gate: measure mode
     probes the ct arm on shapes the heuristic would never promote."""
     bucket = ShapeBucket(136, 256, 255, 1 << 20)   # 34816 >> 2560
-    cells = enumerate_cells(Cell("pallas_t", 32, True, False), bucket,
+    cells = enumerate_cells(Cell("pallas_t", 32, True), bucket,
                             Pins())
     assert any(c.hist_mode == "pallas_ct" for c in cells)
 

@@ -361,21 +361,21 @@ def test_cell_traffic_model():
     from lightgbm_tpu.ops.autotune import Cell, ShapeBucket
     bucket = ShapeBucket(ncols=28, bin_pad=64, num_leaves=255,
                          n_bucket=1 << 20)
-    hilo = Cell("pallas_ct", 8, True, False)
+    hilo = Cell("pallas_ct", 8, True)
     flops, nbytes = cell_traffic(bucket, hilo)
     n = float(1 << 20)
     assert flops == pytest.approx(2.0 * n * 28 * 8)
     assert nbytes == pytest.approx(n * 28 + n * 8.0 * 8
                                    + 8 * 64 * 28 * 8.0)
     # the bf16 trade halves the gradient/hessian read traffic
-    _, nb_bf16 = cell_traffic(bucket, Cell("pallas_ct", 8, False, False))
+    _, nb_bf16 = cell_traffic(bucket, Cell("pallas_ct", 8, False))
     assert nb_bf16 == pytest.approx(nbytes - n * 4.0 * 8)
 
 
 def test_cell_roofline_stamp_shape():
     from lightgbm_tpu.ops.autotune import Cell, ShapeBucket
     bucket = ShapeBucket(28, 64, 255, 1 << 16)
-    stamp = cell_roofline(bucket, Cell("pallas_t", 8, True, False),
+    stamp = cell_roofline(bucket, Cell("pallas_t", 8, True),
                           s_per_wave=1e-3, kind="tpu_v4")
     assert set(stamp) == {"flop_util", "hbm_util", "ai", "bound",
                           "device_kind", "roof_source"}
@@ -395,8 +395,8 @@ def test_measure_cells_stamps_every_probe():
                                            install_probe_hooks,
                                            measure_cells)
     bucket = ShapeBucket(8, 64, 15, 2048)
-    cells = [Cell("pallas_t", 8, True, False),
-             Cell("pallas_ct", 4, False, False)]
+    cells = [Cell("pallas_t", 8, True),
+             Cell("pallas_ct", 4, False)]
     events = []
     install_probe_hooks(bench=lambda cell, b: 1e-3)
     try:

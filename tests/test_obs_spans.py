@@ -456,6 +456,43 @@ def test_kernel_keeps_a_name_the_benchmark_finds_when_lowered_for_tpu(
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
+def test_early_stop_kernel_compiles_for_tpu(topo):
+    """A slab launch (W=32, single-bf16 products, eight row tiles)
+    compiled by Mosaic for a described v5e: scalar prefetch, clamped
+    index maps and the skipped body pass the chip's compiler, the launch
+    pads nothing, and the call keeps the name the benchmark finds.  (At
+    a cell's own 2,000 columns the same compile takes 77 s here: too
+    long for this suite.)"""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.ops.pallas_wave import (slab_plan,
+                                              wave_histogram_pallas_t)
+
+    assert slab_plan(1_200_128, 2000, 63, 32) == (600_064, 2_048)
+    assert slab_plan(2_500_608, 968, 63, 32) == (1_250_944, 3_712)
+    cap, tile = slab_plan(131_072, 64, 63, 32)
+    assert cap == 65_536 == 8 * tile
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def wave(xt, lid, w3, cid, n_active):
+        with jax.named_scope("wave_histogram"):
+            return wave_histogram_pallas_t(xt, lid, w3, cid, 63, hilo=False,
+                                           n_active=n_active)
+
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((64, cap), jnp.uint8), ((cap,), jnp.int32),
+        ((cap, 3), jnp.float32), ((32,), jnp.int32), ((), jnp.int32))]
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(wave).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+    assert "%wave_histogram_pallas_t" in text
+    assert " pad(" not in text         # cap is the launch's own tile multiple
+
+
 # ---------------------------------------------- the grow loop's counters
 
 def _tree_counts(bst):
@@ -513,8 +550,27 @@ def test_counters_agree_with_the_grown_tree(fused):
         assert c["waves"] == _replay_waves(tree, 4)
         assert c["slots"] == 4 * c["waves"]
         assert c["rows"] == rows
-        assert c["rows_visited"] == (c["waves"] + 1) * rows
+        # WAVE runs the pallas_t kernel (interpreted): every wave reads
+        # the row slab of its smaller children, one tile of rows / 2
+        assert c["compacted"] == c["waves"]
+        assert c["kernel_rows"] == c["waves"] * (rows // 2)
+        assert c["rows_visited"] == rows + c["kernel_rows"]
         assert c["hist_rows"] < c["rows_visited"]
+
+
+def test_without_the_slab_every_wave_visits_every_row():
+    """The XLA engine (no Pallas kernel on the CPU without the
+    interpreter): no slab, so the record's `kernel_rows` is one full
+    pass a wave, added on the host."""
+    X, y = _xy(3000, 10, 0)
+    params = dict(WAVE, tpu_pallas_interpret=False)
+    bst = lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                    num_boost_round=2)
+    rows = 3000 + (-3000) % 1024
+    for c in _tree_counts(bst):
+        assert c["compacted"] == 0 and c["waves"] > 0
+        assert c["kernel_rows"] == c["waves"] * rows
+        assert c["rows_visited"] == (c["waves"] + 1) * rows
 
 
 def test_exact_order_attempts_more_than_it_commits():

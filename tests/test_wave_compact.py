@@ -1,19 +1,20 @@
-"""Spectator-row compaction (tpu_wave_compact) vs the full-N fused pass.
+"""The wave's row slab (ops/wave.py slab_hist) against the full-N pass.
 
-Late waves split leaves holding a shrinking fraction of rows; the
-compaction tiers (ops/wave.py compact_wave_pass) gather only the active
-rows before the fused pallas_ct kernel runs.  Claims under test: the
-compacted engine produces THE SAME SPLIT STRUCTURE and THE SAME ROW
-PARTITION as the full-N engine (a spectator row matches no parent and
-no child, so dropping it changes no routing decision), with bit-equal
-trees at single-tile N; at multi-tile N float fields may drift by f32
-ulps (compaction shifts rows across kernel tile boundaries — partial
-sums pair differently under non-sequential reductions), pinned tiny.
+A wave keeps the histograms of its smaller children only.  On the split
+pipeline (partition scan, then the pallas_t kernel) the rows of those
+children are known when the kernel starts: they are gathered, in row
+order, into one slab of N/2 rows, and the kernel stops at the slab's
+last full tile (ops/pallas_wave.py, `n_active`).  Claims under test: the
+same SPLIT STRUCTURE and the same ROW PARTITION as the full pass (a row
+outside the slab matches no child, and the partition is untouched), with
+bit-equal trees while the slab is one tile; across tiles the float
+fields may drift by f32 ulps (the slab moves rows across tile
+boundaries), pinned tiny.  When a smaller child by WEIGHTED count holds
+over half the rows a second slab follows the first.
 
-Runs the real engine end-to-end on CPU via interpret-mode kernels
-(make_wave_core's pallas_interpret static).  Shapes are chosen so the
-1024/2048-row tiers genuinely engage (62 splits over 6000 rows leave
-late-wave frontiers far below the smallest tier).
+Runs the real engine end-to-end on the CPU through interpret-mode
+kernels (make_wave_core's pallas_interpret static); `compact=False` is
+the plumbing that grows the same tree without the slab.
 """
 import numpy as np
 import jax
@@ -21,20 +22,27 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.io.dataset import TrainingData
-from lightgbm_tpu.ops.learner import build_split_params
+from lightgbm_tpu.obs.timers import COUNTERS
+from lightgbm_tpu.ops.learner import SerialTreeLearner, build_split_params
+from lightgbm_tpu.ops.pallas_wave import slab_plan, wave_histogram_pallas_t
 from lightgbm_tpu.ops.split_finder import FeatureMeta
-from lightgbm_tpu.ops.wave import make_wave_grow_fn
+from lightgbm_tpu.ops.wave import make_wave_core, make_wave_grow_fn
 from lightgbm_tpu.utils.config import Config
 
 N, F = 6000, 8
+STRUCTURE = ("num_leaves", "split_feature", "threshold_bin",
+             "default_bin_for_zero", "default_bin", "is_cat", "left_child",
+             "right_child", "leaf_parent", "leaf_count", "leaf_depth",
+             "internal_count")
+FLOATS = ("split_gain", "internal_value", "leaf_value")
 
 
-def _setup(num_leaves, n=N):
+def _setup(num_leaves, n=N, max_bin=63):
     rng = np.random.default_rng(11)
     X = rng.normal(size=(n, F))
     y = (X[:, 1] + np.cos(X[:, 4] * 2) + 0.4 * rng.normal(size=n) > 0.5)
     cfg = Config({"num_leaves": num_leaves, "min_data_in_leaf": 3,
-                  "max_bin": 63, "verbose": -1})
+                  "max_bin": max_bin, "verbose": -1})
     td = TrainingData.from_matrix(X, label=y.astype(np.float64),
                                   config=cfg)
     meta = FeatureMeta(num_bin=jnp.asarray(td.num_bin_arr),
@@ -45,162 +53,208 @@ def _setup(num_leaves, n=N):
     return cfg, td, meta, grad, hess
 
 
-def _run(compact, num_leaves, wave_width, row_mult=None,
-         exact_order=False, n=N, hist_mode="pallas_ct"):
-    cfg, td, meta, grad, hess = _setup(num_leaves, n=n)
+def _run(compact, num_leaves, wave_width, row_mult=None, exact_order=False,
+         n=N, hist_mode="pallas_t", packed=False, grad=None):
+    cfg, td, meta, grad0, hess = _setup(num_leaves, n=n,
+                                        max_bin=15 if packed else 63)
+    grad = grad0 if grad is None else jnp.asarray(grad)
     params = build_split_params(cfg)
     nb = int(td.num_bin_arr.max())
-    X = jnp.asarray(td.binned)
+    X = td.binned
+    if packed:
+        from lightgbm_tpu.ops.pack import pack4_host
+        X = pack4_host(np.asarray(X))
+    X = jnp.asarray(X)
     grow = make_wave_grow_fn(num_leaves, nb, meta, params, -1,
-                             wave_width=wave_width,
-                             hist_mode=hist_mode, with_xt=True,
-                             exact_order=exact_order,
+                             wave_width=wave_width, hist_mode=hist_mode,
+                             with_xt=True, exact_order=exact_order,
+                             packed_cols=td.binned.shape[1] if packed else 0,
                              compact=compact, pallas_interpret=True)
     rm = (jnp.ones(n, jnp.float32) if row_mult is None
           else jnp.asarray(row_mult))
     fm = jnp.ones(td.num_features, dtype=bool)
-    tree, leaf_id = jax.jit(grow)(X, grad, hess, rm, fm,
-                                  jnp.transpose(X))
+    tree, leaf_id = jax.jit(grow)(X, grad, hess, rm, fm, jnp.transpose(X))
     return tree, leaf_id
 
 
-def _trees_identical(a, b):
-    for field in ("num_leaves", "split_feature", "threshold_bin",
-                  "default_bin_for_zero", "default_bin", "is_cat",
-                  "left_child", "right_child", "leaf_parent",
-                  "leaf_count", "leaf_depth", "internal_count"):
-        np.testing.assert_array_equal(np.asarray(getattr(a, field)),
-                                      np.asarray(getattr(b, field)),
-                                      err_msg=field)
-    # float fields: bit-equality is the design claim (0.0 contributions
-    # pass through f32 partial sums unchanged)
-    for field in ("split_gain", "internal_value", "leaf_value"):
-        np.testing.assert_array_equal(np.asarray(getattr(a, field)),
-                                      np.asarray(getattr(b, field)),
-                                      err_msg=field)
+def _counters(tree):
+    return dict(zip(COUNTERS, (int(v) for v in np.asarray(tree.counters))))
 
 
-@pytest.mark.parametrize("hist_mode", ["pallas_ct", "pallas_t"])
-@pytest.mark.parametrize("wave_width", [1, 4])
-def test_compact_matches_full_pass(wave_width, hist_mode):
-    """62 splits over 6000 rows: late waves are far under the 1024-row
-    tier, so the ladder's gathered branches run for real — under both
-    the fused ct tier and the vector-partition t tier."""
-    t_full, l_full = _run(False, 63, wave_width, hist_mode=hist_mode)
-    t_comp, l_comp = _run(True, 63, wave_width, hist_mode=hist_mode)
+def _same(a, b, fields, close=False):
+    for field in fields:
+        x, y = np.asarray(getattr(a, field)), np.asarray(getattr(b, field))
+        if close:
+            np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-6,
+                                       err_msg=field)
+        else:
+            np.testing.assert_array_equal(x, y, err_msg=field)
+
+
+@pytest.mark.parametrize("exact_order", [False, True])
+@pytest.mark.parametrize("wave_width", [1, 4, 32])
+def test_slab_matches_full_pass_at_one_tile(wave_width, exact_order):
+    """6000 rows: the slab (3000 rows) is one kernel tile, so every sum
+    adds the same rows in the same order and the trees are bit-equal,
+    floats included; the exact-order rollback remaps leaf ids AFTER the
+    wave pass and must compose with the slab."""
+    assert slab_plan(N, F, 63, wave_width) == (3000, 3000)
+    t_full, l_full = _run(False, 63, wave_width, exact_order=exact_order)
+    t_slab, l_slab = _run(True, 63, wave_width, exact_order=exact_order)
     assert int(t_full.num_leaves) == 63
-    _trees_identical(t_full, t_comp)
-    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_comp))
+    _same(t_full, t_slab, STRUCTURE + FLOATS)
+    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_slab))
+    c = _counters(t_slab)
+    assert c["compacted"] == c["waves"] > 0
+    assert _counters(t_full)["compacted"] == 0
 
 
-def test_compact_multitile_structure_equal_floats_close():
-    """At N > the kernel's 8192 row tile, compaction shifts active rows
-    across tile boundaries; reductions that pair per-tile partial sums
-    non-sequentially reassociate, so float fields may drift by f32 ulps
-    while routing and split STRUCTURE stay exact (review repro, r5).
-    The promotion gate (tools/bench_suite.py higgs_compact) budgets
-    this at 5e-5 AUC; here the drift itself is pinned tiny."""
+def test_slab_across_tiles_structure_equal_floats_close():
+    """20,000 rows: the slab is two tiles of 8,192 where the full pass
+    has three, so rows cross tile boundaries and per-tile partial sums
+    pair differently: structure and partition stay exact, the float
+    fields agree to 1e-5."""
+    assert slab_plan(20_000, F, 63, 4) == (16_384, 8_192)
     t_full, l_full = _run(False, 63, 4, n=20_000)
-    t_comp, l_comp = _run(True, 63, 4, n=20_000)
-    for field in ("num_leaves", "split_feature", "threshold_bin",
-                  "default_bin_for_zero", "default_bin", "is_cat",
-                  "left_child", "right_child", "leaf_parent",
-                  "leaf_count", "leaf_depth", "internal_count"):
-        np.testing.assert_array_equal(np.asarray(getattr(t_full, field)),
-                                      np.asarray(getattr(t_comp, field)),
-                                      err_msg=field)
-    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_comp))
-    for field in ("split_gain", "internal_value", "leaf_value"):
-        np.testing.assert_allclose(np.asarray(getattr(t_full, field)),
-                                   np.asarray(getattr(t_comp, field)),
-                                   rtol=1e-5, atol=1e-6, err_msg=field)
+    t_slab, l_slab = _run(True, 63, 4, n=20_000)
+    _same(t_full, t_slab, STRUCTURE)
+    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_slab))
+    _same(t_full, t_slab, FLOATS, close=True)
+    c = _counters(t_slab)
+    assert c["compacted"] == c["waves"]
+    # every launch stopped at one or two tiles
+    assert c["waves"] * 8_192 <= c["kernel_rows"] <= c["waves"] * 16_384
+    assert c["kernel_rows"] < c["waves"] * 16_384
 
 
-def test_compact_matches_full_pass_exact_order():
-    """The exact-order commit/rollback path remaps leaf ids AFTER the
-    wave pass — the compacted scatter-back must compose with it."""
-    t_full, l_full = _run(False, 63, 4, exact_order=True)
-    t_comp, l_comp = _run(True, 63, 4, exact_order=True)
-    _trees_identical(t_full, t_comp)
-    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_comp))
+def test_early_stop_changes_no_sum():
+    """`n_active` given against not given on the same slab: the tiles
+    past the last full one hold fill rows only (leaf -2, weight 0), so
+    skipping them is bit-equal; with nothing active the output is the
+    zeroed block."""
+    rng = np.random.default_rng(5)
+    n, na, nb = 3000, 1300, 63
+    X = rng.integers(0, nb, size=(n, F)).astype(np.uint8)
+    lid = rng.integers(0, 6, size=n).astype(np.int32)
+    w3 = rng.normal(size=(n, 3)).astype(np.float32)
+    lid[na:], w3[na:] = -2, 0.0
+    cid = jnp.asarray([1, 3, -1, 5], jnp.int32)
+    args = (jnp.asarray(X.T), jnp.asarray(lid), jnp.asarray(w3), cid, nb)
+    kw = dict(interpret=True, row_tile=512)          # 6 tiles of 512
+    full = wave_histogram_pallas_t(*args, **kw)
+    stop = wave_histogram_pallas_t(*args, n_active=jnp.asarray(na), **kw)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(stop))
+    assert float(jnp.abs(full).max()) > 0
+    # a promise broken on purpose shows that the tiles really are skipped
+    short = wave_histogram_pallas_t(*args, n_active=jnp.asarray(512), **kw)
+    assert not np.array_equal(np.asarray(full), np.asarray(short))
+    none = wave_histogram_pallas_t(*args, n_active=jnp.asarray(0), **kw)
+    assert float(jnp.abs(none).max()) == 0.0
 
 
-def test_compact_matches_full_pass_with_bagging():
-    """Zero-weight (out-of-bag) rows still carry leaf ids the score
-    update needs: the tier choice must count ROWS, not summed weights —
-    a tier sized by weighted counts would truncate the gather and leave
-    OOB rows unrouted."""
+def test_a_second_slab_follows_when_the_children_hold_most_rows():
+    """Bagging weights: the smaller child is chosen by WEIGHTED count,
+    a slab holds ROWS.  70% of the rows weigh 0.01 and the label splits
+    them from the others, so the root's smaller child by weight holds
+    4,200 of 6,000 rows, more than one slab's 3,000: that wave's launch
+    runs over two slabs (nothing is truncated, light rows keep their
+    leaf ids for the score update), and the tree equals the one grown
+    without the slab."""
+    col = np.asarray(_setup(63)[1].binned)[:, 1]
+    light = col <= np.quantile(col, 0.7)
     rng = np.random.default_rng(7)
-    rm = (rng.random(N) < 0.5).astype(np.float32)   # ~50% weight-0 rows
-    t_full, l_full = _run(False, 63, 4, row_mult=rm)
-    t_comp, l_comp = _run(True, 63, 4, row_mult=rm)
-    _trees_identical(t_full, t_comp)
-    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_comp))
+    y = ~light ^ (rng.random(N) < 0.1)
+    rm = np.where(light, 0.01, 1.0).astype(np.float32)
+    kw = dict(row_mult=rm, grad=(0.5 - y).astype(np.float32))
+    t_full, l_full = _run(False, 63, 4, **kw)
+    t_slab, l_slab = _run(True, 63, 4, **kw)
+    _same(t_full, t_slab, STRUCTURE)
+    _same(t_full, t_slab, FLOATS, close=True)
+    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_slab))
+    c = _counters(t_slab)
+    assert c["compacted"] == c["waves"]
+    # one tile a slab here: some wave visited two of them
+    assert c["kernel_rows"] > c["waves"] * 3000
+    assert c["kernel_rows"] % 3000 == 0
 
 
-@pytest.mark.parametrize("hist_mode", ["pallas_ct", "pallas_t"])
-def test_compact_with_packed_bins(hist_mode):
-    """4-bit packing + compaction: the tier gathers COLUMNS of the
-    packed (ceil(F/2), N) Xt and unpacks in place (kernel-side for ct,
-    partition-side via the shared _unpack4_t for t) — the combination
-    must match the unpacked compacted run exactly."""
-    from lightgbm_tpu.ops.pack import pack4_host
-    rng = np.random.default_rng(11)
-    n = 6000
-    X = rng.normal(size=(n, F))
-    y = (X[:, 1] + np.cos(X[:, 4] * 2) + 0.4 * rng.normal(size=n) > 0.5)
-    cfg = Config({"num_leaves": 63, "min_data_in_leaf": 3,
-                  "max_bin": 15, "verbose": -1})
-    td = TrainingData.from_matrix(X, label=y.astype(np.float64),
-                                  config=cfg)
-    meta = FeatureMeta(num_bin=jnp.asarray(td.num_bin_arr),
-                       default_bin=jnp.asarray(td.default_bin_arr),
-                       is_categorical=jnp.asarray(td.is_categorical_arr))
-    params = build_split_params(cfg)
-    nb = int(td.num_bin_arr.max())
-    grad = jnp.asarray((0.5 - y).astype(np.float32))
-    hess = jnp.full(n, 0.25, jnp.float32)
-    rm = jnp.ones(n, jnp.float32)
-    fm = jnp.ones(td.num_features, dtype=bool)
-    Xd = jnp.asarray(td.binned)
-    Xp = jnp.asarray(pack4_host(np.asarray(td.binned)))
-    outs = []
-    for packed, Xin in ((0, Xd), (td.binned.shape[1], Xp)):
-        grow = make_wave_grow_fn(63, nb, meta, params, -1, wave_width=4,
-                                 hist_mode=hist_mode, with_xt=True,
-                                 packed_cols=packed, compact=True,
-                                 pallas_interpret=True)
-        outs.append(jax.jit(grow)(Xin, grad, hess, rm, fm,
-                                  jnp.transpose(Xin)))
-    (t_u, l_u), (t_p, l_p) = outs
-    _trees_identical(t_u, t_p)
-    np.testing.assert_array_equal(np.asarray(l_u), np.asarray(l_p))
+def test_slab_with_packed_bins():
+    """4-bit packing: the slab gathers rows of the packed (N, ceil(F/2))
+    matrix and the kernel unpacks them in VMEM as it does the full
+    pass's; the packed tree equals the unpacked one."""
+    t_u, l_u = _run(True, 63, 4, packed=False, n=N)
+    t_p, l_p = _run(True, 63, 4, packed=True, n=N)
+    t_pf, l_pf = _run(False, 63, 4, packed=True, n=N)
+    _same(t_p, t_pf, STRUCTURE + FLOATS)
+    np.testing.assert_array_equal(np.asarray(l_p), np.asarray(l_pf))
+    assert _counters(t_p)["compacted"] == _counters(t_p)["waves"] > 0
+    assert int(t_u.num_leaves) == 63
 
 
-def test_compact_config_reaches_serial_learner():
-    """tpu_wave_compact threads from Config through the serial learner's
-    wave-core statics (no-op off TPU, but the static must arrive)."""
-    from lightgbm_tpu.ops import learner as learner_mod
-    seen = {}
-    from lightgbm_tpu.ops.wave import make_wave_jit as real_jit
+def test_counters_against_a_hand_count_on_a_three_wave_tree():
+    """8 leaves at W=4: the root's split, then two, then four.  Every
+    wave's launch visits one tile of the slab (cap = c = 3000 rows), and
+    the slab holds exactly the smaller children's rows."""
+    tree, leaf_id = _run(True, 8, 4)
+    c = _counters(tree)
+    assert c["waves"] == 3 and c["committed"] == 7
+    assert c["compacted"] == 3
+    assert c["kernel_rows"] == 3 * 3000
+    assert c["rows"] == N
+    left, right = np.asarray(tree.left_child), np.asarray(tree.right_child)
+    count = lambda ch: int(tree.leaf_count[~ch] if ch < 0   # noqa: E731
+                           else tree.internal_count[ch])
+    assert c["hist_rows"] == sum(min(count(left[i]), count(right[i]))
+                                 for i in range(7))
+    assert c["hist_rows"] <= c["kernel_rows"]
+    off = _counters(_run(False, 8, 4)[0])
+    assert off["kernel_rows"] == 0 == off["compacted"] and off["waves"] == 3
 
+
+def _learner(monkeypatch=None, **keys):
     rng = np.random.default_rng(3)
     X = rng.normal(size=(500, 4))
+    X[rng.random(X.shape) < 0.7] = 0.0
     y = (X[:, 0] > 0).astype(np.float64)
-    cfg = Config({"num_leaves": 15, "min_data_in_leaf": 3, "max_bin": 63,
-                  "verbose": -1, "tpu_growth": "wave",
-                  "tpu_wave_compact": True})
+    cfg = Config(dict({"num_leaves": 15, "min_data_in_leaf": 3,
+                       "max_bin": 63, "verbose": -1}, **keys))
     td = TrainingData.from_matrix(X, label=y, config=cfg)
-    import lightgbm_tpu.ops.wave as wave_mod
+    return SerialTreeLearner(cfg, td)
 
-    def spy(*args):
-        seen["args"] = args
-        return real_jit(*args)
 
-    old = wave_mod.make_wave_jit
-    wave_mod.make_wave_jit = spy
-    try:
-        learner_mod.SerialTreeLearner(cfg, td)
-    finally:
-        wave_mod.make_wave_jit = old
-    assert seen["args"][-1] is True       # the compact static arrived
+@pytest.mark.parametrize("keys, on", [
+    ({"tpu_histogram_mode": "pallas_t", "tpu_pallas_interpret": True}, True),
+    ({"tpu_histogram_mode": "pallas_ct", "tpu_pallas_interpret": True},
+     False),
+    ({"tpu_histogram_mode": "pallas_t"}, False),     # the XLA engine runs
+    ({"tpu_sparse": True}, False),
+    ({"tpu_sparse_kernel": True}, False),
+    ({}, False),
+])
+def test_learner_resolves_the_slab_from_what_it_observes(keys, on):
+    """No key turns the slab on or off: the serial learner reports it on
+    where the pallas_t kernel really runs (here: its interpreter) on a
+    dense store under wave growth, and off under pallas_ct, the sparse
+    stores and wherever the XLA engine runs in the kernel's place."""
+    learner = _learner(**dict({"tpu_growth": "wave"}, **keys))
+    assert learner.wave_compact is on
+    assert learner.obs_info()["wave_compact"] is on
+    assert not hasattr(learner.config, "tpu_wave_compact")   # no such key
+
+
+def test_no_slab_under_a_mesh_axis_or_on_the_tpu_without_pallas_t(
+        monkeypatch):
+    """`slab_active` under `psum_axis` (a mesh shard's slab has no
+    measurement yet) and on a pretend TPU: pallas_t turns it on there
+    with no interpret flag, pallas_ct and f64 do not."""
+    from lightgbm_tpu.ops.wave import slab_active
+    assert slab_active(True, "pallas_t", jnp.float32, None, True)
+    assert not slab_active(True, "pallas_t", jnp.float32, "data", True)
+    assert not slab_active(False, "pallas_t", jnp.float32, None, True)
+    assert not slab_active(True, "pallas_t", jnp.float32, None, False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    make_wave_core.cache_clear()
+    assert slab_active(True, "pallas_t", jnp.float32, None)
+    assert not slab_active(True, "pallas_ct", jnp.float32, None)
+    assert not slab_active(True, "pallas_t", jnp.float64, None)
+    assert not slab_active(True, "pallas_t", jnp.float32, "data")

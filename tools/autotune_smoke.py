@@ -32,15 +32,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def fake_bench(cell, bucket):
     """Synthetic s_per_wave: wider is faster, bf16 beats hilo, ct pays a
-    startup tax at this scale, compaction a small win.  Deterministic, so
+    startup tax at this scale.  Deterministic, so
     the winner is stable across runs and platforms."""
     s = 1.0 / max(1, cell.wave_width)
     if cell.hist_hilo:
         s += 0.1
     if cell.hist_mode == "pallas_ct":
         s += 0.5
-    if cell.compact:
-        s -= 0.01
     return s
 
 

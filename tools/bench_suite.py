@@ -86,20 +86,6 @@ SHAPES = {
         "max_bin": 63, "learning_rate": 0.1, "min_data_in_leaf": 1,
         "tpu_histogram_mode": "pallas_ct", "tpu_wave_width": 32},
         warmup=3, measured=10, timeout=2700),
-    # spectator-row compaction at the flagship (tpu_wave_compact): late
-    # waves gather only active rows (~35% of kernel row work is
-    # spectator rows, ROADMAP r4).  Split structure is exact; float
-    # fields can drift by f32 ulps at multi-tile N (tile-boundary
-    # reassociation, tests/test_wave_compact.py).  Promote to auto iff
-    # AUC within 5e-5 of the higgs_ct arm (reassociation noise is
-    # ~1e-7 relative; anything larger is a real bug) and it/s >= 1.1x
-    # the ct number
-    "higgs_compact": dict(n=10_500_000, f=28, cache_as="higgs", params={
-        "objective": "binary", "metric": "auc", "num_leaves": 255,
-        "max_bin": 63, "learning_rate": 0.1, "min_data_in_leaf": 1,
-        "tpu_histogram_mode": "pallas_ct", "tpu_wave_width": 32,
-        "tpu_wave_compact": True},
-        warmup=3, measured=10, timeout=2700),
     # exact-commit-order waves at the flagship (tpu_wave_order=exact):
     # trees match tpu_wave_width=1 bit-for-bit, so its AUC delta vs the
     # reference equals the EXACT arm's (+7.7e-6 at 10.5M).  This is the
@@ -139,15 +125,6 @@ SHAPES = {
     # currently gated to ncols*bin_pad <= 2048 — these arms supply the
     # wide-F datapoints; the W=16-epsilon / W=32-bosch pathology says
     # wide-F cells can surprise)
-    # wide-F compaction arm (r5): epsilon under pallas_t + the
-    # vector-partition compact tier — the wide-shape form of
-    # higgs_compact; run when a window allows (not in the armed chain)
-    "epsilon_tc": dict(n=400_000, f=2000, cache_as="epsilon", params={
-        "objective": "binary", "metric": "auc", "num_leaves": 255,
-        "max_bin": 63, "learning_rate": 0.1, "min_data_in_leaf": 1,
-        "tpu_histogram_mode": "pallas_t", "tpu_wave_width": 32,
-        "tpu_wave_compact": True},
-        warmup=2, measured=5, timeout=2700),
     # expo_cat sits just past the ct auto bound (40 cols x 64-pad =
     # 2560 > 2048) so it pays the pallas_t two-pass pipeline; this arm
     # prices ct there — with the small per-wave work of 2M x 40, the

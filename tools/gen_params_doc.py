@@ -96,18 +96,11 @@ NOTES = {
                         "update engine (score += leaf_value[leaf_id]): "
                         "XLA gather, or the bit-equal Pallas "
                         "compare-select kernel; auto = gather",
-    "tpu_wave_compact": "true / false — spectator-row compaction for "
-                        "the transposed Pallas wave kernels "
-                        "(pallas_ct / pallas_t): late waves gather "
-                        "only the rows whose leaf is still splitting "
-                        "into capacity tiers (split structure "
-                        "unchanged; float fields can drift by f32 "
-                        "ulps at multi-tile N); opt-in",
     "tpu_bin_pack": "auto / true / false — 4-bit bin packing (at most 16 "
                     "bins/column: max_bin<=15 plus the reserved bin)",
     "tpu_autotune": "off / prior / measure / force — measured on-device "
                     "kernel autotuner for the wave cell (hist kernel, "
-                    "wave width, precision, compaction): off = hand-tuned "
+                    "wave width, precision): off = hand-tuned "
                     "heuristics only, prior = heuristics + decision "
                     "telemetry, measure = microbench the viable cells on "
                     "a cache miss, force = always re-measure; see "
@@ -389,7 +382,7 @@ GROUPS = [
         "checkpoint_every", "checkpoint_dir"]),
     ("TPU-native", [
         "tpu_growth", "tpu_wave_width", "tpu_wave_order", "tpu_wave_chunk",
-        "tpu_wave_lookup", "tpu_wave_compact", "tpu_histogram_mode",
+        "tpu_wave_lookup", "tpu_histogram_mode",
         "tpu_hist_precision", "tpu_score_update", "tpu_bin_pack",
         "tpu_sparse", "tpu_sparse_kernel", "tpu_use_dp", "tpu_predict",
         "tpu_fused_iter", "tpu_pallas_interpret", "tpu_profile_dir"]),
