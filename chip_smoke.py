@@ -42,6 +42,8 @@ FLAGSHIP = {"objective": "binary", "num_leaves": 255, "max_bin": 63,
 DEFAULT_ROUNDS = 5
 STAGED_ROUNDS = 2
 MULTICHIP_ROUNDS = 3
+MULTICHIP_ROWS = 2_000_000  # of the phase's own binned table
+MULTICHIP_SHARD_ROWS = 300_000  # no device's rows end where a file does
 KERNEL_ROWS = 65_536
 WAVE_WIDTH = 32
 PREDICT_ROWS = 200_000      # above the 100,000-row device-path threshold
@@ -107,10 +109,11 @@ def cache_census(cache_dir):
     return len(sizes), sum(sizes)
 
 
-def peak_bytes():
-    """peak_bytes_in_use of every local device, in device order."""
+def device_bytes(counter="peak_bytes_in_use"):
+    """One of the allocator's counters on every local device, in device
+    order."""
     from lightgbm_tpu.obs.memory import device_memory_stats
-    return [row.get("peak_bytes_in_use") for row in device_memory_stats()]
+    return [row.get(counter) for row in device_memory_stats()]
 
 
 def split_trees(bst):
@@ -118,10 +121,6 @@ def split_trees(bst):
     gbdt = bst._gbdt
     gbdt._materialize()
     return [t for t in gbdt.models if t.num_leaves > 1]
-
-
-def root_split(tree):
-    return int(tree.split_feature_inner[0]), int(tree.threshold_in_bin[0])
 
 
 def check_learner(gbdt, expect, fused):
@@ -180,7 +179,7 @@ def train_and_check(name, params, train_set, rounds, holdout, expect,
           "iteration %s (the first includes set-up and compilation), "
           "peak_bytes_in_use %s"
           % (name, rounds, [t.num_leaves for t in trees], got_auc,
-             clock.seconds, peak_bytes()), flush=True)
+             clock.seconds, device_bytes()), flush=True)
     return bst, got_auc
 
 
@@ -362,56 +361,81 @@ def phase_cli(workdir):
           "binary.test" % len(pred), flush=True)
 
 
-def phase_multichip(train_set, holdout, params, rounds, expect_dp,
-                    expect_serial, serial_fused):
-    """tree_learner=data over every device, against a serial run forced
-    to the kernel and precision the mesh learner resolves to."""
+def phase_multichip(rows, columns, shard_rows, params, rounds, expect_dp,
+                    workdir, seed=28):
+    """tree_learner=data over every device, from a binned directory of the
+    phase's own (benchmark/gen.py; its shard boundary falls inside a
+    device's rows), held to the benchmark's plain reference: every leaf's
+    count is the rows that walk to it, and the first step's leaf values,
+    scores and split gains are the reference's own."""
     import jax
+    import lightgbm_tpu as lgb
+    from benchmark import gen
+    from benchmark.files import load_module
+    tree_dict = load_module("drivers", "train_loop").tree_dict
+    gbdt_plain = load_module("references", "gbdt_plain")
+
     n_dev = jax.device_count()
-
-    def placements(env):
-        it = env.iteration + 1
-        if it not in (1, rounds):
-            return
-        gbdt = env.model._gbdt
-        grad, _ = gbdt.objective.get_gradients(gbdt._score_for_objective())
-        for what, arr in (("score", gbdt._score_dev), ("gradient", grad)):
-            print("multichip: after round %d the %s array is %s%s on %s "
-                  "with sharding %s"
-                  % (it, what, arr.dtype, list(arr.shape),
-                     sorted(d.id for d in arr.devices()), arr.sharding),
-                  flush=True)
-
-    dp, dp_auc = train_and_check(
-        "multichip data-parallel", dict(params, tree_learner="data"),
-        train_set, rounds, holdout, expect_dp, fused=False,
-        after_iteration=placements)
-    lrn = dp._gbdt.learner
+    params = dict(params, tree_learner="data")
+    num_bin = int(params["max_bin"])
+    made = gen.generate({"rows": rows, "columns": columns,
+                         "shard_rows": shard_rows, "params": params},
+                        seed, workdir)
+    ds = lgb.Dataset.from_binned(workdir, params=dict(params))
+    ds.construct()
+    bst = lgb.Booster(dict(params), ds)
+    gbdt = bst._gbdt
+    lrn = gbdt.learner
+    jax.block_until_ready(lrn.X)
+    print("multichip: %d x %d in %d shards; after Booster(...) "
+          "bytes_in_use %s" % (rows, columns, made["shards"],
+                               device_bytes("bytes_in_use")), flush=True)
+    check_learner(gbdt, expect_dp, fused=False)
+    check(ds._handle._binned is None,
+          "multichip: the host built the whole bin matrix")
     check(lrn.mesh.devices.size == n_dev,
           "multichip: mesh has %d of %d devices", lrn.mesh.devices.size,
           n_dev)
-    shard_devices = {s.device.id for s in lrn.X.addressable_shards}
-    check(shard_devices == {d.id for d in jax.devices()}
-          and len(lrn.X.addressable_shards) == n_dev,
-          "multichip: X has shards on devices %s", sorted(shard_devices))
+    shards = lrn.X.addressable_shards
+    check({s.device.id for s in shards} == {d.id for d in jax.devices()}
+          and len(shards) == n_dev,
+          "multichip: X has shards on devices %s",
+          sorted(s.device.id for s in shards))
     print("multichip: X %s sharded %s, one shard of %s on each of %d "
           "devices" % (list(lrn.X.shape), lrn.X.sharding.spec,
-                       list(lrn.X.addressable_shards[0].data.shape), n_dev),
-          flush=True)
-    serial, serial_auc = train_and_check(
-        "multichip serial reference",
-        dict(params, tpu_histogram_mode="pallas_t",
-             tpu_hist_precision="hilo"),
-        train_set, rounds, holdout, expect_serial, fused=serial_fused)
-    check(abs(dp_auc - serial_auc) <= 1e-3,
-          "multichip: data-parallel AUC %.5f vs serial %.5f", dp_auc,
-          serial_auc)
-    dp_root = root_split(split_trees(dp)[0])
-    serial_root = root_split(split_trees(serial)[0])
-    check(dp_root == serial_root,
-          "multichip: root split %s vs serial %s", dp_root, serial_root)
-    print("multichip: %d devices, AUC %.5f vs serial %.5f, same root "
-          "split %s" % (n_dev, dp_auc, serial_auc, dp_root), flush=True)
+                       list(shards[0].data.shape), n_dev), flush=True)
+    scores = []
+    for i in range(rounds):
+        bst.update()
+        scores.append(np.array(gbdt.train_score[0], np.float32))
+        if i == 0:
+            print("multichip: after the first step bytes_in_use %s; the "
+                  "score is on %s with sharding %s"
+                  % (device_bytes("bytes_in_use"),
+                     sorted(d.id for d in gbdt._score_dev.devices()),
+                     gbdt._score_dev.sharding), flush=True)
+    trees = [tree_dict(t) for t in split_trees(bst)]
+    check(len(trees) == rounds, "multichip: %d of %d trees split",
+          len(trees), rounds)
+    del bst, ds
+    bins, label = gen.open_shards(workdir)
+    gaps = gbdt_plain.follow(
+        trees, scores, bins, label, params,
+        gen.mapper_dicts(1, num_bin)[0]["default_bin"], num_bin, 8, seed)
+    check(gaps["count_mismatch"] == 0,
+          "multichip: %d rows sit in a leaf they do not walk to",
+          gaps["count_mismatch"])
+    for name, limit in (("leaf_value_gap_step0", 1e-4),
+                        ("score_gap_step0", 1e-4),
+                        ("split_gain_gap_step0", 0.01)):
+        check(gaps[name] <= limit, "multichip: %s %.3g over %.3g", name,
+              gaps[name], limit)
+    print("multichip: %d devices, leaves %s, count mismatch 0, step-0 gaps "
+          "%s, loss gap %.3g"
+          % (n_dev, [t["num_leaves"] for t in trees],
+             {k: float("%.3g" % gaps[k]) for k in (
+                 "leaf_value_gap_step0", "score_gap_step0",
+                 "split_gain_gap_step0")}, gaps["loss_gap"]), flush=True)
 
 
 def main(argv=None):
@@ -477,17 +501,18 @@ def main(argv=None):
             phase_cli(workdir)
     if "multichip" in only:
         if jax.device_count() >= 2:
-            phase_multichip(train_set, holdout, FLAGSHIP, MULTICHIP_ROUNDS,
-                            EXPECT_DATA_PARALLEL,
-                            dict(EXPECT_SERIAL, hist_mode="pallas_t"),
-                            serial_fused=True)
+            with tempfile.TemporaryDirectory() as workdir:
+                phase_multichip(MULTICHIP_ROWS, FEATURES,
+                                MULTICHIP_SHARD_ROWS, FLAGSHIP,
+                                MULTICHIP_ROUNDS, EXPECT_DATA_PARALLEL,
+                                workdir)
         else:
             print("multichip: skipped (1 device)", flush=True)
 
     entries1, bytes1 = cache_census(cache_dir)
     print("chip_smoke: compile cache %s now %d entries, %d bytes (%+d "
           "entries); peak_bytes_in_use %s; %.0f s in all"
-          % (cache_dir, entries1, bytes1, entries1 - entries0, peak_bytes(),
+          % (cache_dir, entries1, bytes1, entries1 - entries0, device_bytes(),
              time.perf_counter() - t0), flush=True)
     print("chip_smoke: passed %s at %d rows" % (only, args.rows),
           flush=True)

@@ -308,6 +308,11 @@ class GBDT:
                 Log.fatal("number of class for initial score error")
             score0[:] = np.asarray(init).reshape(k, self.num_data)
         self._score_dev = jnp.asarray(score0, self.score_dtype)
+        # a mesh learner keeps the rows where its grow program takes and
+        # leaves them, from the first iteration on
+        place = getattr(self.learner, "place_score", None)
+        if place is not None:
+            self._score_dev = place(self._score_dev)
         self._score_host = None
         # re-apply every existing model (incl. loaded/continued ones) on the
         # (possibly new) training data
@@ -458,6 +463,7 @@ class GBDT:
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *devs) \
             if len(devs) > 1 else devs[0]
         host = fenced_get(stacked)      # counted: one sync per batch
+        mesh = getattr(self.learner, "mesh", None)
         for j, i in enumerate(pending):
             ht = jax.tree_util.tree_map(lambda x: x[j], host) \
                 if len(devs) > 1 else host
@@ -473,7 +479,19 @@ class GBDT:
             c["kernel_rows"] += (c["waves"] - c["compacted"]) * c["rows"]
             it, tid = self._tree_iteration(i)
             timers.count("tree", it=it, tree=tid,
-                         rows_visited=c["rows"] + c["kernel_rows"], **c)
+                         rows_visited=c["rows"] + c["kernel_rows"],
+                         shards=1 if mesh is None else int(mesh.devices.size),
+                         allreduce_bytes=c["allreduce_words"] * jnp.dtype(
+                             self.learner.dtype).itemsize, **c)
+        if mesh is not None:
+            # what each of the mesh's devices has held at most, read where
+            # the trees come to the host anyway: whether one device holds
+            # more than its share of the rows
+            from ..obs.memory import device_memory_stats
+            ids = {int(d.id) for d in mesh.devices.flat}
+            timers.count("mesh_memory", peak_bytes_in_use=[
+                row.get("peak_bytes_in_use", 0)
+                for row in device_memory_stats() if row["id"] in ids])
         if self._metrics is not None:
             # host num_leaves is free here — trees just landed on host
             self._metrics["leaves"].inc(

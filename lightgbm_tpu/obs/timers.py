@@ -225,9 +225,12 @@ UNSCOPED = "unscoped"
 # `kernel_rows` holds what the row-slab launches visited (`compacted`
 # waves); a tree's record in the ring (models/gbdt.py) adds the other
 # waves' `rows` each and ``rows_visited = rows + kernel_rows``, as host
-# integers: waves x rows passes int32 at real sizes
+# integers: waves x rows passes int32 at real sizes.  `allreduce_words`
+# counts array elements, from the operands' shapes, that one shard of a
+# mesh hands to `psum` (0 on one device); the record turns them into
+# ``allreduce_bytes`` and adds ``shards``, the mesh's devices
 COUNTERS = ("waves", "slots", "attempted", "committed", "hist_rows", "rows",
-            "kernel_rows", "compacted")
+            "kernel_rows", "compacted", "allreduce_words")
 # jax.monitoring durations that become child spans of the open span
 _JAX_DURATIONS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
@@ -439,6 +442,24 @@ def register_device_scopes(hlo_text):
     if module is not None:
         _scopes[module] = table
     return module
+
+
+def scoped_executable(jitted, cache, args):
+    """`jitted` compiled ahead of time for `args`, one executable per
+    argument signature, kept in `cache`.  The first call with a signature
+    lowers and compiles (or loads from the compile cache) exactly as the
+    jitted call would; holding the Compiled is what lets its own HLO text
+    be read, at no second compile, for the instruction-to-scope table."""
+    import jax
+    key = jax.tree_util.tree_structure(args), tuple(
+        (a.shape, a.dtype, getattr(a, "weak_type", False))
+        for a in jax.tree_util.tree_leaves(args))
+    compiled = cache.get(key)
+    if compiled is None:
+        compiled = jitted.lower(*args).compile()
+        register_device_scopes(compiled.as_text())
+        cache[key] = compiled
+    return compiled
 
 
 def device_scopes():

@@ -138,7 +138,7 @@ class FusedIteration:
         self._step = jax.jit(step)
         # the step compiled ahead of time, one executable per argument
         # signature: holding the Compiled is what lets its own HLO text be
-        # read (timers.register_device_scopes) at no second compile
+        # read (timers.scoped_executable) at no second compile
         self._compiled = {}
 
     def step_args(self, score, row_mult, feature_mask, scale) -> tuple:
@@ -199,21 +199,7 @@ class FusedIteration:
                               "feature_mask", "scale"))
         t0 = obs.entry_start()
         with timers.span("dispatch"):
-            tree, leaf_id, new_score = self._executable(args)(*args)
+            tree, leaf_id, new_score = timers.scoped_executable(
+                self._step, self._compiled, args)(*args)
         obs.entry_end("fused_iter", t0, (tree, leaf_id, new_score))
         return tree, leaf_id, new_score
-
-    def _executable(self, args):
-        """The step compiled for these arguments' shapes and dtypes.  The
-        first call with a signature lowers and compiles (or loads from the
-        compile cache) exactly as the jitted call would, and registers the
-        executable's instruction-to-scope table."""
-        key = jax.tree_util.tree_structure(args), tuple(
-            (a.shape, a.dtype, getattr(a, "weak_type", False))
-            for a in jax.tree_util.tree_leaves(args))
-        compiled = self._compiled.get(key)
-        if compiled is None:
-            compiled = self._step.lower(*args).compile()
-            timers.register_device_scopes(compiled.as_text())
-            self._compiled[key] = compiled
-        return compiled

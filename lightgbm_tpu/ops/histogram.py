@@ -97,12 +97,27 @@ def _scatter_accumulate(binned, w, num_bins: int, logical_cols: int = 0):
 
 
 def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
-                       logical_cols: int = 0):
+                       logical_cols: int = 0, hilo: bool = False):
     """(F, B, 3) via chunked one-hot contraction on the MXU.
 
     logical_cols > 0: binned is 4-bit packed (ops/pack.py); chunks unpack
-    in-scan so the full-width matrix never materializes in HBM."""
+    in-scan so the full-width matrix never materializes in HBM.
+
+    hilo: on the TPU the contraction rounds `w` to bf16 (one product a
+    weight).  True contracts the weight cut to bf16's mantissa and what
+    the cut left, as six channels of the one pass, and adds the halves:
+    the split of ops/pallas_wave.py `_hi_lo`, for a root whose waves take
+    the exact kernels.  The cut is a mask on the bits: XLA folds a
+    float32 -> bf16 -> float32 round trip to nothing (excess precision is
+    allowed), and the second product would be of zeros (read on the v5e,
+    PR 28: the round trip's sums were the one product's to the last bit)."""
+    if hilo:
+        hi = lax.bitcast_convert_type(
+            lax.bitcast_convert_type(w, jnp.uint32) & jnp.uint32(0xFFFF0000),
+            w.dtype)
+        w = jnp.concatenate([hi, w - hi], axis=-1)          # (N, 6)
     n, fdev = binned.shape
+    k = w.shape[1]
     f = logical_cols or fdev
     chunk = min(chunk, max(n, 1))
     pad = (-n) % chunk
@@ -111,7 +126,7 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
         w = jnp.pad(w, ((0, pad), (0, 0)))
     nchunks = (n + pad) // chunk
     xb = binned.reshape(nchunks, chunk, fdev)
-    wb = w.reshape(nchunks, chunk, 3)
+    wb = w.reshape(nchunks, chunk, k)
 
     def step(acc, args):
         xc, wc = args
@@ -124,13 +139,13 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
                                preferred_element_type=wc.dtype)
         return acc, None
 
-    init = jnp.zeros((f, num_bins, 3), dtype=w.dtype)
+    init = jnp.zeros((f, num_bins, k), dtype=w.dtype)
     if nchunks == 1:
         hist, _ = step(init, (xb[0], wb[0]))
-        return hist
-    from .grow import vary_like
-    hist, _ = lax.scan(step, vary_like(init, xb, wb), (xb, wb))
-    return hist
+    else:
+        from .grow import vary_like
+        hist, _ = lax.scan(step, vary_like(init, xb, wb), (xb, wb))
+    return hist[..., :3] + hist[..., 3:] if hilo else hist
 
 
 def gathered_histogram(X, grad, hess, row_mult, idx, valid, num_bins: int,
@@ -162,11 +177,11 @@ def leaf_histogram_scatter(binned, grad, hess, leaf_id, leaf, row_mult,
     return _scatter_accumulate(binned, w, num_bins, logical_cols)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "chunk", "logical_cols"))
+@functools.partial(jax.jit, static_argnames=("num_bins", "chunk",
+                                             "logical_cols", "hilo"))
 def leaf_histogram_onehot(binned, grad, hess, leaf_id, leaf, row_mult,
                           num_bins: int, chunk: int = 16384,
-                          logical_cols: int = 0):
+                          logical_cols: int = 0, hilo: bool = False):
     """(F, B, 3) histogram via chunked one-hot matmul on the MXU.
 
     For each row chunk: one_hot(bins) (C, F, B) contracted with weights
@@ -174,7 +189,8 @@ def leaf_histogram_onehot(binned, grad, hess, leaf_id, leaf, row_mult,
     one-hot tensor never exceeds chunk x F x B.
     """
     w = _weights(grad, hess, leaf_id, leaf, row_mult)  # (N, 3)
-    return _onehot_accumulate(binned, w, num_bins, chunk, logical_cols)
+    return _onehot_accumulate(binned, w, num_bins, chunk, logical_cols,
+                              hilo)
 
 
 def leaf_histogram(binned, grad, hess, leaf_id, leaf, row_mult,

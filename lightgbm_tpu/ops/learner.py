@@ -105,33 +105,64 @@ def build_split_params(config: Config) -> SplitParams:
     )
 
 
-def paged_device_matrix(train_data, row_pad: int = 0):
+def _page_rows(reader, lo: int, hi: int, row_pad: int, device=None):
+    """Rows [lo, hi) of `reader` and `row_pad` zero rows after them as one
+    array on `device` (None: the default device), one page (the part of
+    one directory shard inside the range) at a time."""
+    ids = {} if device is None else {"device": int(device.id)}
+    parts = []
+    for shard, (_, view) in enumerate(reader.iter_rows(lo, hi)):
+        with timers.span("upload_shard", shard=shard, **ids):
+            with timers.span("host_copy"):
+                page = np.ascontiguousarray(view)
+            with timers.span("h2d"):    # the call; the copy is async
+                parts.append(jax.device_put(page, device))
+    if row_pad:
+        parts.append(jnp.zeros((int(row_pad), reader.num_columns),
+                               reader.dtype, device=device))
+    if len(parts) == 1:
+        return parts[0]
+    with timers.span("concat"):         # dispatch only
+        return jnp.concatenate(parts, axis=0)
+
+
+def paged_device_matrix(train_data, row_pad: int = 0, sharding=None):
     """Device bin matrix paged shard-by-shard from a binned-format mmap
     reader (io/binned_format.py): the host never materializes the full
     (N, G) matrix, so peak host RSS stays O(shard) for out-of-core
     datasets.  Returns None when the dataset is not reader-backed —
-    callers fall back to the one-shot host upload."""
+    callers fall back to the one-shot host upload.
+
+    `sharding` (a row sharding over a mesh, parallel/mesh.py): the rows
+    and their `row_pad` tail are cut into one equal range a device, in
+    mesh order, and each of this process's devices is sent only the pages
+    of its own range, a thread a device; the host holds a page a device
+    and no device ever holds another's rows."""
     reader = getattr(train_data, "_binned_reader", None)
     if reader is None or reader.num_columns == 0 or reader.num_data == 0:
         return None
-    # iter_rows restricts paging to the reader's row_range — on a
-    # rank-sharded open (io/dataset.py from_binned(comm=...)) this rank
-    # uploads only its own rows and never maps a foreign shard
+    # the reader's row_range scopes the paging — on a rank-sharded open
+    # (io/dataset.py from_binned(comm=...)) this rank uploads only its
+    # own rows and never maps a foreign shard
+    lo, hi = reader.row_range
     with timers.span("upload"):
-        parts = []
-        for shard, (_, view) in enumerate(reader.iter_rows()):
-            with timers.span("upload_shard", shard=shard):
-                with timers.span("host_copy"):
-                    page = np.ascontiguousarray(view)
-                with timers.span("h2d"):    # the call; the copy is async
-                    parts.append(jnp.asarray(page))
-        if row_pad:
-            parts.append(jnp.zeros((int(row_pad), reader.num_columns),
-                                   parts[0].dtype))
-        if len(parts) == 1:
-            return parts[0]
-        with timers.span("concat"):         # dispatch only
-            return jnp.concatenate(parts, axis=0)
+        if sharding is None:
+            return _page_rows(reader, lo, hi, row_pad)
+        devices = [d for d in sharding.mesh.devices.flat
+                   if d.process_index == jax.process_index()]
+        local = (hi - lo + int(row_pad)) // len(devices)
+
+        def one(k):
+            a = min(lo + k * local, hi)
+            b = min(a + local, hi)
+            return _page_rows(reader, a, b, local - (b - a), devices[k])
+
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(len(devices)) as pool:
+            shards = list(pool.map(one, range(len(devices))))
+        return jax.make_array_from_single_device_arrays(
+            (local * sharding.mesh.devices.size, reader.num_columns),
+            sharding, shards)
 
 
 class SerialTreeLearner:

@@ -236,8 +236,11 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
     compact = slab_active(compact, hist_mode, hist_dtype, psum_axis,
                           pallas_interpret)
 
-    def maybe_psum(x):
+    def maybe_psum(x, sent):
+        """`x` summed over the mesh's shards; its element count joins
+        `sent`, the operands this trace hands to the all-reduce."""
         if psum_axis is not None:
+            sent.append(x.size)
             with scope("hist_allreduce"):
                 return lax.psum(x, psum_axis)
         return x
@@ -638,13 +641,19 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 hist0 = leaf_histogram_sparse(
                     X, grad, hess, leaf_id, 0, row_mult, hist_bins, Fc)
             else:
-                root_kw = ({"chunk": chunk}
+                # where the wave kernels make two bf16 products a weight
+                # the root does too: the larger child is its parent less
+                # the smaller, and down that chain a leaf would be left
+                # with all of a once-rounded root's error
+                root_kw = ({"chunk": chunk,
+                            "hilo": bool(use_pallas_hist and hist_hilo)}
                            if root_hist_fn is leaf_histogram_onehot else {})
                 hist0 = root_hist_fn(
                     X, grad, hess, leaf_id, 0, row_mult, num_bins=hist_bins,
                     logical_cols=packed_cols, **root_kw)
-        root_sums = maybe_psum(root_sums)
-        hist0 = maybe_psum(hist0)
+        root_sent = []
+        root_sums = maybe_psum(root_sums, root_sent)
+        hist0 = maybe_psum(hist0, root_sent)
         Fh, B = hist0.shape[0], hist0.shape[1]
         with scope("root_histogram"):
             if cache_hists:
@@ -658,10 +667,16 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         sums = jnp.zeros((L, 3), hist_dtype).at[0].set(root_sums)
         # what the loop counts (obs/timers.py COUNTERS): each wave adds
         # [1, W, k, kc, rows of the committed smaller children, 0, rows
-        # its slab launches visited, 1 if it ran any]; `rows` is the rows
-        # every full pass visits
+        # its slab launches visited, 1 if it ran any, elements it handed
+        # to the all-reduce]; `rows` is the rows every full pass visits.
+        # Under a mesh the record is the mesh's: every shard's n rows
+        # (the child counts already are global, they come from the summed
+        # histograms; no shard runs a slab), and the elements ONE shard
+        # hands over, the root's to start with
+        shards = 1 if psum_axis is None else lax.psum(1, psum_axis)
         counters = jnp.zeros(len(COUNTERS), jnp.int32).at[
-            COUNTERS.index("rows")].set(n)
+            COUNTERS.index("rows")].set(n * shards).at[
+            COUNTERS.index("allreduce_words")].set(sum(root_sent))
         tree = TreeArrays(
             num_leaves=jnp.asarray(1, jnp.int32),
             split_feature=jnp.zeros(L - 1, jnp.int32),
@@ -755,14 +770,16 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             else:
                 leaf_id, hist_small, slab = wave_pass(
                     leaf_id, tbl, cols, psrc, small_id, valid)
-            hist_small = maybe_psum(hist_small)             # (W, F, B, 3)
+            sent = []
+            hist_small = maybe_psum(hist_small, sent)       # (W, F, B, 3)
             if cache_hists:
                 with scope("wave_histogram"):
                     hist_large = hists[parent] - hist_small
             else:
                 hist_large = maybe_psum(
                     sparse_child_hists(leaf_id, large_id, valid)
-                    if sparse_mode else rehist(leaf_id, large_id, valid))
+                    if sparse_mode else rehist(leaf_id, large_id, valid),
+                    sent)
 
             # ---- vectorized split search for all 2W children
             with scope("split_search"):
@@ -909,7 +926,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.sum(jnp.where(commit, jnp.minimum(
                             info[:, LEFT_COUNT],
                             info[:, RIGHT_COUNT]).astype(jnp.int32), 0)),
-                        jnp.asarray(0, jnp.int32), slab[0], slab[1]]),
+                        jnp.asarray(0, jnp.int32), slab[0], slab[1],
+                        jnp.asarray(sum(sent), jnp.int32)]),
                 )
             return (nn + kc, kc == 0, leaf_id, hists, bests, sums, tree)
 

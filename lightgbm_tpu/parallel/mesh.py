@@ -32,8 +32,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..io.dataset import TrainingData
+from ..obs import timers
 from ..ops.grow import make_grow_fn
-from ..ops.learner import SerialTreeLearner
+from ..ops.learner import SerialTreeLearner, paged_device_matrix
 from ..ops.wave import WAVE_ONLY_MODES
 from ..ops.split_finder import FeatureMeta
 from ..utils.config import Config
@@ -136,24 +137,38 @@ class DataParallelTreeLearner(SerialTreeLearner):
             Log.fatal("Multi-process training needs local rows (%d) "
                       "pre-padded to a multiple of the per-process shard "
                       "count (%d)", n, max(n_shards // self._nproc, 1))
-        # every process must contribute identically-shaped shards (equal
-        # per-process row counts pre-partitioned by the caller, padded to
-        # the per-process shard quantum here)
+        # one process pads the table's tail, once, at upload, to the
+        # serial learner's 1024-row quantum a shard: a shard then has the
+        # shape the same rows have on one chip, and the kernel's launch
+        # pads nothing wave after wave.  The pad rows carry row_mult 0 and
+        # are in no sum.  Several processes pad nothing: a process's pad
+        # would lie in the middle of the global row order, where the
+        # caller's global score and gradient buffers have no hole for it,
+        # which is why the check above refuses rows that would need any
         local_shards = max(n_shards // self._nproc, 1)
-        pad = pad_rows(n, local_shards)
+        pad = 0 if self._nproc > 1 else pad_rows(n, n_shards * 1024)
         self._pad = pad
-        binned = train_data.binned
-        if pad:
-            binned = np.concatenate(
-                [binned, np.zeros((pad, binned.shape[1]), binned.dtype)])
         # the sparse store replaces X below — don't upload (and orphan)
         # the dense matrix when it will never be used.  Must mirror the
         # base ctor's gate exactly (voting subclasses stay dense).
         want_sparse = (bool(config.tpu_sparse)
                        and str(config.tree_learner)
                        in ("data", "data_parallel"))
+        # a reader-backed dataset is paged to the devices, each its own
+        # rows: the host never builds the matrix (train_data._binned
+        # stays None)
+        x_sharding = NamedSharding(self.mesh, P(DATA_AXIS, None))
         X_dev = (None if want_sparse
-                 else make_row_sharded(self.mesh, binned, extra_dims=1))
+                 else paged_device_matrix(train_data, pad, x_sharding))
+        binned = None
+        if X_dev is None:
+            binned = train_data.binned
+            if pad:
+                binned = np.concatenate(
+                    [binned, np.zeros((pad, binned.shape[1]),
+                                      binned.dtype)])
+            if not want_sparse:
+                X_dev = make_row_sharded(self.mesh, binned, extra_dims=1)
         super().__init__(config, train_data, psum_axis=DATA_AXIS,
                          device_data=X_dev)
         # GLOBAL row count: every process contributes n+pad rows
@@ -195,6 +210,14 @@ class DataParallelTreeLearner(SerialTreeLearner):
                 make_row_sharded(self.mesh, np.asarray(leaf))
                 for leaf in host_store])
         self._row_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
+        # the grow program's arguments keep one placement from the first
+        # call on (rows over the mesh, the feature mask on every device),
+        # so it is lowered and compiled once a booster: ahead of time, as
+        # the fused step is, which registers its scope table
+        # (`hist_allreduce` among its scopes)
+        self._replicated = NamedSharding(self.mesh, P())
+        self._full_mask = jax.device_put(self._full_mask, self._replicated)
+        self._compiled = {}
         self._ones = make_row_sharded(
             self.mesh,
             np.concatenate([np.ones(n, np.float32),
@@ -304,7 +327,8 @@ class DataParallelTreeLearner(SerialTreeLearner):
     def _pad_rows_dev(self, arr, fill=0.0):
         if isinstance(arr, jax.Array) and arr.ndim == 1 \
                 and arr.shape[0] == self._global_rows \
-                and arr.dtype == self.dtype:
+                and arr.dtype == self.dtype \
+                and arr.sharding.is_equivalent_to(self._row_sharding, 1):
             return arr          # already a (global) row-sharded device array
         if self._nproc == 1:
             # async on-device pad + placement (no host round-trip: the
@@ -319,6 +343,19 @@ class DataParallelTreeLearner(SerialTreeLearner):
             arr = np.concatenate(
                 [arr, np.full((self._pad,), fill, self.dtype)])
         return make_row_sharded(self.mesh, arr)
+
+    def place_score(self, score):
+        """The booster's (k, N) score where the staged chain's per-row
+        programs leave it once they have run over this learner's leaf
+        ids: rows over the mesh where they divide evenly, else on every
+        device.  Starting there, no program of the chain is lowered a
+        second time for a second placement.  (Multi-process: the score
+        stays the rank's own rows.)"""
+        if self._nproc > 1:
+            return score
+        even = score.shape[-1] % self.mesh.devices.size == 0
+        return jax.device_put(score, NamedSharding(
+            self.mesh, P(None, DATA_AXIS) if even else P()))
 
     def local_rows(self, global_arr):
         """This process's rows of a row-sharded global array, pad
@@ -347,13 +384,16 @@ class DataParallelTreeLearner(SerialTreeLearner):
             row_mult = self._pad_rows_dev(row_mult)
         if feature_mask is None:
             feature_mask = self.sample_feature_mask()
+        feature_mask = jax.device_put(feature_mask, self._replicated)
         args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs = self._obs
         obs.entry_args("tree_grow", self._grow, args,
                        names=("X", "grad", "hess", "row_mult",
                               "feature_mask", "Xt")[:len(args)])
         t0 = obs.entry_start()
-        tree, leaf_id = self._grow(*args)
+        with timers.span("dispatch"):
+            tree, leaf_id = timers.scoped_executable(
+                self._grow, self._compiled, args)(*args)
         obs.entry_end("tree_grow", t0, (tree, leaf_id))
         if getattr(self, "_m_coll", None) is not None:
             self._m_coll.inc(self._coll_tree_bytes)

@@ -521,6 +521,9 @@ class Config:
         # within 1.0e-4 — tools/BENCH_SUITE.md higgs_bf16); exact
         # growth, data-parallel execution, and every non-pallas engine
         # stay hilo.  Set 'hilo' to force the exact split everywhere.
+        # Where the kernels make two products the root's one-hot pass
+        # (ops/histogram.py) makes two as well: one would leave its
+        # rounding to the larger child of every split.
         "tpu_hist_precision": ("str", "auto"),
         # row-chunk size of the wave engine's fused partition+histogram
         # sweep; smaller chunks shrink the (chunk, F*B) one-hot tile

@@ -213,3 +213,177 @@ def test_feature_parallel_never_packs_nibbles():
     tree_s, _ = SerialTreeLearner(cfg_s, td_s).train(g, h)
     tree_f, _ = fp.train(g, h)
     assert _tree_signature(tree_f) == _tree_signature(tree_s)
+
+
+# ---------------------------------------------------------------------------
+# From a binned directory, over four of the devices: the deployment of the
+# benchmark's epsilon_2000_dp4 cell, tiny.  The directory's files end at
+# rows 1700, 3400, 5100 and 6800; a device's rows end at 2048, 4096 and 6144.
+
+DP4_ROWS, DP4_COLS, DP4_SHARD_ROWS = 7003, 12, 1700
+DP4 = {"objective": "binary", "num_leaves": 8, "max_bin": 63,
+       "min_data_in_leaf": 5, "learning_rate": 0.1, "verbose": -1,
+       "tpu_growth": "wave", "tpu_wave_width": 4}
+
+
+@pytest.fixture(scope="module")
+def dp4_dir(tmp_path_factory):
+    from lightgbm_tpu.io import binned_format
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(DP4_ROWS, DP4_COLS))
+    y = (X[:, 0] + 0.5 * X[:, 1] - 0.25 * X[:, 2]
+         + 0.1 * rng.normal(size=DP4_ROWS) > 0).astype(np.float64)
+    path = str(tmp_path_factory.mktemp("dp4") / "d")
+    ds = lgb.Dataset(X, label=y, params=dict(DP4)).construct()
+    binned_format.save_training_data(ds._handle, path,
+                                     shard_rows=DP4_SHARD_ROWS)
+    return path
+
+
+@pytest.fixture
+def four_devices(monkeypatch):
+    """`tree_learner=data` through the public path takes every device;
+    hand it the first four."""
+    from lightgbm_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setattr(mesh_mod, "make_data_mesh",
+                        lambda devices=None: make_data_mesh(
+                            jax.devices()[:4]))
+
+
+def _booster(path, **extra):
+    params = dict(DP4, **extra)
+    ds = lgb.Dataset.from_binned(path, params=dict(params))
+    ds.construct()
+    return lgb.Booster(dict(params), ds), ds
+
+
+def _tree_records():
+    from lightgbm_tpu.obs import timers
+    return [r["fields"] for r in timers.snapshot()
+            if r["kind"] == "count" and r["name"] == "tree"]
+
+
+def test_sharded_upload_gives_each_device_its_rows_and_no_others(
+        dp4_dir, four_devices):
+    from lightgbm_tpu.obs import timers
+    timers.clear()
+    bst, ds = _booster(dp4_dir, tree_learner="data")
+    lrn = bst._gbdt.learner
+    assert type(lrn) is DataParallelTreeLearner
+    assert lrn.mesh.devices.size == 4
+    # the host never built the matrix
+    assert ds._handle._binned is None
+    local = 2048                    # 7003 rows to 4 x 1024 a device
+    assert lrn.X.shape == (4 * local, DP4_COLS) and lrn._pad == 8192 - 7003
+    reader = ds._handle._binned_reader
+    whole = np.asarray(reader.matrix())
+    shards = sorted(lrn.X.addressable_shards, key=lambda s: s.index[0].start)
+    assert [s.device for s in shards] == list(lrn.mesh.devices.flat)
+    for k, s in enumerate(shards):
+        have = np.asarray(s.data)
+        real = whole[k * local:(k + 1) * local]
+        np.testing.assert_array_equal(have[:len(real)], real)
+        assert not have[len(real):].any()       # the tail's pad rows
+    # one upload_shard span a page, each naming its device
+    pages = [r["ids"] for r in timers.snapshot()
+             if r["kind"] == "span" and r["name"] == "upload_shard"]
+    assert sorted(p["device"] for p in pages) == [0, 0, 1, 1, 2, 2, 3, 3]
+    assert bst._gbdt._score_dev.sharding.is_equivalent_to(
+        jax.sharding.NamedSharding(lrn.mesh, jax.sharding.PartitionSpec()),
+        2)                          # 7003 rows do not divide by four
+
+
+def test_shards_root_histograms_add_up_to_the_whole_tables(
+        dp4_dir, four_devices):
+    """The share tied to the whole: the four local root histograms sum to
+    the whole table's, every real row counted once and no pad row."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import leaf_histogram_onehot
+    bst, ds = _booster(dp4_dir, tree_learner="data")
+    lrn = bst._gbdt.learner
+    rng = np.random.default_rng(3)
+    g = rng.normal(size=lrn.X.shape[0]).astype(np.float32)
+    h = rng.uniform(0.1, 0.3, size=lrn.X.shape[0]).astype(np.float32)
+    ones = np.asarray(lrn._ones)
+
+    def hist(x, lo, hi):
+        return np.asarray(leaf_histogram_onehot(
+            jnp.asarray(x), jnp.asarray(g[lo:hi]), jnp.asarray(h[lo:hi]),
+            jnp.zeros(hi - lo, jnp.int32), 0, jnp.asarray(ones[lo:hi]),
+            num_bins=lrn.num_bins), np.float64)
+
+    parts = [hist(np.asarray(s.data), s.index[0].start, s.index[0].stop)
+             for s in lrn.X.addressable_shards]
+    whole = hist(np.asarray(ds._handle._binned_reader.matrix()), 0, DP4_ROWS)
+    np.testing.assert_allclose(sum(parts), whole, rtol=1e-5, atol=1e-4)
+    assert sum(p[0, :, 2].sum() for p in parts) == DP4_ROWS
+
+
+def test_mesh_booster_grows_the_serial_trees_once_lowered_and_counted(
+        dp4_dir, four_devices):
+    from lightgbm_tpu.obs import timers
+    lowered = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _s, **kw: lowered.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration"
+        else None)
+    timers.clear()
+    timers._scopes.clear()
+    dp, _ = _booster(dp4_dir, tree_learner="data")
+    per_update = []
+    for _ in range(3):
+        before = len(lowered)
+        dp.update()
+        per_update.append(lowered[before:])
+    lrn = dp._gbdt.learner
+    # one lowering of the grow program a booster, and nothing at all is
+    # lowered after the first update
+    assert per_update[0].count("jit(grow)") == 1 and len(lrn._compiled) == 1
+    assert per_update[1] == [] == per_update[2]
+    table = timers.device_scopes()["jit_grow"]
+    assert {"hist_allreduce", "wave_histogram", "split_search",
+            "wave_partition", "root_histogram"} <= set(table.values())
+    # a `dispatch` span closes inside every iteration
+    spans = [r for r in timers.snapshot() if r["kind"] == "span"]
+    its = {r["seq"] for r in spans if r["name"] == "iteration"}
+    assert len(its) == 3
+    assert [r["cause"] in its for r in spans
+            if r["name"] == "dispatch"] == [True] * 3
+    dp._gbdt._materialize()
+    mesh_records = _tree_records()
+    # one `mesh_memory` record a batch of trees, a number a device (the
+    # CPU's allocator keeps none: zeros)
+    memory = [r["fields"]["peak_bytes_in_use"] for r in timers.snapshot()
+              if r["kind"] == "count" and r["name"] == "mesh_memory"]
+    assert memory == [[0, 0, 0, 0]]
+
+    timers.clear()
+    serial, _ = _booster(dp4_dir, tree_learner="serial")
+    for _ in range(3):
+        serial.update()
+    serial._gbdt._materialize()
+    assert not [r for r in timers.snapshot() if r["name"] == "mesh_memory"]
+    for td, ts in zip(dp._gbdt.models, serial._gbdt.models):
+        nl = ts.num_leaves
+        assert td.num_leaves == nl == 8
+        for field in ("split_feature_inner", "threshold_in_bin",
+                      "left_child", "right_child", "internal_count"):
+            np.testing.assert_array_equal(getattr(td, field)[:nl - 1],
+                                          getattr(ts, field)[:nl - 1])
+        np.testing.assert_array_equal(td.leaf_count[:nl], ts.leaf_count[:nl])
+        np.testing.assert_allclose(td.leaf_value[:nl], ts.leaf_value[:nl],
+                                   rtol=1e-4)
+    # 8 leaves at width 4 are three waves: 1, 2 and 4 splits.  One shard
+    # hands over the root's three sums and (F, B, 3) histogram, then a
+    # (W, F, B, 3) block a wave, in float32
+    fb3 = DP4_COLS * lrn.num_bins * 3
+    for rec, one in zip(mesh_records, _tree_records()):
+        assert rec["waves"] == 3 == one["waves"]
+        assert rec["shards"] == 4 and one["shards"] == 1
+        assert rec["allreduce_bytes"] == 4 * (3 + fb3 + 3 * 4 * fb3)
+        assert one["allreduce_bytes"] == 0
+        # the record is the mesh's: every shard's rows, pad included
+        assert rec["rows"] == 8192 and one["rows"] == 7 * 1024
+        assert rec["rows_visited"] == 4 * 8192
+        for key in ("committed", "slots", "hist_rows"):
+            assert rec[key] == one[key]
