@@ -223,9 +223,10 @@ SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_compact",
 UNSCOPED = "unscoped"
 # the grow loop's counter vector (ops/wave.py), in order.  On the device
 # `kernel_rows` holds what the row-slab launches visited (`compacted`
-# waves); a tree's record in the ring (models/gbdt.py) adds the other
-# waves' `rows` each and ``rows_visited = rows + kernel_rows``, as host
-# integers: waves x rows passes int32 at real sizes.  `allreduce_words`
+# waves; under a mesh every shard's launches, summed); a tree's record
+# in the ring (models/gbdt.py) adds the other waves' `rows` each and
+# ``rows_visited = rows + kernel_rows``, as host integers: waves x rows
+# passes int32 at real sizes.  `allreduce_words`
 # counts array elements, from the operands' shapes, that one shard of a
 # mesh hands to `psum` (0 on one device); the record turns them into
 # ``allreduce_bytes`` and adds ``shards``, the mesh's devices

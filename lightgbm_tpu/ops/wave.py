@@ -118,12 +118,13 @@ def slab_active(compact: bool, hist_mode: str, hist_dtype, psum_axis,
                 pallas_interpret: bool = False) -> bool:
     """True when a wave's histogram launch reads the row slab of its
     smaller children in place of all N rows: the pallas_t kernel really
-    runs (or its interpreter), on one device.  Decided from what the
-    program can observe; the serial learner reports it (obs_info)."""
+    runs (or its interpreter), on one device or on every shard of a data
+    mesh (`psum_axis` decides nothing: a shard's slab holds its own rows
+    of the children).  Decided from what the program can observe; the
+    learner reports it (obs_info)."""
     runs = pallas_wave_active(hist_mode, hist_dtype) or (
         pallas_interpret and hist_dtype == jnp.float32)
-    return bool(compact and hist_mode == "pallas_t" and runs
-                and psum_axis is None)
+    return bool(compact and hist_mode == "pallas_t" and runs)
 
 
 def make_wave_grow_fn(num_leaves: int, num_bins: int, meta: FeatureMeta,
@@ -229,10 +230,10 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
     pallas_transposed = hist_mode in ("pallas_t", "pallas_ct")
     pallas_fused = hist_mode == "pallas_ct"
     # the row slab (slab_hist below) serves the split pipeline, partition
-    # scan then pallas_t, in one program on one device: the fused ct
-    # kernel routes and histograms in one read, and a mesh shard's slab
-    # has no measurement yet.  `compact` is no knob (no config key
-    # reaches it): False lets a test grow the same tree without the slab
+    # scan then pallas_t, on one device and on every shard of a data
+    # mesh; the fused ct kernel routes and histograms in one read and
+    # has none.  `compact` is no knob (no config key reaches it): False
+    # lets a test grow the same tree without the slab
     compact = slab_active(compact, hist_mode, hist_dtype, psum_axis,
                           pallas_interpret)
 
@@ -390,7 +391,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 new_lid = route_rows(r, colv, lid)
             return new_lid, sparse_child_hists(new_lid, small_id, valid)
 
-        no_slab = jnp.zeros(2, jnp.int32)   # a wave that ran no slab
+        no_rows = jnp.asarray(0, jnp.int32)  # a wave that ran no slab
 
         @scope("wave_histogram")
         def pallas_hist(lid, cid):
@@ -433,8 +434,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             only partitions; 'pallas_ct' fuses BOTH halves into one
             kernel — a single read of Xt per wave.
 
-            Returns (new leaf ids, (W, Fc, B, 3) histograms, the slabs'
-            two counts: rows their launches visited and 1 if they ran).
+            Returns (new leaf ids, (W, Fc, B, 3) histograms, the rows the
+            slabs' launches visited: this shard's own, like the sums).
             """
             if use_pallas_hist and pallas_fused:
                 from .pallas_wave import wave_partition_hist_pallas_ct
@@ -445,7 +446,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.where(valid, small_id, -1), cols, psrc,
                         hist_bins, bundled=has_bundle,
                         logical_cols=packed_cols, hilo=hist_hilo,
-                        interpret=pallas_interpret) + (no_slab,)
+                        interpret=pallas_interpret) + (no_rows,)
             with scope("wave_partition"):
                 lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
                     else leaf_id.reshape(nch, c)
@@ -505,11 +506,11 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                     init = vary_like(init, xb, lb, wb3)
                 flat, lid2 = lax.scan(step, init, (xb, lb, wb3))
                 new_leaf_id = lid2.reshape(-1)[:n]
-            slab = no_slab
+            visited = no_rows
             if use_pallas_hist:
                 cid = jnp.where(valid, small_id, -1)
                 if slab_cap:
-                    hist, slab = slab_hist(new_leaf_id, cid)
+                    hist, visited = slab_hist(new_leaf_id, cid)
                 else:
                     hist = pallas_hist(new_leaf_id, cid)
             else:
@@ -517,19 +518,30 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 with scope("wave_histogram"):
                     hist = flat.reshape(Fc, hist_bins, W, 3).transpose(
                         2, 0, 1, 3)
-            return new_leaf_id, hist, slab
+            return new_leaf_id, hist, visited
 
         def slab_hist(leaf_id, cid):
             """Histograms of the children `cid` from slabs of their rows
-            -> (hist, [rows the launches visited, 1]).
+            -> (hist, rows the launches visited).
 
             One sort puts the children's rows first, in row order; a
             slab is the next `slab_cap` of them, gathered from X.  With
-            all weights 1 one slab holds them all (a smaller child by
-            count has at most half its parent's rows).  The count is
-            WEIGHTED and the slab holds ROWS, so under bagging, GOSS or
-            row_mult 0 rows they can be more than half: then a second
-            slab follows, and nothing is ever truncated.
+            all weights 1, on one device, one slab holds them all (a
+            smaller child by count has at most half its parent's rows).
+            The count is WEIGHTED and the slab holds ROWS, so under
+            bagging, GOSS or row_mult 0 rows they can be more than half:
+            then a second slab follows, and nothing is ever truncated.
+
+            Under a data mesh "at most half" is a GLOBAL argument, not a
+            local one: the children are the smaller ones by the SUMMED
+            histograms, and a shard gathers ITS OWN rows of them, which
+            may be most of the shard (rows sorted by a split column, a
+            skewed file split) or none.  So `n_active` and the loop's
+            trip count are per-shard values: the second slab takes what
+            the first cannot hold, and a shard that holds none runs the
+            loop zero times and hands zeros to the wave's psum.  Shards
+            may differ in trips only because the loop holds no
+            collective: the psum follows it (the wave's body).
 
             Exactness: a row outside the slabs matches no child, so it
             adds 0.0 to every sum; the rows keep their order, so each
@@ -579,10 +591,10 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
 
             with scope("wave_histogram"):
                 zero = jnp.zeros((W, Fc, hist_bins, 3), hist_dtype)
-            hist, visited = lax.fori_loop(
+            # the carry enters the loop as it leaves: shard-local sums
+            return lax.fori_loop(
                 0, (n_active + (slab_cap - 1)) // slab_cap, slab,
-                (zero, jnp.asarray(0, jnp.int32)))
-            return hist, jnp.stack([visited, jnp.asarray(1, jnp.int32)])
+                vary_like((zero, no_rows), leaf_id))
 
         @scope("wave_histogram")
         def rehist(leaf_id, ids, valid):
@@ -671,8 +683,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         # to the all-reduce]; `rows` is the rows every full pass visits.
         # Under a mesh the record is the mesh's: every shard's n rows
         # (the child counts already are global, they come from the summed
-        # histograms; no shard runs a slab), and the elements ONE shard
-        # hands over, the root's to start with
+        # histograms), the rows ALL shards' slab launches visited (one
+        # word more through the wave's all-reduce), and the elements ONE
+        # shard hands over, the root's to start with
         shards = 1 if psum_axis is None else lax.psum(1, psum_axis)
         counters = jnp.zeros(len(COUNTERS), jnp.int32).at[
             COUNTERS.index("rows")].set(n * shards).at[
@@ -764,14 +777,16 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 small_id = jnp.where(left_small, parent, newleaf)
                 large_id = jnp.where(left_small, newleaf, parent)
             if sparse_mode:
-                slab = no_slab
+                visited = no_rows
                 leaf_id, hist_small = sparse_wave_pass(
                     leaf_id, tbl, small_id, valid, col_w)
             else:
-                leaf_id, hist_small, slab = wave_pass(
+                leaf_id, hist_small, visited = wave_pass(
                     leaf_id, tbl, cols, psrc, small_id, valid)
             sent = []
             hist_small = maybe_psum(hist_small, sent)       # (W, F, B, 3)
+            if slab_cap:
+                visited = maybe_psum(visited, sent)
             if cache_hists:
                 with scope("wave_histogram"):
                     hist_large = hists[parent] - hist_small
@@ -926,7 +941,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.sum(jnp.where(commit, jnp.minimum(
                             info[:, LEFT_COUNT],
                             info[:, RIGHT_COUNT]).astype(jnp.int32), 0)),
-                        jnp.asarray(0, jnp.int32), slab[0], slab[1],
+                        jnp.asarray(0, jnp.int32), visited,
+                        jnp.asarray(int(bool(slab_cap)), jnp.int32),
                         jnp.asarray(sum(sent), jnp.int32)]),
                 )
             return (nn + kc, kc == 0, leaf_id, hists, bests, sums, tree)

@@ -227,6 +227,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
         caps = (default_row_capacities(local_rows)
                 if self.row_capacities else ())   # same gate, per-shard rows
         voting = bool(self._grow_kwargs(n_shards).get("voting_k", 0))
+        check_vma = True
         if self.growth == "wave" and not voting:
             # wave schedule under the data mesh: the per-wave histogram
             # block is psum'd ONCE (W splits per collective instead of one)
@@ -247,7 +248,16 @@ class DataParallelTreeLearner(SerialTreeLearner):
                 chunk=int(config.tpu_wave_chunk),
                 sparse_col_cap=self.sparse_col_cap, with_xt=needs_xt,
                 exact_order=self.wave_order == "exact",
-                lookup=self.wave_lookup, hist_hilo=self.hist_hilo)
+                lookup=self.wave_lookup, hist_hilo=self.hist_hilo,
+                pallas_interpret=self.pallas_interpret)
+            # the varying-axes check stays on wherever Mosaic compiles the
+            # kernels.  JAX's Pallas HLO interpreter (tests, off-TPU)
+            # fails it on a row slab's launch: it evaluates the block
+            # index maps with the scalar-prefetched tile count, a shard's
+            # own, beside its invariant loop index (`dynamic_slice
+            # requires varying manual axes to match`, pallas/core.py
+            # compute_start_indices_interpret)
+            check_vma = not (self.pallas_interpret and self.wave_compact)
             if needs_xt:
                 self._Xt = jax.jit(
                     jnp.transpose,
@@ -283,7 +293,8 @@ class DataParallelTreeLearner(SerialTreeLearner):
             in_specs=in_specs,
             out_specs=(jax.tree_util.tree_map(lambda _: P(),
                                               self._dummy_tree_spec()),
-                       P(DATA_AXIS)))
+                       P(DATA_AXIS)),
+            check_vma=check_vma)
         self._grow = jax.jit(sharded_grow)
         Log.info("%s over %d devices (%d padded rows)",
                  type(self).__name__, n_shards, pad)

@@ -263,6 +263,21 @@ def _tree_records():
             if r["kind"] == "count" and r["name"] == "tree"]
 
 
+def _lowered_by_each_update(bst, updates=3):
+    """The functions each of `updates` steps of `bst` lowered, by name."""
+    lowered = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _s, **kw: lowered.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration"
+        else None)
+    per_update = []
+    for _ in range(updates):
+        before = len(lowered)
+        bst.update()
+        per_update.append(lowered[before:])
+    return per_update
+
+
 def test_sharded_upload_gives_each_device_its_rows_and_no_others(
         dp4_dir, four_devices):
     from lightgbm_tpu.obs import timers
@@ -322,19 +337,10 @@ def test_shards_root_histograms_add_up_to_the_whole_tables(
 def test_mesh_booster_grows_the_serial_trees_once_lowered_and_counted(
         dp4_dir, four_devices):
     from lightgbm_tpu.obs import timers
-    lowered = []
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda event, _s, **kw: lowered.append(kw.get("fun_name"))
-        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration"
-        else None)
     timers.clear()
     timers._scopes.clear()
     dp, _ = _booster(dp4_dir, tree_learner="data")
-    per_update = []
-    for _ in range(3):
-        before = len(lowered)
-        dp.update()
-        per_update.append(lowered[before:])
+    per_update = _lowered_by_each_update(dp)
     lrn = dp._gbdt.learner
     # one lowering of the grow program a booster, and nothing at all is
     # lowered after the first update
@@ -387,3 +393,79 @@ def test_mesh_booster_grows_the_serial_trees_once_lowered_and_counted(
         assert rec["rows_visited"] == 4 * 8192
         for key in ("committed", "slots", "hist_rows"):
             assert rec[key] == one[key]
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in `jaxpr`, nested bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in _sub_jaxprs(eqn):
+            yield from _primitives(sub)
+
+
+def _loops_in_loops(jaxpr, depth=0):
+    """The bodies of every `while` that sits inside another `while`."""
+    for eqn in jaxpr.eqns:
+        inner = depth + (eqn.primitive.name == "while")
+        if inner == 2:
+            yield eqn.params["body_jaxpr"].jaxpr
+        for sub in _sub_jaxprs(eqn):
+            yield from _loops_in_loops(sub, min(inner, 1))
+
+
+def test_slab_loop_under_shard_map_holds_no_collective_and_lowers_once(
+        dp4_dir, four_devices, monkeypatch):
+    """The row slab on every shard of the mesh (the interpreted pallas_t
+    kernel).  A shard's slab loop runs as often as ITS rows of the wave's
+    children need, so shards differ in trips: legal only while the loop
+    holds no collective.  In the traced program the loop that launches
+    the kernel holds none; in the compiled one every all-reduce sits
+    under `hist_allreduce`, outside any inner loop; and the program is
+    still lowered once a booster."""
+    from lightgbm_tpu.obs import timers
+    timers.clear()
+    calls = []
+    ahead = timers.scoped_executable
+    monkeypatch.setattr(timers, "scoped_executable", lambda fn, cache, args: (
+        calls.append((fn, args)), ahead(fn, cache, args))[1])
+    dp, _ = _booster(dp4_dir, tree_learner="data",
+                     tpu_histogram_mode="pallas_t",
+                     tpu_pallas_interpret=True)
+    lrn = dp._gbdt.learner
+    assert type(lrn) is DataParallelTreeLearner and lrn.wave_compact
+    per_update = _lowered_by_each_update(dp)
+    assert per_update[0].count("jit(grow)") == 1 and len(lrn._compiled) == 1
+    assert per_update[1] == [] == per_update[2]
+    dp._gbdt._materialize()
+    for rec in _tree_records():
+        assert rec["compacted"] == rec["waves"] == 3
+        assert rec["rows_visited"] == rec["rows"] + rec["kernel_rows"]
+        assert rec["kernel_rows"] < 3 * rec["rows"]
+
+    grow, args = calls[0]
+    slab_loops = [body for body in _loops_in_loops(grow.trace(*args).jaxpr)
+                  if "pallas_call" in set(_primitives(body))]
+    assert len(slab_loops) == 1
+    inside = set(_primitives(slab_loops[0]))
+    assert "gather" in inside                 # the slab's rows, then the kernel
+    assert not [p for p in inside if "psum" in p or p.startswith("all_")
+                or p in ("pmax", "pmin", "ppermute")], sorted(inside)
+
+    text, = [c.as_text() for c in lrn._compiled.values()]
+    reduces = [line for line in text.splitlines()
+               if " all-reduce(" in line or " all-reduce-start(" in line]
+    assert reduces
+    for line in reduces:
+        op_name = line.split('op_name="')[1].split('"')[0]
+        assert "hist_allreduce" in op_name, op_name
+        assert "while/body/while" not in op_name, op_name
+    # the slab's own loop is there, as a loop, inside the loop of waves
+    assert "while/body/while/body/wave_compact" in text

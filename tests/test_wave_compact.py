@@ -15,14 +15,23 @@ over half the rows a second slab follows the first.
 Runs the real engine end-to-end on the CPU through interpret-mode
 kernels (make_wave_core's pallas_interpret static); `compact=False` is
 the plumbing that grows the same tree without the slab.
+
+Under a data mesh (`shards=4`: the program under shard_map over four of
+the host's devices, as parallel/mesh.py builds it) the children are the
+smaller ones by the SUMMED histograms and every shard gathers its own
+rows of them: a shard may hold most of its rows there, or none.
 """
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, PartitionSpec as P
 
 from lightgbm_tpu.io.dataset import TrainingData
 from lightgbm_tpu.obs.timers import COUNTERS
+from lightgbm_tpu.ops.grow import TreeArrays
 from lightgbm_tpu.ops.learner import SerialTreeLearner, build_split_params
 from lightgbm_tpu.ops.pallas_wave import slab_plan, wave_histogram_pallas_t
 from lightgbm_tpu.ops.split_finder import FeatureMeta
@@ -30,6 +39,7 @@ from lightgbm_tpu.ops.wave import make_wave_core, make_wave_grow_fn
 from lightgbm_tpu.utils.config import Config
 
 N, F = 6000, 8
+NM, SHARDS = 8192, 4        # 2,048 rows a shard: its slab is 1,024, one tile
 STRUCTURE = ("num_leaves", "split_feature", "threshold_bin",
              "default_bin_for_zero", "default_bin", "is_cat", "left_child",
              "right_child", "leaf_parent", "leaf_count", "leaf_depth",
@@ -54,7 +64,10 @@ def _setup(num_leaves, n=N, max_bin=63):
 
 
 def _run(compact, num_leaves, wave_width, row_mult=None, exact_order=False,
-         n=N, hist_mode="pallas_t", packed=False, grad=None):
+         n=N, hist_mode="pallas_t", packed=False, grad=None, shards=0,
+         order=None):
+    """`shards`: under shard_map over that many host devices, rows split
+    evenly in their order; `order` permutes the rows first."""
     cfg, td, meta, grad0, hess = _setup(num_leaves, n=n,
                                         max_bin=15 if packed else 63)
     grad = grad0 if grad is None else jnp.asarray(grad)
@@ -69,10 +82,23 @@ def _run(compact, num_leaves, wave_width, row_mult=None, exact_order=False,
                              wave_width=wave_width, hist_mode=hist_mode,
                              with_xt=True, exact_order=exact_order,
                              packed_cols=td.binned.shape[1] if packed else 0,
-                             compact=compact, pallas_interpret=True)
+                             compact=compact, pallas_interpret=True,
+                             psum_axis="data" if shards else None)
     rm = (jnp.ones(n, jnp.float32) if row_mult is None
           else jnp.asarray(row_mult))
     fm = jnp.ones(td.num_features, dtype=bool)
+    if order is not None:
+        X, grad, hess, rm = (a[jnp.asarray(order)]
+                             for a in (X, grad, hess, rm))
+    if shards:
+        # the interpreter fails the varying-axes check on a slab launch
+        # (parallel/mesh.py): off here as there
+        grow = jax.shard_map(
+            grow, mesh=Mesh(np.array(jax.devices()[:shards]), ("data",)),
+            in_specs=(P("data", None), P("data"), P("data"), P("data"),
+                      P(), P(None, "data")),
+            out_specs=(TreeArrays(*([P()] * len(TreeArrays._fields))),
+                       P("data")), check_vma=False)
     tree, leaf_id = jax.jit(grow)(X, grad, hess, rm, fm, jnp.transpose(X))
     return tree, leaf_id
 
@@ -242,19 +268,181 @@ def test_learner_resolves_the_slab_from_what_it_observes(keys, on):
     assert not hasattr(learner.config, "tpu_wave_compact")   # no such key
 
 
-def test_no_slab_under_a_mesh_axis_or_on_the_tpu_without_pallas_t(
+def test_slab_under_a_mesh_axis_as_on_one_device_and_only_with_pallas_t(
         monkeypatch):
-    """`slab_active` under `psum_axis` (a mesh shard's slab has no
-    measurement yet) and on a pretend TPU: pallas_t turns it on there
-    with no interpret flag, pallas_ct and f64 do not."""
+    """`slab_active` does not ask for `psum_axis`: a mesh shard runs the
+    slab wherever one device would.  On a pretend TPU pallas_t turns it
+    on with no interpret flag, pallas_ct and f64 do not."""
     from lightgbm_tpu.ops.wave import slab_active
     assert slab_active(True, "pallas_t", jnp.float32, None, True)
-    assert not slab_active(True, "pallas_t", jnp.float32, "data", True)
-    assert not slab_active(False, "pallas_t", jnp.float32, None, True)
-    assert not slab_active(True, "pallas_t", jnp.float32, None, False)
+    assert slab_active(True, "pallas_t", jnp.float32, "data", True)
+    assert not slab_active(False, "pallas_t", jnp.float32, "data", True)
+    assert not slab_active(True, "pallas_t", jnp.float32, "data", False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     make_wave_core.cache_clear()
     assert slab_active(True, "pallas_t", jnp.float32, None)
-    assert not slab_active(True, "pallas_ct", jnp.float32, None)
-    assert not slab_active(True, "pallas_t", jnp.float64, None)
-    assert not slab_active(True, "pallas_t", jnp.float32, "data")
+    assert not slab_active(True, "pallas_ct", jnp.float32, "data")
+    assert not slab_active(True, "pallas_t", jnp.float64, "data")
+    assert slab_active(True, "pallas_t", jnp.float32, "data")
+
+
+# ------------------------------------------------- under a data mesh
+
+@functools.lru_cache(maxsize=None)
+def _mesh_run(compact, wave_width):
+    """63 leaves over the four shards, rows in their generated order."""
+    return _run(compact, 63, wave_width, n=NM, shards=SHARDS)
+
+
+def _slab_rows(active, cap=NM // SHARDS // 2):
+    """Rows the slab launches of one wave visit on a shard that holds
+    `active` rows of the wave's children: one tile (= cap here) a slab,
+    ceil(active / cap) slabs."""
+    return -(-active // cap) * cap
+
+
+@pytest.mark.parametrize("wave_width", [1, 4, 32])
+def test_mesh_slab_matches_the_mesh_full_pass(wave_width):
+    """tree_learner=data's program with the slab against the same mesh
+    without it: every shard adds its own rows of the children in their
+    order, the psum adds the shards' blocks in the same order either
+    way: same structure, same partition, floats to 1e-5."""
+    assert slab_plan(NM // SHARDS, F, 63, wave_width) == (1024, 1024)
+    t_full, l_full = _mesh_run(False, wave_width)
+    t_slab, l_slab = _mesh_run(True, wave_width)
+    assert int(t_full.num_leaves) == 63
+    _same(t_full, t_slab, STRUCTURE)
+    _same(t_full, t_slab, FLOATS, close=True)
+    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_slab))
+    c, off = _counters(t_slab), _counters(t_full)
+    assert c["compacted"] == c["waves"] > 0 == off["compacted"]
+    assert c["rows"] == NM == off["rows"]
+    # a shard's slab launch stops at its one tile: under a full pass's
+    assert 0 < c["kernel_rows"] < c["waves"] * NM and not off["kernel_rows"]
+    # one word more a wave through the all-reduce: the rows visited
+    assert c["allreduce_words"] == off["allreduce_words"] + c["waves"]
+
+
+def test_mesh_slab_grows_the_serial_slabs_tree():
+    """Four shards' slabs against one device's on the same rows: the
+    sums pair differently (four partial blocks and a psum), so structure
+    and partition are equal and the floats close."""
+    t_one, l_one = _run(True, 63, 4, n=NM)
+    t_dp, l_dp = _mesh_run(True, 4)
+    _same(t_one, t_dp, STRUCTURE)
+    _same(t_one, t_dp, FLOATS, close=True)
+    np.testing.assert_array_equal(np.asarray(l_one), np.asarray(l_dp))
+    one, dp = _counters(t_one), _counters(t_dp)
+    assert dp["hist_rows"] == one["hist_rows"] and dp["waves"] == one["waves"]
+    assert dp["compacted"] == dp["waves"]        # 1 a wave, not 1 a shard
+
+
+def test_sorted_rows_put_the_smaller_child_on_one_shard():
+    """"At most half" is global: rows sorted by the root's split column
+    leave one shard with all of its rows in the root's smaller child (a
+    second slab follows the first there) and another with none (its loop
+    runs zero times and hands zeros to the psum).  Nothing is truncated:
+    the tree is the unsorted rows' tree, leaf for leaf."""
+    stump, _ = _run(True, 2, 4, n=NM, shards=SHARDS)
+    col = np.asarray(_setup(2, n=NM)[1].binned)[:, int(stump.split_feature[0])]
+    order = np.argsort(col, kind="stable")
+    t2, l2 = _run(True, 2, 4, n=NM, shards=SHARDS, order=order)
+    _same(stump, t2, STRUCTURE)
+    small = int(np.argmin(np.bincount(np.asarray(l2), minlength=2)))
+    active = (np.asarray(l2) == small).reshape(SHARDS, -1).sum(axis=1)
+    assert active.max() == NM // SHARDS and active.min() == 0
+    assert sorted(_slab_rows(int(a)) for a in active)[::3] == [0, 2048]
+    c = _counters(t2)
+    assert c["waves"] == 1 == c["compacted"]
+    assert c["kernel_rows"] == sum(_slab_rows(int(a)) for a in active)
+    # the whole tree from sorted rows: the generated order's tree, and
+    # every row in the leaf it had there
+    t_sorted, l_sorted = _run(True, 63, 4, n=NM, shards=SHARDS, order=order)
+    t_slab, l_slab = _mesh_run(True, 4)
+    _same(t_slab, t_sorted, STRUCTURE)
+    _same(t_slab, t_sorted, FLOATS, close=True)
+    np.testing.assert_array_equal(np.asarray(l_slab)[order],
+                                  np.asarray(l_sorted))
+
+
+def test_bagging_weights_under_the_mesh():
+    """The weighted-count case on every shard: 70% of the rows weigh
+    0.01, the smaller child by weight holds most rows of each shard, so
+    the shards' loops run two slabs; same trees as without the slab."""
+    col = np.asarray(_setup(63, n=NM)[1].binned)[:, 1]
+    light = col <= np.quantile(col, 0.7)
+    y = ~light ^ (np.random.default_rng(7).random(NM) < 0.1)
+    kw = dict(row_mult=np.where(light, 0.01, 1.0).astype(np.float32),
+              grad=(0.5 - y).astype(np.float32), n=NM, shards=SHARDS)
+    t_full, l_full = _run(False, 63, 4, **kw)
+    t_slab, l_slab = _run(True, 63, 4, **kw)
+    _same(t_full, t_slab, STRUCTURE)
+    _same(t_full, t_slab, FLOATS, close=True)
+    np.testing.assert_array_equal(np.asarray(l_full), np.asarray(l_slab))
+    c = _counters(t_slab)
+    assert c["compacted"] == c["waves"]
+    # some wave visited two slabs on some shard
+    assert c["kernel_rows"] > c["waves"] * NM // 2
+    assert c["kernel_rows"] % 1024 == 0
+
+
+def test_mesh_counters_against_a_hand_count_on_a_three_wave_tree(
+        monkeypatch):
+    """`lgb.train(tree_learner=data)` with the interpreted kernel, 8
+    leaves at W=4: the root's split, then two, then four.  The record is
+    the mesh's: `kernel_rows` the sum over shards of what their slab
+    launches visited (from each shard's own rows of each wave's smaller
+    children), `compacted` 1 a wave, `rows_visited = rows +
+    kernel_rows`, and one word a wave beside the histogram block in
+    `allreduce_bytes`."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs import timers
+    from lightgbm_tpu.parallel import mesh as mesh_mod
+    make_mesh = mesh_mod.make_data_mesh
+    monkeypatch.setattr(mesh_mod, "make_data_mesh",
+                        lambda devices=None: make_mesh(
+                            jax.devices()[:SHARDS]))
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(NM, F))
+    y = (X[:, 1] + np.cos(X[:, 4] * 2) + 0.4 * rng.normal(size=NM) > 0.5)
+    params = {"objective": "binary", "num_leaves": 8, "max_bin": 63,
+              "min_data_in_leaf": 3, "verbose": -1, "tree_learner": "data",
+              "tpu_growth": "wave", "tpu_wave_width": 4,
+              "tpu_histogram_mode": "pallas_t",
+              "tpu_pallas_interpret": True}
+    timers.clear()
+    bst = lgb.train(params, lgb.Dataset(X, label=y.astype(np.float64),
+                                        params=params), num_boost_round=1)
+    lrn = bst._gbdt.learner
+    assert type(lrn).__name__ == "DataParallelTreeLearner"
+    assert lrn.wave_compact and lrn.obs_info()["wave_compact"]
+    bst._gbdt._materialize()
+    rec, = [r["fields"] for r in timers.snapshot()
+            if r["kind"] == "count" and r["name"] == "tree"]
+    tree = bst._gbdt.models[0]
+    assert rec["waves"] == 3 == rec["compacted"] and rec["committed"] == 7
+    assert rec["shards"] == SHARDS and rec["rows"] == NM
+    # every row's leaf, and every node's leaves, from the grown tree
+    leaf = bst.predict(X, pred_leaf=True).reshape(-1).astype(int)
+
+    def leaves(ch):
+        return ([~ch] if ch < 0 else leaves(tree.left_child[ch])
+                + leaves(tree.right_child[ch]))
+
+    def count(ch):
+        return int(tree.leaf_count[~ch] if ch < 0
+                   else tree.internal_count[ch])
+
+    kernel_rows = 0
+    for wave in ([0], [1, 2], [3, 4, 5, 6]):     # nodes in commit order
+        kids = []
+        for node in wave:
+            lc, rc = tree.left_child[node], tree.right_child[node]
+            kids += leaves(lc if count(lc) < count(rc) else rc)
+        active = np.isin(leaf, kids).reshape(SHARDS, -1).sum(axis=1)
+        kernel_rows += sum(_slab_rows(int(a)) for a in active)
+    assert rec["kernel_rows"] == kernel_rows
+    assert rec["rows_visited"] == rec["rows"] + kernel_rows
+    assert rec["hist_rows"] <= kernel_rows < 3 * NM
+    fb3 = F * lrn.num_bins * 3
+    assert rec["allreduce_bytes"] == 4 * (3 + fb3 + 3 * (4 * fb3 + 1))
