@@ -10,9 +10,9 @@ the static twin of a runtime audit:
   events     emit sites vs the obs/events.py field tables
              (runtime twin: validate_event, which sees only runs)
   config     Config reads vs utils/config.py vs docs/Parameters.md
-  vmem       the Pallas tile planners evaluated over the autotuner's
-             shape grid against the VMEM budgets
-             (runtime twin: the v5e probes behind docs/Autotuning.md)
+  vmem       the Pallas tile planners evaluated over the benchmark
+             shapes and the widths of the ladder against the VMEM
+             budgets
 
 Entry point: ``python -m lightgbm_tpu lint`` (analysis/cli.py).
 """

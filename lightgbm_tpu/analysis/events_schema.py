@@ -15,9 +15,7 @@ cross-checked against the field tables in ``obs/events.py`` —
 
 Emit sites recognized: ``<obj>.event("name", k=v, ...)`` anywhere in the
 package (the Observer API, plus local ``emit()`` shims with the same
-(ev, **fields) shape — obs/merge.py), and the autotuner's deferred queue
-``events.append(("name", {...}))`` whose tuples are re-emitted through
-``obs.event`` later (ops/learner.py _drain).
+(ev, **fields) shape — obs/merge.py).
 
 The tables are IMPORTED from obs/events.py, not re-declared here — the
 analyzer can't drift from the schema it checks.
@@ -117,31 +115,6 @@ def _emit_call(node: ast.Call) -> Optional[Tuple[str, List[str], bool,
     return ev_name, explicit, has_splat, schema_kw
 
 
-def _queued_tuple(node: ast.Call) -> Optional[Tuple[str, List[str],
-                                                    bool]]:
-    """('name', fields, has_dynamic) for ``<list>.append(("name", {...}))``
-    — the autotuner's deferred-emission idiom."""
-    fn = node.func
-    if not (isinstance(fn, ast.Attribute) and fn.attr == "append"
-            and len(node.args) == 1):
-        return None
-    arg = node.args[0]
-    if not (isinstance(arg, ast.Tuple) and len(arg.elts) == 2):
-        return None
-    ev_name = str_const(arg.elts[0])
-    payload = arg.elts[1]
-    if ev_name is None or not isinstance(payload, ast.Dict):
-        return None
-    explicit, dynamic = [], False
-    for k in payload.keys:
-        s = str_const(k) if k is not None else None
-        if k is None or s is None:
-            dynamic = True          # **merge or computed key
-        else:
-            explicit.append(s)
-    return ev_name, explicit, dynamic
-
-
 def run(modules: List[SourceModule], repo_root: str) -> List[Finding]:
     findings: List[Finding] = []
     for mod in modules:
@@ -153,15 +126,4 @@ def run(modules: List[SourceModule], repo_root: str) -> List[Finding]:
                 ev_name, explicit, has_splat, schema_kw = info
                 _check_fields(mod, node.lineno, ev_name, explicit,
                               has_splat, schema_kw, findings)
-                continue
-            q = _queued_tuple(node)
-            if q is not None:
-                ev_name, explicit, dynamic = q
-                # a queued 2-tuple only counts as an emit site when the
-                # name IS a declared event — any (str, dict) append
-                # would otherwise false-positive as unknown-type
-                if _schema().declared_fields(ev_name) is None:
-                    continue
-                _check_fields(mod, node.lineno, ev_name, explicit,
-                              dynamic, None, findings)
     return findings

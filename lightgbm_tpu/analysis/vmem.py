@@ -4,12 +4,12 @@ CPU-only runner, against the budgets the kernels assume on device.
 This is the pass that makes the PR-11 band fix regression-proof without
 a TPU: instead of pattern-matching kernel source, it IMPORTS
 ``ops/pallas_wave._tile_plan`` / ``tile_plan_vmem_report`` and
-``ops/pallas_hist.tile_shape`` and sweeps them over the autotuner's
-shape-bucket grid (ops/autotune.py enumerates cells over exactly these
-axes).  Three invariants:
+``ops/pallas_hist.tile_shape`` and sweeps them over the benchmark
+shapes and the widths of the ladder (ops/plan.py resolve_wave_width).
+Three invariants:
 
 * ``vmem-budget``         — a wave cell whose hist block passes the
-  64 MB resident gate (``autotune.WAVE_VMEM_GATE``) must plan a TOTAL
+  64 MB resident gate (``plan.WAVE_VMEM_GATE``) must plan a TOTAL
   live set (resident + transients) that fits physical VMEM.  In the
   chunked-RMW regime the planner deliberately runs resident blocks up
   to the gate with ~60 MB of transients on top — legal on v5e's 128 MB
@@ -29,7 +29,8 @@ an inline suppression there covers a deliberately-over-budget regime.
 Grid: ncols from the bucketization tests/benches (epsilon 2000, bosch
 968, higgs 28, airline 8, synthetic 40/136/700), bin_pad from
 ops/wave._bin_pad's two products (64, 128) plus 256 for deep-bin runs,
-wave widths from autotune's candidate ladder.  ~200 cells, < 1 s on CPU.
+the widths of the ladder and their neighbours.  ~200 cells, < 1 s on
+CPU.
 """
 from __future__ import annotations
 
@@ -43,7 +44,7 @@ PASS_NAME = "vmem"
 RULES = {
     "vmem-budget":
         "wave tile plan's total live set exceeds physical VMEM for a "
-        "cell the autotuner would admit",
+        "cell the VMEM gate admits",
     "vmem-serialized-rmw":
         "tile planner re-creates the serialized chunked-RMW pathology "
         "(PR-11 accumulator-aware clamp regressed)",
@@ -58,8 +59,8 @@ BIN_PAD_GRID = (64, 128, 256)
 WIDTH_GRID = (1, 8, 16, 32, 64)
 NUM_BINS_GRID = (16, 63, 64, 255, 256, 1024, 4096)
 
-# v5e VMEM arena per core (the autotuner's target part; the measured
-# ceiling every budget constant in ops/pallas_wave.py is derived from)
+# v5e VMEM arena per core (the ceiling every budget constant in
+# ops/pallas_wave.py is derived from)
 TOTAL_VMEM_BYTES = 128 << 20
 
 
@@ -78,7 +79,7 @@ def _def_line(modules: List[SourceModule], path_suffix: str,
 
 def _check_wave(modules: List[SourceModule],
                 findings: List[Finding]) -> None:
-    from ..ops.autotune import WAVE_VMEM_GATE
+    from ..ops.plan import WAVE_VMEM_GATE
     from ..ops.pallas_wave import tile_plan_vmem_report
     from ..ops.wave import hist_block_bytes
 
@@ -88,7 +89,7 @@ def _check_wave(modules: List[SourceModule],
         for bp in BIN_PAD_GRID:
             for w in WIDTH_GRID:
                 if hist_block_bytes(fc, bp, w) > WAVE_VMEM_GATE:
-                    continue        # the autotuner gates this cell out
+                    continue        # the VMEM gate keeps this cell out
                 rep = tile_plan_vmem_report(N_ROWS, fc, bp, w)
                 live = rep["live_new"]     # resident + transients
                 if live > TOTAL_VMEM_BYTES:

@@ -133,14 +133,12 @@ class GBDT:
         if se not in ("auto", "gather", "pallas"):
             Log.fatal("Unknown tpu_score_update %s (expected auto/"
                       "gather/pallas)", config.tpu_score_update)
-        # Round-5 promotion (pre-registered rule, BENCH_NOTES.md "Armed
-        # decks"; measured tools/BENCH_SUITE.md 15:50 block): auto ->
-        # the pallas compare-select kernel — 1.45 vs 1.30 it/s at the
-        # 10.5M flagship with EXACTLY equal AUC (0.89295, the bit-equal
-        # claim held on chip).  The dispatch itself (ops/predict.py)
-        # still gates on TPU + num_leaves<=512 + f32 score and falls
-        # back to the XLA gather otherwise, so 'auto' is safe to
-        # resolve unconditionally here.
+        # auto -> the pallas compare-select kernel, bit-equal to the
+        # gather (ledger, PR 25-29: it runs in every cell; against the
+        # gather it is not measured by the driver).  The dispatch itself
+        # (ops/predict.py) still gates on TPU + num_leaves<=512 + f32
+        # score and falls back to the XLA gather otherwise, so 'auto' is
+        # safe to resolve unconditionally here.
         self._score_engine = "pallas" if se == "auto" else se
 
     def _reset_observer(self, config: Config) -> None:
@@ -535,12 +533,11 @@ class GBDT:
         cached — the verdict depends only on booster/learner/objective
         shape, all of which invalidate ``_fused_state`` when rebuilt.
 
-        auto: fuse when eligible AND the win is expected — the TPU
-        Pallas wave path is live (dispatch latency is what the fused
-        program removes) or the autotuner measured the fused cell as
-        this shape bucket's winner.  on: force when eligible; an
-        explicit opt-in is never dropped silently, so ineligibility
-        warns.  off: never."""
+        auto: fuse when eligible AND the plan wishes it (ops/plan.py
+        Plan.fused_wanted: the TPU Pallas wave path is live, so
+        dispatch latency is what the fused program removes).  on: force
+        when eligible; an explicit opt-in is never dropped silently, so
+        ineligibility warns.  off: never."""
         if self._fused_state is not None:
             return self._fused_state[0]
         mode = str(getattr(self.config, "tpu_fused_iter", "auto")
@@ -558,14 +555,7 @@ class GBDT:
                                 "is unavailable (%s); using the staged "
                                 "chain", why)
             else:
-                want = mode == "on"
-                if mode == "auto":
-                    from ..ops.wave import pallas_wave_active
-                    lrn = self.learner
-                    want = (pallas_wave_active(
-                        getattr(lrn, "hist_mode", ""), lrn.dtype)
-                        or bool(getattr(lrn, "fused_autotune", False)))
-                if want:
+                if mode == "on" or self.learner.plan.fused_wanted:
                     fused = _fi.FusedIteration.build(
                         self.learner, self.objective,
                         self.num_data, self.score_dtype)

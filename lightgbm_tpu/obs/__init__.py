@@ -43,7 +43,7 @@ GPU Accelerated Learning" ground their claims in, built into the loop):
   every jitted entry achieved-vs-peak utilization, arithmetic
   intensity, a compute/memory/collective/host-orchestration bound and
   headroom seconds; emits the per-iteration ``utilization`` rollup
-  (``obs_utilization_every``, schema 13) and stamps autotune probes;
+  (``obs_utilization_every``, schema 13);
 * ``live``    — the in-run live telemetry plane (``obs_http_port`` /
   ``obs_http_addr``): a stdlib ThreadingHTTPServer daemon serving
   ``/metrics`` (Prometheus), ``/healthz`` (200/503), ``/statusz``

@@ -47,13 +47,10 @@ serve_slo      window_s, routes (schema 7; obs/serve.py — periodic
 serve_summary  batches, rows, shed_total (schema 7; serve/scheduler.py —
                ServingPredictor lifetime totals emitted on close(), the
                run_end of a serving session)
-autotune_probe cell, s_per_wave (schema 8; ops/autotune.py — one
-               microbenched candidate kernel cell with its measured
-               seconds per wave)
-autotune_decision mode, source, cell (schema 8; ops/autotune.py — the
-               kernel-selection decision for one learner construction:
-               chosen cell vs the heuristic prior, every probed cell's
-               s/wave, winner margin, probe overhead, cache hit/path)
+autotune_probe cell, s_per_wave (schema 8; the measured kernel tuner
+               that was deleted: nothing emits it, an older run's
+               timeline still validates)
+autotune_decision mode, source, cell (schema 8; as autotune_probe)
 wave_band_escape width_from, width_to (schema 8; ops/learner.py — the
                auto wave width escaped the measured pathological
                hist-block band; previously silent, BENCH_NOTES.md)
@@ -185,10 +182,9 @@ _REQUIRED = {
     "serve_request": ("route", "rows", "bucket", "spans"),
     "serve_slo": ("window_s", "routes"),
     "serve_summary": ("batches", "rows", "shed_total"),
-    # schema 8 (ops/autotune.py + ops/learner.py): measured kernel
-    # selection — per-cell probe timings, the per-learner decision
-    # (with prior, margin and cache provenance), and the previously
-    # silent pathology-band width escape
+    # schema 8: the deleted kernel tuner's probe timings and decision,
+    # and the deleted pathology-band width escape — emitted by nothing,
+    # accepted from an older run's timeline
     "autotune_probe": ("cell", "s_per_wave"),
     "autotune_decision": ("mode", "source", "cell"),
     "wave_band_escape": ("width_from", "width_to"),
@@ -285,9 +281,7 @@ _OPTIONAL = {
                   "burn_long", "targets", "verdicts"),
     "serve_summary": ("pad_rows", "max_queue_depth", "requests", "shed",
                       "executables", "slo", "drift"),
-    # schema 13: every probed cell carries its analytic roofline stamp
-    # (flop/hbm utilization at the measured s/wave, dominant bound) so
-    # `obs explain` can say why the winner won — obs/roofline.py
+    # the deleted tuner's optional fields (see the required table)
     "autotune_probe": ("bucket", "waves", "roofline"),
     "autotune_decision": ("bucket", "device_kind", "prior", "cells",
                           "margin", "overhead_s", "cache_hit",

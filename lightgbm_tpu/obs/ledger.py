@@ -11,15 +11,15 @@ frames as the hard part of GBDT perf work applied *across* runs:
   reduced to one run record: the ``run_header`` context + provenance
   (git rev / dirty / host / argv, schema 10), the headline metrics
   ``bench_compare`` gates (iters/sec, compile_s, recompiles, serve
-  QPS/p99/shed, autotune overhead, construct_s, final eval), and the
+  QPS/p99/shed, construct_s, final eval), and the
   run outcome.  Records are keyed by (suite, shape bucket, device
   kind) — the comparability cell — plus schema + git rev for
   attribution.
 * **Store** — a ledger directory holds an append-only ``index.jsonl``
   (one line per run; a crash mid-append costs at most the trailing
   partial line, which readers skip) and a full per-run record under
-  ``runs/`` written with the same tmp + ``os.replace`` idiom as
-  ``autotune_cache.json``.  Readers rebuild index-lost runs from
+  ``runs/`` written tmp + ``os.replace``.  Readers rebuild
+  index-lost runs from
   ``runs/`` — a corrupted index line never loses history.
 * **Rolling baselines** — per (cell, metric): median/MAD over the last
   N clean comparable runs with a noise floor, exposed to
@@ -63,7 +63,6 @@ METRIC_DIRECTIONS = {
     "serve_qps": +1,
     "serve_p99_s": -1,
     "serve_shed_rate": -1,
-    "autotune_overhead_s": -1,
     "host_orchestration_s": -1,
     "construct_s": -1,
     "vs_baseline": +1,
@@ -172,10 +171,6 @@ def metrics_from_events(events):
         out["serve_p99_s"] = float(serve[-1]["p99_s"])
         if serve[-1].get("shed_rate") is not None:
             out["serve_shed_rate"] = float(serve[-1]["shed_rate"])
-    decs = [e for e in events if e.get("ev") == "autotune_decision"]
-    if decs:
-        out["autotune_overhead_s"] = sum(
-            float(e.get("overhead_s", 0.0)) for e in decs)
     cons = [e for e in events if e.get("ev") == "dataset_construct"]
     if cons:
         out["construct_s"] = sum(
@@ -386,8 +381,8 @@ class Ledger:
             return False
         rec = dict(rec, ingested_t=time.time())
         os.makedirs(self.runs_dir, exist_ok=True)
-        # full record first (atomic tmp+replace, the autotune-cache
-        # idiom), THEN the index line — a crash between the two leaves a
+        # full record first (atomic tmp+replace), THEN the index
+        # line — a crash between the two leaves a
         # recoverable runs/ file, never a dangling index entry
         run_path = os.path.join(self.runs_dir, key + ".json")
         tmp = run_path + ".tmp.%d" % os.getpid()

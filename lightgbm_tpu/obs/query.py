@@ -302,17 +302,6 @@ def render_summary(events, out=None):
     if "health" in m:
         w("health: " + "  ".join("%s=%d" % kv
                                  for kv in sorted(m["health"].items())))
-    decs = [e for e in events if e.get("ev") == "autotune_decision"]
-    if decs:
-        e = decs[-1]
-        c = e.get("cell") or {}
-        w("autotune: %s/%s  cell %s W=%s %s%s  (obs explain for probe "
-          "timings)"
-          % (e.get("mode", "?"), e.get("source", "?"),
-             c.get("hist_mode", "?"), c.get("wave_width", "?"),
-             "hilo" if c.get("hist_hilo", True) else "bf16",
-             # timelines from before the row slab carry the old knob
-             " compact" if c.get("compact") else ""))
     rr = m.get("rank_report")
     if rr:
         from .merge import render_report
@@ -507,59 +496,12 @@ def render_explain(events, out=None, topk=10):
                   % (metric, sorted(pts)[-1][1], vds,
                      sorted(vpts)[-1][1], gap))
 
-    # -------------------------------------------------- autotune decisions
-    def _cell(c):
-        return "%s W=%s %s%s" % (
-            c.get("hist_mode", "?"), c.get("wave_width", "?"),
-            "hilo" if c.get("hist_hilo", True) else "bf16",
-            " compact" if c.get("compact") else "")
-
-    decisions = [e for e in events if e.get("ev") == "autotune_decision"]
-    if decisions:
-        wrote = True
-        w()
-        w("autotune decisions (schema v8, ops/autotune.py):")
-        for e in decisions:
-            chosen, prior = e.get("cell") or {}, e.get("prior") or {}
-            line = ("  [%s/%s] bucket %s: %s"
-                    % (e.get("mode", "?"), e.get("source", "?"),
-                       e.get("bucket", "?"), _cell(chosen)))
-            if chosen != prior and prior:
-                line += "  (prior: %s)" % _cell(prior)
-            if e.get("cache_hit"):
-                line += "  [cache hit, zero probe waves]"
-            w(line)
-            cells = e.get("cells") or ()
-            if cells:
-                from .roofline import describe_roofline_position
-                best = min((c.get("s_per_wave") for c in cells
-                            if c.get("s_per_wave") is not None),
-                           default=None)
-                for c in cells:
-                    s = c.get("s_per_wave")
-                    tag = " <- winner" if (s is not None and s == best) \
-                        else ""
-                    # schema 13: the probe's roofline stamp says WHY —
-                    # e.g. "pallas_ct at 71% HBM vs pallas_t at 34%"
-                    pos = describe_roofline_position(c.get("roofline"))
-                    if pos:
-                        tag = "  [at %s]%s" % (pos, tag)
-                    w("    %-34s %10.6f s/wave%s"
-                      % (_cell(c.get("cell") or {}),
-                         s if s is not None else float("nan"), tag))
-                if e.get("margin"):
-                    w("    winner margin: %.1f%% faster than runner-up"
-                      % (100.0 * float(e["margin"])))
-                if e.get("overhead_s"):
-                    w("    probe overhead: %.4f s (persisted to %s)"
-                      % (float(e["overhead_s"]),
-                         e.get("cache_path", "?")))
     escapes = [e for e in events if e.get("ev") == "wave_band_escape"]
     if escapes:
         wrote = True
         w()
-        w("wave band escapes (the measured %s-%s MB hist-block pathology"
-          " band, BENCH_NOTES.md):"
+        w("wave band escapes (an older run's %s-%s MB hist-block"
+          " band):"
           % (escapes[0].get("band_lo_mb", "?"),
              escapes[0].get("band_hi_mb", "?")))
         for e in escapes:

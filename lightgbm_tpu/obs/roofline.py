@@ -19,7 +19,7 @@ GPU-GBDT literature (arxiv 1706.08359 frames histogram building as a
 memory-bandwidth roofline problem) and the accelerator-design paper
 (arxiv 2011.02022, per-stage utilization) both assume exists.
 
-Three consumers:
+Two consumers:
 
 * ``python -m lightgbm_tpu obs roofline RUN.jsonl [--check]``
   (obs/query.py) renders the headroom-ranked table; ``--check`` fails
@@ -28,10 +28,7 @@ Three consumers:
 * ``RunObserver.iter_end`` emits a per-iteration ``utilization``
   rollup event (schema 13, ``obs_utilization_every``) whose
   ``flop_util`` / ``hbm_util`` feed the cross-run ledger and the
-  ``bench_compare`` gate exactly like it/s;
-* ``ops/autotune.py`` stamps every probed cell with its roofline
-  position (``cell_roofline``) so ``obs explain`` can say *why* a
-  winner won ("pallas_ct at 71% HBM vs pallas_t at 34%").
+  ``bench_compare`` gate exactly like it/s.
 
 Peaks are **dataplane ceilings, not promises**: the table below holds
 published per-chip figures for the TPU generations the wave engine
@@ -113,7 +110,7 @@ def normalize_kind(kind):
 
 
 def device_kind():
-    """This process's device kind (autotune's cache key convention):
+    """This process's device kind (the peaks table's key convention):
     ``jax.devices()[0].device_kind``, else the backend name."""
     try:
         import jax
@@ -339,49 +336,6 @@ def utilization_rollup(entry_summary, costs, peaks, world_size=1):
     }
 
 
-# -- the autotuner's analytic cell model --------------------------------
-
-def cell_traffic(bucket, cell):
-    """Static (flops, hbm_bytes) per wave of one autotune cell.
-
-    The wave histogram pass reads every bucketed row's bin byte per
-    column plus its gradient/hessian pair (8 B in exact hilo precision,
-    4 B in the bf16 trade) and writes W padded (bins x cols) f32
-    hi/lo histogram pairs; MXU work is the one-hot dot, 2 FLOPs per
-    (row, col) MAC.  A static model — same spirit as the collectives
-    event's byte estimates: shape arithmetic the host can do without
-    timing anything inside the program.
-    """
-    n = float(getattr(bucket, "n_bucket", 0) or 0)
-    ncols = float(getattr(bucket, "ncols", 0) or 0)
-    bin_pad = float(getattr(bucket, "bin_pad", 0) or 0)
-    width = float(getattr(cell, "wave_width", 1) or 1)
-    gh_bytes = 4.0 if getattr(cell, "hist_hilo", True) is False else 8.0
-    flops = 2.0 * n * ncols * max(width, 1.0)
-    nbytes = (n * ncols                       # bin bytes, once per wave
-              + n * gh_bytes * max(width, 1.0)  # grad/hess per sweep
-              + width * bin_pad * ncols * 8.0)  # f32 hi+lo hist writes
-    return flops, nbytes
-
-
-def cell_roofline(bucket, cell, s_per_wave, kind=None, overrides=None):
-    """The roofline stamp for one probed autotune cell: where its
-    measured s/wave sits against this chip's compute and memory roofs.
-    ops/autotune.py attaches this dict to every ``autotune_probe``
-    event so ``obs explain`` can say why the winner won."""
-    if kind is None:
-        kind = device_kind()
-    peaks = peaks_for(kind, overrides)
-    flops, nbytes = cell_traffic(bucket, cell)
-    r = entry_roofline({"flops": flops, "bytes_accessed": nbytes},
-                       s_per_wave, 1, peaks)
-    return {"flop_util": round(r["flop_util"], 4),
-            "hbm_util": round(r["hbm_util"], 4),
-            "ai": round(r["ai"], 3) if r["ai"] else None,
-            "bound": r["bound"], "device_kind": peaks.get("kind"),
-            "roof_source": peaks.get("source")}
-
-
 # -- rendering -----------------------------------------------------------
 
 def fmt_quantity(v, unit=""):
@@ -401,25 +355,6 @@ def fmt_bytes(v):
         if abs(v) >= thresh:
             return "%.2f %s" % (v / thresh, suffix)
     return "%d B" % int(v)
-
-
-def describe_roofline_position(r):
-    """One clause for an autotune cell / entry stamp: '71% HBM' or
-    '12% MXU' — the dominant roof, as obs explain prints it."""
-    if not isinstance(r, dict):
-        return ""
-    bound = r.get("bound", "")
-    if bound == "memory":
-        return "%d%% HBM" % round(100 * float(r.get("hbm_util") or 0.0))
-    if bound == "compute":
-        return "%d%% MXU" % round(100 * float(r.get("flop_util") or 0.0))
-    if bound == "collective":
-        return "%d%% ICI" % round(100 * float(r.get("ici_util") or 0.0))
-    if bound:
-        top = max(float(r.get("hbm_util") or 0.0),
-                  float(r.get("flop_util") or 0.0))
-        return "%s, %d%% of roof" % (bound, round(100 * top))
-    return ""
 
 
 def render_roofline(events, out=None, check=False, peaks_path=""):

@@ -27,7 +27,7 @@ import time
 # process-global count of host<->device synchronizations issued through
 # fence().  Every deliberate block_until_ready in the training stack
 # routes through fence() so this is a complete audit: a default run
-# (NULL observer, no autotune probe) must leave it unchanged across
+# (NULL observer) must leave it unchanged across
 # training — asserted by bench.py --dry.
 _FENCE_COUNT = 0
 

@@ -43,8 +43,7 @@ Eligibility (models/gbdt.py _resolve_fused_iter): serial learner, one
 tree per iteration, a built-in (traceable) objective, no custom
 gradients, no GOSS/DART gradient rescale, no gradient health staging.
 Everything else falls back to the staged chain; ``tpu_fused_iter``
-(auto/on/off) picks between them, and the autotuner measures the flip
-as a cell dimension (ops/autotune.py Cell.fused, cache schema rev 2).
+(auto/on/off) picks between them (auto: ops/plan.py Plan.fused_wanted).
 """
 from __future__ import annotations
 

@@ -76,9 +76,7 @@ def tile_shape(num_bins: int):
     Widths even the 128 floor cannot absorb fail ``supports_bins`` and
     never reach the kernel (leaf_histogram_pallas falls back to onehot).
 
-    Public: the kernel's VMEM geometry is part of the selection surface
-    the autotuner (ops/autotune.py) and its probe harness reason about
-    when instantiating kernel cells standalone."""
+    Public: the vmem lint pass (analysis/vmem.py) evaluates it."""
     f_blk = 8
     row_chunk = 2048
     resident = f_blk * num_bins * 3 * 4          # the out block, VMEM-held

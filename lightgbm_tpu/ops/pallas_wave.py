@@ -40,8 +40,8 @@ from .wave import WAVE_ONLY_MODES, _bin_pad  # noqa: F401  (shared policy
 
 
 # -- VMEM scheduling thresholds (the 18-30 MB band post-mortem) ----------
-# The former "pathology band" (deleted HIST_BLOCK_BAND prior,
-# ops/autotune.py) was a lossy proxy for a Mosaic scheduling edge the
+# The former "pathology band" (the deleted HIST_BLOCK_BAND width
+# rule) was a lossy proxy for a Mosaic scheduling edge the
 # fused-iteration probe work finally isolated: the accumulator block's
 # per-sub-block read-modify-write only overlaps the MXU contraction while
 # the kernel's LIVE SET (resident accumulator + transient tiles) fits the

@@ -72,9 +72,9 @@ def _bin_pad(num_bins: int) -> int:
 def hist_block_bytes(ncols: int, bin_pad: int, width: int) -> int:
     """Bytes of the (ncols*bin_pad, 3W) f32 accumulator block the wave
     kernels keep resident in VMEM — the single geometry fact behind the
-    auto-mode VMEM gate, the accumulator-aware tile planner
-    (ops/pallas_wave.py _tile_plan), and the autotuner's cell
-    enumeration (ops/autotune.py)."""
+    auto-mode VMEM gate (ops/plan.py WAVE_VMEM_GATE), the
+    accumulator-aware tile planner (ops/pallas_wave.py _tile_plan) and
+    the static VMEM sweep (analysis/vmem.py)."""
     return ncols * bin_pad * 12 * width
 
 
@@ -97,32 +97,32 @@ def _slot_hist(ohf, match, wc, W, hist_dtype, exact_order):
                       preferred_element_type=hist_dtype)
 
 
-def pallas_wave_active(hist_mode: str, hist_dtype=jnp.float32) -> bool:
+def pallas_wave_active(hist_mode, hist_dtype=jnp.float32, backend=None):
     """True when a Pallas wave kernel will ACTUALLY run: TPU backend, f32
     accumulation (the kernels are single-dtype), and a pallas mode.  The
-    single copy of this predicate — the engine gate, the serial learner's
-    Xt precompute, and the mesh learner's Xt precompute all import it."""
-    return (jax.default_backend() == "tpu"
+    single copy of this predicate: the engine gate below asks it of the
+    running backend, ops/plan.py of the `backend` it was handed."""
+    return ((backend or jax.default_backend()) == "tpu"
             and hist_dtype == jnp.float32
             and hist_mode in ("pallas",) + WAVE_ONLY_MODES)
 
 
-def transposed_wave_active(hist_mode: str, hist_dtype=jnp.float32) -> bool:
+def transposed_wave_active(hist_mode, hist_dtype=jnp.float32, backend=None):
     """True when the running kernel is one of the TRANSPOSED layouts —
     i.e. a per-booster (F, N) Xt is worth materializing."""
     return (hist_mode in ("pallas_t", "pallas_ct")
-            and pallas_wave_active(hist_mode, hist_dtype))
+            and pallas_wave_active(hist_mode, hist_dtype, backend))
 
 
 def slab_active(compact: bool, hist_mode: str, hist_dtype, psum_axis,
-                pallas_interpret: bool = False) -> bool:
+                pallas_interpret: bool = False, backend=None) -> bool:
     """True when a wave's histogram launch reads the row slab of its
     smaller children in place of all N rows: the pallas_t kernel really
     runs (or its interpreter), on one device or on every shard of a data
     mesh (`psum_axis` decides nothing: a shard's slab holds its own rows
     of the children).  Decided from what the program can observe; the
-    learner reports it (obs_info)."""
-    runs = pallas_wave_active(hist_mode, hist_dtype) or (
+    plan reports it (ops/plan.py Plan.slab, the learner's obs_info)."""
+    runs = pallas_wave_active(hist_mode, hist_dtype, backend) or (
         pallas_interpret and hist_dtype == jnp.float32)
     return bool(compact and hist_mode == "pallas_t" and runs)
 

@@ -236,16 +236,14 @@ class DataParallelTreeLearner(SerialTreeLearner):
             # (a row-shard of X and the matching column-shard of Xt live
             # on the same device, so this transpose is comm-free) instead
             # of once per tree dispatch inside the shard-mapped grow
-            from ..ops.wave import transposed_wave_active
-            needs_xt = (transposed_wave_active(self.hist_mode, self.dtype)
-                        and not self.sparse_on)
+            needs_xt = self.plan.needs_xt
             grow = make_wave_grow_fn(
                 self.num_leaves, self.num_bins, self.meta, self.params,
                 config.max_depth, wave_width=self.wave_width,
                 hist_dtype=self.dtype, psum_axis=DATA_AXIS,
                 bundle=self.bundle_arrays, group_bins=self.group_bins,
                 cache_hists=self.cache_hists, hist_mode=self.hist_mode,
-                chunk=int(config.tpu_wave_chunk),
+                chunk=self.plan.wave_chunk,
                 sparse_col_cap=self.sparse_col_cap, with_xt=needs_xt,
                 exact_order=self.wave_order == "exact",
                 lookup=self.wave_lookup, hist_hilo=self.hist_hilo,

@@ -65,7 +65,7 @@ kMinScore = -np.inf
 
 
 def compilation_cache_dir() -> str:
-    """The one directory for compiled programs and the autotune JSON:
+    """The one directory for compiled programs:
     ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
     (found from this package's ``__file__``).  A fixed path, because a
     cache directory that moves never hits."""

@@ -170,11 +170,6 @@ ALIAS_TABLE: Dict[str, str] = {
     "serve_slo_p99": "serve_slo_p99_ms",
     "serve_slo_window": "serve_slo_window_s",
     "serve_slo_snapshot_every": "serve_slo_every_s",
-    "autotune": "tpu_autotune",
-    "autotune_mode": "tpu_autotune",
-    "autotune_cache": "tpu_autotune_cache",
-    "autotune_cache_path": "tpu_autotune_cache",
-    "autotune_waves": "tpu_autotune_waves",
     "fused_iter": "tpu_fused_iter",
 }
 
@@ -221,8 +216,6 @@ PARAMETER_SET = {
     "tpu_growth", "tpu_wave_width", "tpu_bin_pack", "tpu_wave_chunk",
     "tpu_sparse", "tpu_wave_order", "tpu_predict", "tpu_wave_lookup",
     "tpu_sparse_kernel", "tpu_hist_precision", "tpu_score_update",
-    # measured kernel autotuner (ops/autotune.py)
-    "tpu_autotune", "tpu_autotune_cache", "tpu_autotune_waves",
     # fused boosting iteration (ops/fused_iter.py)
     "tpu_fused_iter", "tpu_pallas_interpret",
     # observability (lightgbm_tpu/obs/)
@@ -470,12 +463,10 @@ class Config:
         # transposed operands, 'pallas_ct' = fused partition+histogram
         # wave kernel, compact split table, one read of X_t per wave).
         # auto, on TPU when the wave engine runs it (f32, dense,
-        # serial/data learner): pallas_ct for narrow shapes
-        # (ncols * bin_pad <= 2048 — measured winner at 10.5M x 28 and
-        # 1M x 28, r4), pallas_t for wider VMEM-feasible shapes; else
-        # onehot on TPU, scatter elsewhere.  (pallas_f/pallas_ft were
-        # deleted in r4: lost every on-chip A/B, padded-operand OOM
-        # liability — tools/AB_RESULTS.md.)
+        # serial/data learner): pallas_ct for narrow shapes on one
+        # device (ncols * bin_pad <= 2560), pallas_t for wider
+        # VMEM-feasible shapes; else onehot on TPU, scatter elsewhere
+        # (ops/plan.py prior_hist_mode says what each side rests on).
         "tpu_histogram_mode": ("str", "auto"),
         # 'auto' | 'exact' | 'wave' — growth schedule (ops/wave.py):
         # 'exact' is the reference's one-split-at-a-time leaf-wise order;
@@ -484,9 +475,10 @@ class Config:
         "tpu_growth": ("str", "auto"),
         # W in 'wave' growth: splits the top-W pending leaves per sweep
         # (same greedy frontier as leaf-wise, batched; quality parity in
-        # tests/test_wave.py).  -1 = auto, scaled to num_leaves (measured
-        # on v5e: W=16 fastest at 63 leaves, W=32 at 255); set 1 to
-        # reproduce the reference's exact split sequence.
+        # tests/test_wave.py).  -1 = auto, scaled to num_leaves (8 up to
+        # 31 leaves, 16 up to 127, 32 above: ops/plan.py
+        # resolve_wave_width); set 1 to reproduce the reference's exact
+        # split sequence.
         "tpu_wave_width": ("int", -1),
         # 'auto' | 'batched' | 'exact' — wave COMMIT ORDER.  'batched'
         # commits all W top-gain splits per sweep (fastest; the greedy
@@ -504,8 +496,9 @@ class Config:
         # (L, 10) split table on the MXU; 'compact' matches rows against
         # only the W wave parents (<=1 match per row, so the masked sum
         # is exact) — W/L of the one-hot footprint; 'gather' indexes the
-        # table directly.  auto -> compact on TPU (measured +12% over
-        # onehot-lookup on v5e at the flagship recipe), onehot elsewhere.
+        # table directly.  auto -> compact on TPU (every cell of the
+        # ledger runs it; against the others it is not measured by the
+        # driver), onehot elsewhere.
         "tpu_wave_lookup": ("str", "auto"),
         # 'auto' | 'hilo' | 'bf16' — MXU product precision of the Pallas
         # wave histogram kernels.  'hilo' (exact bf16 hi+lo split, two
@@ -517,10 +510,11 @@ class Config:
         # ROUTING is unaffected (exact f32 compares) — only histogram
         # sums, and through them split choices, can drift.  auto = bf16
         # where the Pallas wave kernels run under single-chip wave
-        # growth (promoted round 5: 1.63x at the 10.5M flagship, AUC
-        # within 1.0e-4 — tools/BENCH_SUITE.md higgs_bf16); exact
-        # growth, data-parallel execution, and every non-pallas engine
-        # stay hilo.  Set 'hilo' to force the exact split everywhere.
+        # growth (ledger, PR 25/27: both one-chip cells, `correct`);
+        # exact growth, data-parallel execution (ledger, PR 28/29) and
+        # every non-pallas engine stay hilo (ops/plan.py
+        # prior_hist_hilo).  Set 'hilo' to force the exact split
+        # everywhere.
         # Where the kernels make two products the root's one-hot pass
         # (ops/histogram.py) makes two as well: one would leave its
         # rounding to the larger child of every split.
@@ -550,37 +544,21 @@ class Config:
         # 'auto' | 'gather' | 'pallas' — the train-side score update
         # (score += leaf_value[leaf_id]).  'gather' = XLA small-table
         # gather; 'pallas' = compare-select kernel (ops/predict.py,
-        # bit-equal, measured faster at the 10.5M flagship: 1.45 vs
-        # 1.30 it/s with EXACTLY equal AUC — tools/BENCH_SUITE.md
-        # higgs_su).  auto = pallas (promoted round 5); the dispatch
-        # falls back to the gather off-TPU, above 512 leaves, or on
-        # f64 scores (tpu_use_dp).
+        # bit-equal).  auto = pallas (it runs in every cell of the
+        # ledger; against the gather it is not measured by the driver);
+        # the dispatch falls back to the gather off-TPU, above 512
+        # leaves, or on f64 scores (tpu_use_dp).
         "tpu_score_update": ("str", "auto"),
-        # 'off' | 'prior' | 'measure' | 'force' — the measured kernel
-        # autotuner (ops/autotune.py, docs/Autotuning.md).  off = the
-        # heuristic prior only (bit-identical to the legacy inline
-        # selection; the CPU-CI default).  prior = adopt a cached
-        # winner when one exists, never probe.  measure = on cache miss
-        # microbench the 3-5 candidate (kernel, W, precision) cells
-        # for the shape bucket on-device and persist the winner.  force
-        # = always re-probe, overwriting the cache.
-        "tpu_autotune": ("str", "off"),
-        # autotune cache file; empty = autotune_cache.json in the XLA
-        # compile-cache directory (utils/common.py compilation_cache_dir)
-        "tpu_autotune_cache": ("str", ""),
-        # timed waves per probed cell (compile + one warmup wave are
-        # always excluded from the timing window)
-        "tpu_autotune_waves": ("int", 3),
         # 'auto' | 'on' | 'off' — the fused boosting iteration
         # (ops/fused_iter.py, docs/FusedIteration.md): gradients, the
         # grow program and the score update submitted as ONE jitted
         # device entry per tree instead of the staged three-dispatch
         # chain.  auto = fuse when the booster/objective shape is
-        # eligible and either the TPU wave path is live or the
-        # autotuner measured the fused cell as the winner (rev-2
-        # cells).  on = force when eligible (warns and stays staged
-        # when not).  off = always the staged chain.  Fused and staged
-        # produce bit-identical models (tests/test_fused_iter.py).
+        # eligible and the TPU wave path is live (ops/plan.py
+        # Plan.fused_wanted).  on = force when eligible (warns and
+        # stays staged when not).  off = always the staged chain.
+        # Fused and staged produce bit-identical models
+        # (tests/test_fused_iter.py).
         "tpu_fused_iter": ("str", "auto"),
         # run the Pallas wave kernels through the interpreter on CPU
         # (tests/CI only): exercises the real kernel bodies — tiling,

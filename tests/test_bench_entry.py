@@ -77,11 +77,6 @@ def test_compilation_cache_in_the_checkout_on_tpu(monkeypatch):
                        "jax_persistent_cache_min_compile_time_secs": 0.0}
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
-    # the autotune JSON sits in the same directory
-    from lightgbm_tpu.ops.autotune import resolve_cache_path
-    from lightgbm_tpu.utils.config import Config
-    assert resolve_cache_path(Config({})) == os.path.join(
-        d, "autotune_cache.json")
 
 
 def test_package_import_is_backend_clean():

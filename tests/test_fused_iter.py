@@ -144,9 +144,8 @@ def test_fused_on_with_ineligible_config_stays_staged():
 
 
 def test_fused_auto_stays_staged_on_plain_cpu():
-    """auto only fuses where the wave Pallas kernels are active or the
-    autotuner measured the fused cell as the winner — a default CPU run
-    is neither."""
+    """auto only fuses where the wave Pallas kernels are active
+    (ops/plan.py Plan.fused_wanted) — a default CPU run is not."""
     bst = _train_one({})
     assert bst._gbdt._fused_state == (None,)
 

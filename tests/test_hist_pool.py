@@ -47,7 +47,7 @@ def test_pool_auto_budget_boundaries():
     """The auto budget admits Higgs- and Epsilon-shaped caches (both fit a
     16 GB chip alongside the data) but rejects unbounded growth, and an
     explicit histogram_pool_size always wins."""
-    from lightgbm_tpu.ops.learner import hist_cache_enabled
+    from lightgbm_tpu.ops.plan import hist_cache_enabled
     from lightgbm_tpu.utils.config import Config
     cfg = Config({"verbose": -1})
     assert hist_cache_enabled(cfg, 255, 28, 64, 4)        # Higgs: 5.5 MB

@@ -211,7 +211,7 @@ def test_config_registry_and_doc_fresh():
 # ------------------------------------------------------------------- vmem
 
 def test_vmem_clean_on_repo_planners():
-    # PR-11 invariants hold: every autotuner-admitted cell plans a live
+    # PR-11 invariants hold: every cell the VMEM gate admits plans a live
     # set within physical VMEM, no serialized chunked-RMW plan, and the
     # hist kernel fits its tile budget at every width it claims
     assert vmem.run(core.load_modules(REPO_ROOT), REPO_ROOT) == []

@@ -281,10 +281,11 @@ def test_obs_explain_empty_timeline(tmp_path):
     from lightgbm_tpu.obs.query import last_run, load_timeline
     assert query.render_explain(last_run(load_timeline(str(path))),
                                 out=buf) is False
-    # schema v8: even a model/data-quiet run records its kernel
-    # autotune decision, so the report is never empty for a trained run
-    assert "autotune decisions" in buf.getvalue()
-    # a timeline with no explainable events at all keeps the fallback
+    # a model/data-quiet run has nothing to explain (the learner queues
+    # no construction-time event any more): the fallback says how to
+    # populate the report
+    assert "no model/data events" in buf.getvalue()
+    # and so does a timeline with no events at all
     buf2 = io.StringIO()
     assert query.render_explain([], out=buf2) is False
     assert "no model/data events" in buf2.getvalue()

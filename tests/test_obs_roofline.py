@@ -6,8 +6,8 @@ join and its bound classification edges (compute / memory / collective /
 host-orchestration, the ORCH_FLOOR regime), the per-iteration
 ``utilization`` rollup math and its end-to-end emission from a real
 training run, the ``obs roofline`` CLI and its ``--check`` exit codes,
-the autotune-cell roofline stamp (analytic traffic model + probe-event
-stamping + ``obs explain`` rendering), the serving-tier executable
+an older run's tuner events (accepted, rendered by nothing), the
+serving-tier executable
 join, the humanized ``obs recompiles`` cost tags, the shared
 ``parse_compiled`` helper both the JIT tracker and the serve tier read
 XLA analyses through (list-form ``cost_analysis`` regression), and the
@@ -28,8 +28,6 @@ from lightgbm_tpu.obs.compile import analyze_compiled, parse_compiled
 from lightgbm_tpu.obs.ledger import metrics_from_events
 from lightgbm_tpu.obs.query import main as obs_main
 from lightgbm_tpu.obs.roofline import (BOUNDS, DEFAULT_PEAKS, ORCH_FLOOR,
-                                       cell_roofline, cell_traffic,
-                                       describe_roofline_position,
                                        entry_roofline, fmt_bytes,
                                        fmt_quantity, load_peak_overrides,
                                        normalize_kind, peaks_for,
@@ -355,90 +353,26 @@ def test_cli_peaks_override(tmp_path, capsys):
     assert "50.00 GB" in out.replace("GiB", "GB") or "46.57 GiB" in out
 
 
-# -------------------------------------------------- autotune stamping
+# ----------------------------- event kinds that nothing emits any more
 
-def test_cell_traffic_model():
-    from lightgbm_tpu.ops.autotune import Cell, ShapeBucket
-    bucket = ShapeBucket(ncols=28, bin_pad=64, num_leaves=255,
-                         n_bucket=1 << 20)
-    hilo = Cell("pallas_ct", 8, True)
-    flops, nbytes = cell_traffic(bucket, hilo)
-    n = float(1 << 20)
-    assert flops == pytest.approx(2.0 * n * 28 * 8)
-    assert nbytes == pytest.approx(n * 28 + n * 8.0 * 8
-                                   + 8 * 64 * 28 * 8.0)
-    # the bf16 trade halves the gradient/hessian read traffic
-    _, nb_bf16 = cell_traffic(bucket, Cell("pallas_ct", 8, False))
-    assert nb_bf16 == pytest.approx(nbytes - n * 4.0 * 8)
-
-
-def test_cell_roofline_stamp_shape():
-    from lightgbm_tpu.ops.autotune import Cell, ShapeBucket
-    bucket = ShapeBucket(28, 64, 255, 1 << 16)
-    stamp = cell_roofline(bucket, Cell("pallas_t", 8, True),
-                          s_per_wave=1e-3, kind="tpu_v4")
-    assert set(stamp) == {"flop_util", "hbm_util", "ai", "bound",
-                          "device_kind", "roof_source"}
-    assert stamp["device_kind"] == "tpu_v4"
-    assert stamp["roof_source"] == "table"
-    assert stamp["bound"] in BOUNDS
-    assert 0.0 <= stamp["flop_util"] <= 1.0
-    # the stamp validates as an autotune_probe optional field
-    validate_event({"ev": "autotune_probe", "t": 1.0, "run": "r0",
-                    "cell": {}, "s_per_wave": 1e-3, "roofline": stamp},
-                   strict=True)
-
-
-def test_measure_cells_stamps_every_probe():
-    from lightgbm_tpu.ops.autotune import (Cell, ShapeBucket,
-                                           clear_probe_hooks,
-                                           install_probe_hooks,
-                                           measure_cells)
-    bucket = ShapeBucket(8, 64, 15, 2048)
-    cells = [Cell("pallas_t", 8, True),
-             Cell("pallas_ct", 4, False)]
-    events = []
-    install_probe_hooks(bench=lambda cell, b: 1e-3)
-    try:
-        out = measure_cells(cells, bucket, None, 2, events)
-    finally:
-        clear_probe_hooks()
-    assert len(out) == 2 and len(events) == 2
-    for name, fields in events:
-        assert name == "autotune_probe"
-        stamp = fields["roofline"]
-        assert stamp is not None and stamp["bound"] in BOUNDS
-
-
-def test_explain_prints_roofline_position(tmp_path, capsys):
-    assert describe_roofline_position(
-        {"bound": "memory", "hbm_util": 0.71}) == "71% HBM"
-    assert describe_roofline_position(
-        {"bound": "compute", "flop_util": 0.12}) == "12% MXU"
-    assert describe_roofline_position(
-        {"bound": "collective", "ici_util": 0.4}) == "40% ICI"
-    assert "host-orchestration" in describe_roofline_position(
-        {"bound": "host-orchestration", "hbm_util": 0.01})
-    assert describe_roofline_position(None) == ""
-    assert describe_roofline_position({}) == ""
-    cell = {"hist_mode": "pallas_ct", "wave_width": 8,
-            "hist_hilo": True, "compact": False}
-    p = _write(tmp_path / "tl.jsonl", [
-        _header(),
-        {"ev": "autotune_decision", "run": "r0", "t": 1e9 + 1,
-         "mode": "measure", "source": "measured", "cell": cell,
-         "cells": [
-             {"cell": cell, "s_per_wave": 1e-3,
-              "roofline": {"bound": "memory", "hbm_util": 0.71}},
-             {"cell": dict(cell, hist_mode="pallas_t"),
-              "s_per_wave": 2e-3,
-              "roofline": {"bound": "memory", "hbm_util": 0.34}}]},
-        _end({}),
-    ])
+def test_explain_accepts_an_older_runs_tuner_events(tmp_path, capsys):
+    """The measured kernel tuner is deleted and nothing emits its two
+    event kinds (schema 8, with schema 13's roofline stamp), but a
+    timeline written by an older run is input from outside: each kind
+    still validates with every field the table declares for it, and
+    `obs explain` reads past them."""
+    from lightgbm_tpu.obs.events import _REQUIRED, declared_fields
+    kinds = sorted(ev for ev in _REQUIRED if ev.startswith("auto"))
+    assert len(kinds) == 2
+    old = [dict({f: {} for f in declared_fields(ev)},
+                ev=ev, run="r0", t=1e9 + 1 + i)
+           for i, ev in enumerate(kinds)]
+    for e in old:
+        validate_event(e, strict=True)
+    p = _write(tmp_path / "tl.jsonl", [_header()] + old + [_end({})])
     assert obs_main(["explain", p]) == 0
     out = capsys.readouterr().out
-    assert "[at 71% HBM]" in out and "[at 34% HBM]" in out
-    assert "<- winner" in out
+    assert kinds[0] not in out and "no model/data events" in out
 
 
 # -------------------------------------------------- serve tier

@@ -73,8 +73,8 @@ def test_exact_order_matches_w1_goss_dart():
 def test_exact_order_auto_defaults():
     """auto wave order resolves exact ONLY for order-sensitive configs;
     auto width then keeps the ladder instead of collapsing to W=1."""
-    from lightgbm_tpu.ops.learner import (resolve_wave_order,
-                                          resolve_wave_width)
+    from lightgbm_tpu.ops.plan import (resolve_wave_order,
+                                       resolve_wave_width)
     from lightgbm_tpu.utils.config import Config
 
     plain = Config({"objective": "binary", "verbose": -1})

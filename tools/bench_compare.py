@@ -41,7 +41,7 @@ Usage:
         [--tol-ips 0.08] [--tol-compile 0.25] [--tol-mem 0.10] \
         [--tol-recompile 0] [--tol-eval 0.02] \
         [--tol-serve-qps 0.15] [--tol-serve-p99 0.30] \
-        [--tol-serve-shed 0.25] [--tol-autotune 0.50] \
+        [--tol-serve-shed 0.25] \
         [--tol-construct 0.30] [--tol-host-orch 0.50] [--json]
 
 Exit codes: 0 pass, 1 regression beyond tolerance, 2 load/usage error.
@@ -86,13 +86,6 @@ METRICS = {
     # sheds nothing, so ANY shedding in the candidate is a regression;
     # overload-vs-overload runs tolerate 25% load-generator noise
     "serve_shed_rate": (-1, 0.25),
-    # total probe seconds the kernel autotuner paid this run (summed
-    # over autotune_decision events, ops/autotune.py).  Zero on cache
-    # hits / tuning off — the zero-baseline rule makes ANY candidate
-    # probing vs a warm-cache baseline a regression, which is exactly
-    # the "second run on the same shape performs zero probe waves"
-    # contract; measure-vs-measure runs tolerate 50% timer noise
-    "autotune_overhead_s": (-1, 0.50),
     # dataset construction wall seconds (summed over dataset_construct
     # events, io/streaming.py two-pass ingest).  A pre-binned reload
     # reports sketch_s == bin_s == 0, so candidate-vs-baseline catches
@@ -172,12 +165,6 @@ def _from_timeline(events):
         out["serve_p99_s"] = float(serve[-1]["p99_s"])
         if serve[-1].get("shed_rate") is not None:
             out["serve_shed_rate"] = float(serve[-1]["shed_rate"])
-    # kernel-autotuner probe cost (schema v8): present whenever the run
-    # recorded a decision, zero when the cache was warm or tuning off
-    decs = [e for e in events if e.get("ev") == "autotune_decision"]
-    if decs:
-        out["autotune_overhead_s"] = sum(
-            float(e.get("overhead_s", 0.0)) for e in decs)
     # host-orchestration glue (schema v11): mean over the run's iter
     # records; older timelines without the field simply skip the metric
     orch = [float(e["host_orchestration_s"]) for e in iters
@@ -457,10 +444,6 @@ def main(argv=None):
         "serve_shed_rate"][1],
         help="serving shed-rate relative tolerance (a zero-shed "
              "baseline fails on ANY candidate shedding)")
-    ap.add_argument("--tol-autotune", type=float, default=METRICS[
-        "autotune_overhead_s"][1],
-        help="autotune probe-overhead relative tolerance (a warm-cache "
-             "zero-overhead baseline fails on ANY candidate probing)")
     ap.add_argument("--tol-construct", type=float, default=METRICS[
         "construct_s"][1],
         help="dataset-construction time relative tolerance (a "
@@ -488,7 +471,6 @@ def main(argv=None):
             "serve_qps": args.tol_serve_qps,
             "serve_p99_s": args.tol_serve_p99,
             "serve_shed_rate": args.tol_serve_shed,
-            "autotune_overhead_s": args.tol_autotune,
             "construct_s": args.tol_construct,
             "host_orchestration_s": args.tol_host_orch,
             "flop_util": args.tol_flop_util,

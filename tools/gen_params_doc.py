@@ -79,15 +79,17 @@ NOTES = {
                           "pallas_ct histogram kernels; auto on TPU "
                           "under the wave engine (f32, dense, "
                           "serial/data) = pallas_ct for narrow shapes "
-                          "(ncols x bin-pad <= 2048), pallas_t for "
-                          "wider VMEM-feasible ones, else onehot (TPU) "
-                          "/ scatter",
+                          "on one device (ncols x bin-pad <= 2560), "
+                          "pallas_t for wider VMEM-feasible ones, else "
+                          "onehot (TPU) / scatter (ops/plan.py)",
     "tpu_hist_precision": "auto / hilo / bf16 — Pallas wave-kernel MXU "
                           "product precision: hilo = exact bf16 hi+lo "
                           "split (two dots); bf16 = single "
                           "round-to-nearest term, half the MXU work "
                           "(the reference GPU's single-precision "
-                          "histogram trade); auto = hilo",
+                          "histogram trade); auto = bf16 where the "
+                          "kernels run under wave growth on one device, "
+                          "hilo elsewhere (data mesh, exact growth)",
     "tpu_sparse_kernel": "true / false — with tpu_sparse, use the "
                          "entry-chunk MXU sparse store (Pallas kernel, "
                          "wave growth, serial learner) instead of the "
@@ -95,28 +97,17 @@ NOTES = {
     "tpu_score_update": "auto / gather / pallas — train-side score "
                         "update engine (score += leaf_value[leaf_id]): "
                         "XLA gather, or the bit-equal Pallas "
-                        "compare-select kernel; auto = gather",
+                        "compare-select kernel; auto = pallas (falls "
+                        "back to the gather off-TPU, above 512 leaves, "
+                        "on f64 scores)",
     "tpu_bin_pack": "auto / true / false — 4-bit bin packing (at most 16 "
                     "bins/column: max_bin<=15 plus the reserved bin)",
-    "tpu_autotune": "off / prior / measure / force — measured on-device "
-                    "kernel autotuner for the wave cell (hist kernel, "
-                    "wave width, precision): off = hand-tuned "
-                    "heuristics only, prior = heuristics + decision "
-                    "telemetry, measure = microbench the viable cells on "
-                    "a cache miss, force = always re-measure; see "
-                    "Autotuning.md",
-    "tpu_autotune_cache": "autotune decision cache path (JSON); empty = "
-                          "autotune_cache.json next to the XLA compile "
-                          "cache",
-    "tpu_autotune_waves": "timed waves per probed cell in measure/force "
-                          "mode (plus one untimed warmup wave)",
     "tpu_fused_iter": "auto / on / off — run each boosting iteration as "
                       "ONE fused device program (gradients + tree growth "
                       "+ score update, ops/fused_iter.py) instead of the "
                       "staged entry chain; bit-identical models either "
                       "way.  auto = fuse where the Pallas wave kernels "
-                      "are active or the autotuner measured the fused "
-                      "cell as the winner; ineligible configs (DART/"
+                      "are active; ineligible configs (DART/"
                       "GOSS/multiclass/custom fobj/obs_health) always "
                       "use the staged chain; see FusedIteration.md",
     "tpu_pallas_interpret": "true / false — run the Pallas wave kernels "
@@ -386,8 +377,6 @@ GROUPS = [
         "tpu_hist_precision", "tpu_score_update", "tpu_bin_pack",
         "tpu_sparse", "tpu_sparse_kernel", "tpu_use_dp", "tpu_predict",
         "tpu_fused_iter", "tpu_pallas_interpret", "tpu_profile_dir"]),
-    ("Autotune", [
-        "tpu_autotune", "tpu_autotune_cache", "tpu_autotune_waves"]),
     ("Observability", [
         "obs_events_path", "obs_timing", "obs_memory_every",
         "obs_trace_iters", "obs_trace_dir", "obs_flush_every",
