@@ -68,6 +68,35 @@ class _NullOrchestration:
 _NULL_ORCH = _NullOrchestration()
 
 
+# elements of one device-to-host copy of a score matrix (16 MB of
+# float32).  A (1, 41,943,040) score fetched whole, 168 MB, leaves the TPU
+# runtime taking 6 ms for every later dispatch of the process where it took
+# 1.6 (read on the chip, PR 33: at once after `np.asarray(score,
+# float64)` in three processes of three, never after twelve fetches by
+# pieces of this size in two; the host's own allocations do not do it)
+_HOST_PIECE = 1 << 22
+
+
+@jax.jit
+def _columns_from(x, start):
+    return jax.lax.dynamic_slice_in_dim(x, start, _HOST_PIECE, axis=1)
+
+
+def _host_float64(dev) -> np.ndarray:
+    """The (k, n) device matrix `dev` on the host as float64, fetched in
+    pieces of `_HOST_PIECE` columns where it has more (the last piece
+    starts early enough to be whole, so every piece is one program)."""
+    n = dev.shape[1] if dev.ndim == 2 else 0
+    if n <= _HOST_PIECE:
+        return np.asarray(dev, dtype=np.float64)
+    out = np.empty(dev.shape, np.float64)
+    for start in range(0, n, _HOST_PIECE):
+        start = min(start, n - _HOST_PIECE)
+        out[:, start:start + _HOST_PIECE] = np.asarray(
+            _columns_from(dev, start))
+    return out
+
+
 class GBDT:
     """Gradient Boosting Decision Tree (boosting.h:21-261 interface)."""
 
@@ -134,11 +163,12 @@ class GBDT:
             Log.fatal("Unknown tpu_score_update %s (expected auto/"
                       "gather/pallas)", config.tpu_score_update)
         # auto -> the pallas compare-select kernel, bit-equal to the
-        # gather (ledger, PR 25-29: it runs in every cell; against the
-        # gather it is not measured by the driver).  The dispatch itself
-        # (ops/predict.py) still gates on TPU + num_leaves<=512 + f32
-        # score and falls back to the XLA gather otherwise, so 'auto' is
-        # safe to resolve unconditionally here.
+        # gather, in the staged chain and, since PR 33, inside the fused
+        # step (before it the fused step, which every one-chip cell runs,
+        # took the gather whatever this said).  The dispatch itself
+        # (ops/predict.py pallas_score_update_runs) still gates on TPU +
+        # num_leaves<=512 + f32 score and falls back to the XLA gather
+        # otherwise, so 'auto' is safe to resolve unconditionally here.
         self._score_engine = "pallas" if se == "auto" else se
 
     def _reset_observer(self, config: Config) -> None:
@@ -380,13 +410,13 @@ class GBDT:
     def train_score(self) -> np.ndarray:
         """Host mirror of the training scores (pull-on-demand)."""
         if self._score_host is None:
-            self._score_host = np.asarray(self._score_dev, dtype=np.float64)
+            self._score_host = _host_float64(self._score_dev)
         return self._score_host
 
     def valid_score_host(self, i: int) -> np.ndarray:
         if self._valid_score_host[i] is None:
-            self._valid_score_host[i] = np.asarray(self._valid_score_dev[i],
-                                                   dtype=np.float64)
+            self._valid_score_host[i] = _host_float64(
+                self._valid_score_dev[i])
         return self._valid_score_host[i]
 
     def _invalidate_train(self):
@@ -558,7 +588,8 @@ class GBDT:
                 if mode == "on" or self.learner.plan.fused_wanted:
                     fused = _fi.FusedIteration.build(
                         self.learner, self.objective,
-                        self.num_data, self.score_dtype)
+                        self.num_data, self.score_dtype,
+                        self._score_engine)
         self._fused_state = (fused,)
         return fused
 

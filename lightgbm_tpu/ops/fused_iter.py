@@ -30,10 +30,11 @@ The fused program traces the SAME functions the staged path calls:
 * growth: the learner's OWN jitted grow closure (``learner._grow``) is
   inlined — same statics, same kernels, same reduction orders, including
   the CPU-interpret Pallas path under ``tpu_pallas_interpret=true``;
-* score update: ops/partition.py ``score_update_impl`` — the single
-  source the staged gather engine (ops/predict.py) delegates to.  (The
-  staged TPU pallas score engine selects the same clipped f32 values;
-  its bit-equality claim is documented at its dispatch site.)
+* score update: ops/predict.py ``score_update_traced`` with the
+  booster's own ``tpu_score_update`` engine — the gather form of
+  ops/partition.py ``score_update_impl`` or, where the staged chain
+  dispatches it (a TPU, an f32 score, at most 512 leaves), the Pallas
+  compare-select kernel, which selects the same clipped f32 values.
 
 Same trees, same split-audit events, same model file — enforced by
 tests/test_fused_iter.py across the flagship/epsilon/msltr/expo_cat
@@ -50,7 +51,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .partition import score_update_impl
+from .predict import score_update_traced
 from ..obs import timers
 from ..utils.log import Log
 
@@ -98,7 +99,8 @@ class FusedIteration:
     second HBM copy with the dataset.
     """
 
-    def __init__(self, learner, objective, num_data: int):
+    def __init__(self, learner, objective, num_data: int,
+                 score_engine: str = "gather"):
         self._learner = learner
         self._num_data = int(num_data)
         self._obj_arrays, rebind = objective.split_device_state()
@@ -125,13 +127,13 @@ class FusedIteration:
                 tree, leaf_id = grow(X, g, h, row_mult, feature_mask)
             else:
                 tree, leaf_id = grow(X, g, h, row_mult, feature_mask, Xt)
-            # stage 3: partition-side score update, shared impl with the
-            # staged gather engine (bit-identity single source)
+            # stage 3: partition-side score update, the staged chain's
+            # own engine (bit-identity single source)
             with jax.named_scope("score_update"):
                 if pad:
                     leaf_id = leaf_id[: self._num_data]
-                new_score = score_update_impl(score, leaf_id,
-                                              tree.leaf_value, scale)
+                new_score = score_update_traced(
+                    score, leaf_id, tree.leaf_value, scale, score_engine)
             return tree, leaf_id, new_score
 
         self._step = jax.jit(step)
@@ -147,7 +149,8 @@ class FusedIteration:
                 feature_mask, scale)
 
     @classmethod
-    def build(cls, learner, objective, num_data: int, score_dtype):
+    def build(cls, learner, objective, num_data: int, score_dtype,
+              score_engine: str = "gather"):
         """Construct and trace-check the fused program.
 
         jax.eval_shape traces without compiling or executing, so a
@@ -156,7 +159,7 @@ class FusedIteration:
         user-defined ObjectiveFunction subclass whose get_gradients runs
         host code on the score (numpy on a tracer: a JAXTypeError) sends
         the booster back to the staged chain, with a warning."""
-        fused = cls(learner, objective, num_data)
+        fused = cls(learner, objective, num_data, score_engine)
         n = int(num_data)
         shapes = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),

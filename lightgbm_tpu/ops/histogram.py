@@ -78,6 +78,13 @@ def _gathered_weights(grad, hess, row_mult, idx, valid):
                      axis=-1)                     # (C, 3)
 
 
+def chunk_rows(arrays, i, chunk: int):
+    """Rows [i * chunk, (i + 1) * chunk) of each of `arrays`: the chunk
+    loops' one way to a chunk (here and in ops/wave.py)."""
+    return tuple(lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=0)
+                 for a in arrays)
+
+
 def _scatter_accumulate(binned, w, num_bins: int, logical_cols: int = 0):
     """(F, B, 3) from (C, F) bins and (C, 3) weights via segment_sum.
 
@@ -125,26 +132,29 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
         binned = jnp.pad(binned, ((0, pad), (0, 0)))
         w = jnp.pad(w, ((0, pad), (0, 0)))
     nchunks = (n + pad) // chunk
-    xb = binned.reshape(nchunks, chunk, fdev)
-    wb = w.reshape(nchunks, chunk, k)
 
-    def step(acc, args):
-        xc, wc = args
+    def step(i, acc):
+        # a chunk is a window of the (N, F) matrix itself, never a row of
+        # a (nchunks, chunk, F) reshape: where the rows are the minor
+        # dimension of the device layout (a narrow table) that reshape is
+        # a real transposition, a copy of the matrix a tree, and the
+        # TPU compiler's code for it grows with nchunks unless nchunks
+        # is a multiple of 8 (PERF.md section 6, PR 33)
+        xc, wc = chunk_rows((binned, w), i, chunk)
         if logical_cols:
             from .pack import unpack4
             xc = unpack4(xc, f)
         onehot = jax.nn.one_hot(xc.astype(jnp.int32), num_bins,
                                 dtype=wc.dtype)          # (C, F, B)
-        acc = acc + jnp.einsum("cfb,cw->fbw", onehot, wc,
-                               preferred_element_type=wc.dtype)
-        return acc, None
+        return acc + jnp.einsum("cfb,cw->fbw", onehot, wc,
+                                preferred_element_type=wc.dtype)
 
     init = jnp.zeros((f, num_bins, k), dtype=w.dtype)
     if nchunks == 1:
-        hist, _ = step(init, (xb[0], wb[0]))
+        hist = step(0, init)
     else:
         from .grow import vary_like
-        hist, _ = lax.scan(step, vary_like(init, xb, wb), (xb, wb))
+        hist = lax.fori_loop(0, nchunks, step, vary_like(init, binned, w))
     return hist[..., :3] + hist[..., 3:] if hilo else hist
 
 

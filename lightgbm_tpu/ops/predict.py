@@ -178,6 +178,7 @@ def _update_score_pallas(score, leaf_id, vals, interpret=False):
     operands = (vals[None, :].astype(jnp.float32), l2, s2)
     out = pl.pallas_call(
         kernel,
+        name="score_update_pallas",
         grid=(m // c,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),     # (1, L) table
@@ -194,32 +195,53 @@ def _update_score_pallas(score, leaf_id, vals, interpret=False):
     return out.reshape(-1)[:n]
 
 
-def update_score_from_partition(score, leaf_id, leaf_value, scale,
-                                engine: str = "gather"):
-    """Train-side score update via the learner's final partition
-    (score_updater.hpp:91-99): score += clip(scale * leaf_value)[leaf_id].
-
-    engine='pallas' (TPU): the compare-select kernel above — bit-equal
-    results, measured faster at large N; anything else: the XLA gather.
+def pallas_score_update_runs(engine: str, num_leaves: int, score_dtype,
+                             backend=None) -> bool:
+    """True where the compare-select kernel above takes the train-side
+    score update: asked for, a TPU, at most 512 leaf slots, an f32 score.
     The kernel's work is O(L) per row (one unrolled select per leaf
     slot), so large-leaf configs fall back to the gather, whose cost is
     L-independent — 512 keeps the kernel comfortably ahead of the
     measured ~8-cycle/row gather while bounding trace/compile size.
     f32-only: with tpu_use_dp=true the score/leaf values are f64 and the
     kernel's f32 table cast would break the bit-equality claim (and f64
-    VMEM blocks don't lower on TPU) — those configs use the gather.
+    VMEM blocks don't lower on TPU) — those configs use the gather."""
+    return (engine == "pallas"
+            and (backend or jax.default_backend()) == "tpu"
+            and num_leaves <= 512 and score_dtype == jnp.float32)
+
+
+def score_update_traced(score, leaf_id, leaf_value, scale,
+                        engine: str = "gather"):
+    """score += clip(scale * leaf_value)[leaf_id] on ONE device, free of
+    dispatch on concrete arrays, so the fused iteration
+    (ops/fused_iter.py) inlines the same update the staged chain
+    dispatches: the kernel where `pallas_score_update_runs`, else the
+    gather form (ops/partition.py), bit-equal to each other."""
+    if pallas_score_update_runs(engine, leaf_value.shape[0], score.dtype):
+        vals = jnp.clip(leaf_value * scale, -kMaxTreeOutput,
+                        kMaxTreeOutput)
+        return _update_score_pallas(score, leaf_id, vals)
+    return score_update_impl(score, leaf_id, leaf_value, scale)
+
+
+def update_score_from_partition(score, leaf_id, leaf_value, scale,
+                                engine: str = "gather"):
+    """Train-side score update via the learner's final partition
+    (score_updater.hpp:91-99): score += clip(scale * leaf_value)[leaf_id].
+
+    engine='pallas' (TPU): the compare-select kernel above — bit-equal
+    results, measured faster at large N; anything else: the XLA gather
+    (`pallas_score_update_runs` says which).
     One device only: outside shard_map a Mosaic kernel cannot be
     partitioned automatically ("Mosaic kernels cannot be automatically
     partitioned" at lowering), so the mesh learners' row-sharded
     leaf_id / score take the gather, which XLA partitions by itself.
     """
-    if (engine == "pallas" and jax.default_backend() == "tpu"
-            and leaf_value.shape[0] <= 512
-            and score.dtype == jnp.float32
+    if (pallas_score_update_runs(engine, leaf_value.shape[0], score.dtype)
             and len(score.devices() | leaf_id.devices()) == 1):
-        vals = jnp.clip(leaf_value * scale, -kMaxTreeOutput,
-                        kMaxTreeOutput)
-        return _update_score_pallas(score, leaf_id, vals)
+        return score_update_traced(score, leaf_id, leaf_value, scale,
+                                   engine)
     return _update_score_gather(score, leaf_id, leaf_value, scale)
 
 

@@ -42,7 +42,8 @@ from jax import lax
 
 from ..obs.timers import COUNTERS
 from .grow import TreeArrays, feature_hist_view, pvary_for, vary_like
-from .histogram import leaf_histogram_onehot, leaf_histogram_scatter
+from .histogram import (chunk_rows, leaf_histogram_onehot,
+                        leaf_histogram_scatter)
 from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
                            LEFT_COUNT, LEFT_OUTPUT, LEFT_SUM_G, LEFT_SUM_H,
                            RIGHT_COUNT, RIGHT_OUTPUT, RIGHT_SUM_G,
@@ -55,6 +56,11 @@ from .split_finder import (DEFAULT_BIN_FOR_ZERO, FEATURE, GAIN, IS_CAT,
 # one-line change.  Lives here (not pallas_wave.py) so CPU-only installs
 # never import jax.experimental.pallas just to validate a config.
 WAVE_ONLY_MODES = ("pallas_t", "pallas_ct")
+
+# float32 holds every whole number below this and, above it, only the
+# even ones: the histograms' count channel, the split search's sums over
+# the bins and a split's (LEFT_COUNT, RIGHT_COUNT) are all float32
+F32_WHOLE = 1 << 24
 
 # the grow program's phases, by the names obs/timers.py SCOPES declares:
 # the step program's HLO instructions are read back by them
@@ -260,10 +266,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
     def grow(X, grad, hess, row_mult, feature_mask, meta, bundle, Xt=None):
         n = grad.shape[0]       # X may be a SparseDeviceStore pytree
         if sparse_mode:
-            Fc = Fdev = X.fill.shape[0]
+            Fc = X.fill.shape[0]
         else:
             Fc = packed_cols or X.shape[1]    # LOGICAL group columns
-            Fdev = X.shape[1]                 # stored (packed: half)
         if packed_cols:
             from .pack import unpack4
             unpack = lambda xc: unpack4(xc, Fc)  # noqa: E731
@@ -284,8 +289,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         nch = (n + pad) // c
         if not sparse_mode:
             with scope("wave_partition"):
+                # the chunk loops below take windows of Xp itself
+                # (histogram.chunk_rows), never a (nch, c, F) reshape
                 Xp = jnp.pad(X, ((0, pad), (0, 0))) if pad else X
-                xb = Xp.reshape(nch, c, Fdev)
         # ---- the row slab: a wave keeps the histograms of its smaller
         # children only, and by count those hold at most half the rows
         # (0.28 N on average over a 255-leaf tree).  The partition has
@@ -448,16 +454,15 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         logical_cols=packed_cols, hilo=hist_hilo,
                         interpret=pallas_interpret) + (no_rows,)
             with scope("wave_partition"):
-                lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
-                    else leaf_id.reshape(nch, c)
+                lb = jnp.pad(leaf_id, (0, pad)) if pad else leaf_id
                 wpad = jnp.pad(w3, ((0, pad), (0, 0))) if pad else w3
-                wb3 = wpad.reshape(nch, c, 3)
                 l_iota = jnp.arange(L, dtype=jnp.int32)
                 f_iota = jnp.arange(Fc, dtype=jnp.int32)
 
-            def step(acc, args):
-                xc, lc, wc = args                   # (C,Fdev) (C,) (C,3)
-                with scope("wave_partition"):
+            def step(i, carry):
+                acc, lids = carry       # chunk i's leaf ids move in place
+                with scope("wave_partition"):   # (C,F) (C,) (C,3)
+                    xc, lc, wc = chunk_rows((Xp, lids, wpad), i, c)
                     xc = unpack(xc)                 # (C, Fc) logical bins
                     if lookup == "compact":
                         # <=1 match per row, so the sum is exact and XLA
@@ -493,19 +498,21 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         acc = acc + _slot_hist(
                             oh.reshape(c, Fc * hist_bins), match, wc, W,
                             hist_dtype, exact_order)
-                return acc, lc2
+                with scope("wave_partition"):
+                    lids = lax.dynamic_update_slice_in_dim(lids, lc2, i * c,
+                                                           axis=0)
+                return acc, lids
 
             acc_shape = ((Fc * hist_bins, 3 * W) if not use_pallas_hist
                          else (1, 1))
             init = jnp.zeros(acc_shape, dtype=hist_dtype)
             if nch == 1:
-                flat, lid2 = step(init, (xb[0], lb[0], wb3[0]))
-                new_leaf_id = lid2[:n]
+                flat, lid2 = step(0, (init, lb))
             else:
                 if not use_pallas_hist:
-                    init = vary_like(init, xb, lb, wb3)
-                flat, lid2 = lax.scan(step, init, (xb, lb, wb3))
-                new_leaf_id = lid2.reshape(-1)[:n]
+                    init = vary_like(init, Xp, lb, wpad)
+                flat, lid2 = lax.fori_loop(0, nch, step, (init, lb))
+            new_leaf_id = lid2[:n] if pad else lid2
             visited = no_rows
             if use_pallas_hist:
                 cid = jnp.where(valid, small_id, -1)
@@ -602,29 +609,26 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             no-cache larger-child pass."""
             if use_pallas_hist:
                 return pallas_hist(leaf_id, jnp.where(valid, ids, -1))
-            lb = jnp.pad(leaf_id, (0, pad)).reshape(nch, c) if pad \
-                else leaf_id.reshape(nch, c)
+            lb = jnp.pad(leaf_id, (0, pad)) if pad else leaf_id
             wpad = jnp.pad(w3, ((0, pad), (0, 0))) if pad else w3
-            wb3 = wpad.reshape(nch, c, 3)
 
-            def step(acc, args):
-                xc, lc, wc = args
+            def step(i, acc):
+                xc, lc, wc = chunk_rows((Xp, lb, wpad), i, c)
                 xc = unpack(xc)
                 match = ((lc[:, None] == ids[None, :])
                          & valid[None, :]).astype(hist_dtype)
                 oh = jax.nn.one_hot(xc.astype(jnp.int32), hist_bins,
                                     dtype=oh_dtype)
-                acc = acc + _slot_hist(
+                return acc + _slot_hist(
                     oh.reshape(c, Fc * hist_bins), match, wc, W,
                     hist_dtype, exact_order)
-                return acc, None
 
             init = jnp.zeros((Fc * hist_bins, 3 * W), dtype=hist_dtype)
             if nch == 1:
-                flat, _ = step(init, (xb[0], lb[0], wb3[0]))
+                flat = step(0, init)
             else:
-                flat, _ = lax.scan(step, vary_like(init, xb, lb, wb3),
-                                   (xb, lb, wb3))
+                flat = lax.fori_loop(0, nch, step,
+                                     vary_like(init, Xp, lb, wpad))
             return flat.reshape(Fc, hist_bins, W, 3).transpose(2, 0, 1, 3)
 
         @scope("split_search")
@@ -950,6 +954,29 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         carry = (jnp.asarray(0, jnp.int32), jnp.asarray(False), leaf_id,
                  hists, bests, sums, tree)
         carry = lax.while_loop(cond, body, carry)
-        return carry[-1], carry[2]
+        tree, leaf_id = carry[-1], carry[2]
+        if cache_hists and not sparse_mode:
+            # a split's (LEFT_COUNT, RIGHT_COUNT) are float32 sums: a node
+            # of 2^24 rows or more has a rounded count, and `rest = total
+            # - accumulated` (ops/split_finder.py) hands the row or two it
+            # is off down the chain of larger children to one leaf (read
+            # on the chip at 41,943,040 rows, PR 33: 2-6 rows a run over
+            # three trees).  A leaf's cached histogram holds its count
+            # bin by bin, each exact while the root's fullest bin of that
+            # column stays under 2^24: a kernel's sum of ones, or a
+            # parent's less a smaller child's.  So the leaves are counted
+            # from it, in int32, over the column whose fullest bin is
+            # emptiest; below 2^24 rows the float counts are the same
+            with scope("tree_commit"):
+                fullest = jnp.max(hist0[:, :, 2], axis=1)         # (Fh,)
+                col = jnp.argmin(fullest)
+                counts = jnp.sum(
+                    jnp.take(carry[3][..., 2], col, axis=1).astype(jnp.int32),
+                    axis=1)                                       # (L,)
+                tree = tree._replace(leaf_count=jnp.where(
+                    (fullest[col] < F32_WHOLE)
+                    & (jnp.arange(L) < tree.num_leaves),
+                    counts, tree.leaf_count))
+        return tree, leaf_id
 
     return grow

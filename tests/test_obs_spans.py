@@ -599,12 +599,16 @@ def test_counters_agree_with_the_grown_tree(fused):
         assert c["hist_rows"] < c["rows_visited"]
 
 
-def test_without_the_slab_every_wave_visits_every_row():
+@pytest.mark.parametrize("engine", [
+    {"tpu_pallas_interpret": False},
+    {"tpu_histogram_mode": "pallas_ct"}], ids=["xla", "pallas_ct"])
+def test_without_the_slab_every_wave_visits_every_row(engine):
     """The XLA engine (no Pallas kernel on the CPU without the
-    interpreter): no slab, so the record's `kernel_rows` is one full
-    pass a wave, added on the host."""
+    interpreter) and the fused partition-and-histogram kernel (the
+    narrow cell's, interpreted): no slab, so the record's `kernel_rows`
+    is one full pass a wave, added on the host."""
     X, y = _xy(3000, 10, 0)
-    params = dict(WAVE, tpu_pallas_interpret=False)
+    params = dict(WAVE, **engine)
     bst = lgb.train(params, lgb.Dataset(X, label=y, params=params),
                     num_boost_round=2)
     rows = 3000 + (-3000) % 1024

@@ -60,6 +60,10 @@ PLANS = [
      None, None, "tpu", "data", T,
      ("pallas_t", "wave", "batched", 32, T, "compact", 0, "",
       T, 16384, F, T, T, T)),
+    ("higgs_28-tpu", "higgs_28",
+     None, None, "tpu", None, F,
+     ("pallas_ct", "wave", "batched", 32, F, "compact", 0, "",
+      T, 16384, F, T, T, F)),
     ("epsilon_2000-cpu", "epsilon_2000",
      None, None, "cpu", None, F,
      ("scatter", "exact", "batched", 1, T, "onehot", 0, "",
