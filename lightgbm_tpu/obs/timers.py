@@ -223,7 +223,10 @@ SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_compact",
 UNSCOPED = "unscoped"
 # the grow loop's counter vector (ops/wave.py), in order.  On the device
 # `kernel_rows` holds what the row-slab launches visited (`compacted`
-# waves; under a mesh every shard's launches, summed); a tree's record
+# waves; under a mesh every shard's launches, summed) and
+# `slab_rows_moved` the rows the slabs' moves gathered for them, by
+# chunks up to the last live row (ops/wave.py move_rows: at most a chunk
+# a slab over `kernel_rows`); a tree's record
 # in the ring (models/gbdt.py) adds the other waves' `rows` each and
 # ``rows_visited = rows + kernel_rows``, as host integers: waves x rows
 # passes int32 at real sizes.  `allreduce_words`
@@ -231,7 +234,7 @@ UNSCOPED = "unscoped"
 # mesh hands to `psum` (0 on one device); the record turns them into
 # ``allreduce_bytes`` and adds ``shards``, the mesh's devices
 COUNTERS = ("waves", "slots", "attempted", "committed", "hist_rows", "rows",
-            "kernel_rows", "compacted", "allreduce_words")
+            "kernel_rows", "slab_rows_moved", "compacted", "allreduce_words")
 # jax.monitoring durations that become child spans of the open span
 _JAX_DURATIONS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",

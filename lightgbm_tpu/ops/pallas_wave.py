@@ -379,6 +379,17 @@ def slab_plan(n, fc, num_bins, k, packed=False, row_tile=8192):
     return -(-half // c) * c, c
 
 
+def slab_chunk(cap, c):
+    """Rows a chunk of the slab's move gathers (ops/wave.py move_rows): a
+    whole number of the launch's row tiles c, about cap / 32.  The move
+    ends at the chunk that holds the last live row, so it moves up to one
+    chunk more than the kernel reads.  A chunk costs by its bytes, not by
+    being one (v5e, PERF.md section 6, PR 34: counts from 8 to 64 read
+    alike), so the count only bounds that excess.  Up to 47 tiles: a
+    chunk a tile."""
+    return c * max(1, round(cap / c / 32))
+
+
 @functools.partial(jax.jit, static_argnames=("num_bins", "row_tile",
                                              "interpret", "logical_cols",
                                              "hilo"))

@@ -39,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..obs.timers import COUNTERS
 from .grow import TreeArrays, feature_hist_view, pvary_for, vary_like
@@ -101,6 +102,32 @@ def _slot_hist(ohf, match, wc, W, hist_dtype, exact_order):
     wmat = (match[:, :, None] * wc[:, None, :]).reshape(c, 3 * W)
     return jnp.einsum("cq,cw->qw", ohf, wmat,
                       preferred_element_type=hist_dtype)
+
+
+def move_rows(X, keys, live, g, buf):
+    """The row slab's move -> (`buf` with its first chunks written, the
+    rows they hold).
+
+    Chunk i is rows `keys[i*g : (i+1)*g]` of the row-major X, gathered
+    and transposed into columns i*g.. of the (Fdev, cap) `buf`.  Only the
+    chunks that hold one of the first `live` keys run (a traced trip
+    count), so the move follows the rows the kernel will read, not the
+    slab's capacity; what `buf` held past them stays, and is never read:
+    the launch stops at the last tile with a live row, and g is a whole
+    number of its tiles (ops/pallas_wave.py slab_chunk).  Where g does
+    not divide cap the last chunk starts at cap - g and moves some rows
+    twice, to the same place.  Keys past the table (fill rows, weight 0)
+    take its last row."""
+    cap = keys.shape[0]
+
+    def chunk(i, buf):
+        at = jnp.minimum(i * g, cap - g)
+        rows = jnp.take(X, lax.dynamic_slice(keys, (at,), (g,)), axis=0,
+                        mode="clip")
+        return lax.dynamic_update_slice(buf, jnp.transpose(rows), (0, at))
+
+    trips = (jnp.clip(live, 0, cap) + (g - 1)) // g
+    return lax.fori_loop(0, trips, chunk, buf), trips * g
 
 
 def pallas_wave_active(hist_mode, hist_dtype=jnp.float32, backend=None):
@@ -302,11 +329,12 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         # leaves being split, ordered_sparse_bin.hpp:26-209) with ONE
         # static shape.  cap is the slab launch's own tile multiple, so
         # that launch pads nothing.
-        slab_cap = slab_tile = 0
+        slab_cap = slab_tile = slab_g = 0
         if compact and not sparse_mode:
-            from .pallas_wave import slab_plan
+            from .pallas_wave import slab_chunk, slab_plan
             slab_cap, slab_tile = slab_plan(n, Fc, hist_bins, W,
                                             packed=bool(packed_cols))
+            slab_g = slab_chunk(slab_cap, slab_tile)
             if slab_cap >= n:          # a single row: nothing to skip
                 slab_cap = 0
         # transposed matrix for the v2 kernel (MXU-native dot orientation):
@@ -397,7 +425,9 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 new_lid = route_rows(r, colv, lid)
             return new_lid, sparse_child_hists(new_lid, small_id, valid)
 
-        no_rows = jnp.asarray(0, jnp.int32)  # a wave that ran no slab
+        # (rows the slab launches visited, rows the move gathered): a
+        # wave that ran no slab
+        no_rows = jnp.zeros(2, jnp.int32)
 
         @scope("wave_histogram")
         def pallas_hist(lid, cid):
@@ -441,7 +471,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
             kernel — a single read of Xt per wave.
 
             Returns (new leaf ids, (W, Fc, B, 3) histograms, the rows the
-            slabs' launches visited: this shard's own, like the sums).
+            slabs' launches visited and their moves gathered: this shard's
+            own, like the sums).
             """
             if use_pallas_hist and pallas_fused:
                 from .pallas_wave import wave_partition_hist_pallas_ct
@@ -529,10 +560,11 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
 
         def slab_hist(leaf_id, cid):
             """Histograms of the children `cid` from slabs of their rows
-            -> (hist, rows the launches visited).
+            -> (hist, (rows the launches visited, rows the moves gathered)).
 
             One sort puts the children's rows first, in row order; a
-            slab is the next `slab_cap` of them, gathered from X.  With
+            slab is the next `slab_cap` of them, gathered from X by
+            chunks up to the last live one (move_rows).  With
             all weights 1, on one device, one slab holds them all (a
             smaller child by count has at most half its parent's rows).
             The count is WEIGHTED and the slab holds ROWS, so under
@@ -574,7 +606,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                      w3[:, 0], w3[:, 1], w3[:, 2]), num_keys=1)]
 
             def slab(j, acc):
-                hist, visited = acc
+                hist, rows_seen = acc
                 with scope("wave_compact"):
                     key, lid_c, *w_c = (
                         lax.dynamic_slice(x, (j * slab_cap,), (slab_cap,))
@@ -583,10 +615,19 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                     live = jnp.arange(slab_cap, dtype=jnp.int32) < left
                     lid_c = jnp.where(live, lid_c, -2)
                     w3_c = jnp.where(live[:, None], jnp.stack(w_c, -1), 0.0)
-                    # fill rows' bins may be any row's (their weight is
-                    # 0): clip, and spare the fill's select over the slab
-                    xt_c = jnp.transpose(jnp.take(X, key, axis=0,
-                                                  mode="clip"))
+                    # the buffer starts out as the allocator left it:
+                    # fill rows' bins may be anything (their weight is
+                    # 0, and past the last live tile nothing is read),
+                    # and zeros cost 2.3 ms a wave at 2,000 columns.  Its
+                    # layout is pinned to the kernel's: left free, XLA
+                    # lays it out column-major, which spares the chunks
+                    # their transposes and transposes all slab_cap rows
+                    # after the loop instead
+                    buf = with_layout_constraint(
+                        lax.empty((X.shape[1], slab_cap), X.dtype),
+                        Layout(major_to_minor=(0, 1)))
+                    xt_c, moved = move_rows(X, key, left, slab_g,
+                                            vary_like(buf, leaf_id))
                 with scope("wave_histogram"):
                     hist = hist + wave_histogram_pallas_t(
                         xt_c, lid_c, w3_c, cid, hist_bins,
@@ -594,7 +635,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         interpret=pallas_interpret, n_active=left)
                 tiles = (jnp.minimum(left, slab_cap)
                          + (slab_tile - 1)) // slab_tile
-                return hist, visited + tiles * slab_tile
+                return hist, rows_seen + jnp.stack([tiles * slab_tile,
+                                                    moved])
 
             with scope("wave_histogram"):
                 zero = jnp.zeros((W, Fc, hist_bins, 3), hist_dtype)
@@ -683,13 +725,14 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
         sums = jnp.zeros((L, 3), hist_dtype).at[0].set(root_sums)
         # what the loop counts (obs/timers.py COUNTERS): each wave adds
         # [1, W, k, kc, rows of the committed smaller children, 0, rows
-        # its slab launches visited, 1 if it ran any, elements it handed
-        # to the all-reduce]; `rows` is the rows every full pass visits.
+        # its slab launches visited, rows their moves gathered, 1 if it
+        # ran any, elements it handed to the all-reduce]; `rows` is the
+        # rows every full pass visits.
         # Under a mesh the record is the mesh's: every shard's n rows
         # (the child counts already are global, they come from the summed
-        # histograms), the rows ALL shards' slab launches visited (one
-        # word more through the wave's all-reduce), and the elements ONE
-        # shard hands over, the root's to start with
+        # histograms), the rows ALL shards' slab launches visited and
+        # moved (two words more through the wave's all-reduce), and the
+        # elements ONE shard hands over, the root's to start with
         shards = 1 if psum_axis is None else lax.psum(1, psum_axis)
         counters = jnp.zeros(len(COUNTERS), jnp.int32).at[
             COUNTERS.index("rows")].set(n * shards).at[
@@ -945,7 +988,7 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.sum(jnp.where(commit, jnp.minimum(
                             info[:, LEFT_COUNT],
                             info[:, RIGHT_COUNT]).astype(jnp.int32), 0)),
-                        jnp.asarray(0, jnp.int32), visited,
+                        jnp.asarray(0, jnp.int32), visited[0], visited[1],
                         jnp.asarray(int(bool(slab_cap)), jnp.int32),
                         jnp.asarray(sum(sent), jnp.int32)]),
                 )

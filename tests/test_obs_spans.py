@@ -493,6 +493,50 @@ def test_early_stop_kernel_compiles_for_tpu(topo):
     assert " pad(" not in text         # cap is the launch's own tile multiple
 
 
+def test_grow_program_holds_one_instance_of_the_slab_kernel(monkeypatch):
+    """The wave grower as `auto` builds it for a wide table on the chip
+    (pallas_t, W=32, the row slab, cached histograms), lowered for the
+    TPU: ONE Mosaic custom call, `wave_histogram_pallas_t`, called from
+    ONE place.  The move in front of it runs by chunks with a traced trip
+    count, and nothing chooses between two launches: a ladder of slab
+    sizes (`lax.cond` arms, each with the kernel's 38.5 MB of device code;
+    deleted in PR 27) or a second form of the move kept beside the first
+    would show here as a second instance.  The record the loop fills
+    names what the move gathered."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import wave
+    from lightgbm_tpu.ops.learner import build_split_params
+    from lightgbm_tpu.ops.split_finder import FeatureMeta
+    from lightgbm_tpu.utils.config import Config
+
+    assert "slab_rows_moved" in timers.COUNTERS
+    assert timers.COUNTERS.index("slab_rows_moved") \
+        == timers.COUNTERS.index("kernel_rows") + 1
+    wave.make_wave_core.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, f = 131_072, 64
+    meta = FeatureMeta(num_bin=jnp.full(f, 63, jnp.int32),
+                       default_bin=jnp.zeros(f, jnp.int32),
+                       is_categorical=jnp.zeros(f, bool))
+    grow = wave.make_wave_grow_fn(
+        255, 63, meta, build_split_params(Config({"verbose": -1})), -1,
+        wave_width=32, hist_mode="pallas_t", with_xt=True)
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((n, f), jnp.uint8), ((n,), jnp.float32), ((n,), jnp.float32),
+        ((n,), jnp.float32), ((f,), jnp.bool_), ((f, n), jnp.uint8))]
+    try:
+        text = jax.jit(grow).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+    finally:
+        wave.make_wave_core.cache_clear()
+    assert text.count("@tpu_custom_call") == 1
+    assert re.findall(r'kernel_name = "(\w+)"', text) \
+        == ["wave_histogram_pallas_t"]
+    assert len(re.findall(r"call @wave_histogram_pallas_t", text)) == 1
+
+
 def test_split_search_reads_the_wave_block_once_when_compiled_for_tpu(topo):
     """The search of a wave's 64 children at Epsilon's width, compiled
     for a described v5e: the running sums over the bins on the MXU and

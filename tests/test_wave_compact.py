@@ -33,7 +33,8 @@ from lightgbm_tpu.io.dataset import TrainingData
 from lightgbm_tpu.obs.timers import COUNTERS
 from lightgbm_tpu.ops.grow import TreeArrays
 from lightgbm_tpu.ops.learner import SerialTreeLearner, build_split_params
-from lightgbm_tpu.ops.pallas_wave import slab_plan, wave_histogram_pallas_t
+from lightgbm_tpu.ops.pallas_wave import (slab_chunk, slab_plan,
+                                          wave_histogram_pallas_t)
 from lightgbm_tpu.ops.split_finder import FeatureMeta
 from lightgbm_tpu.ops.wave import make_wave_core, make_wave_grow_fn
 from lightgbm_tpu.utils.config import Config
@@ -319,8 +320,9 @@ def test_mesh_slab_matches_the_mesh_full_pass(wave_width):
     assert c["rows"] == NM == off["rows"]
     # a shard's slab launch stops at its one tile: under a full pass's
     assert 0 < c["kernel_rows"] < c["waves"] * NM and not off["kernel_rows"]
-    # one word more a wave through the all-reduce: the rows visited
-    assert c["allreduce_words"] == off["allreduce_words"] + c["waves"]
+    # two words more a wave through the all-reduce: the rows the launches
+    # visited and the rows the moves gathered
+    assert c["allreduce_words"] == off["allreduce_words"] + 2 * c["waves"]
 
 
 def test_mesh_slab_grows_the_serial_slabs_tree():
@@ -393,8 +395,8 @@ def test_mesh_counters_against_a_hand_count_on_a_three_wave_tree(
     the mesh's: `kernel_rows` the sum over shards of what their slab
     launches visited (from each shard's own rows of each wave's smaller
     children), `compacted` 1 a wave, `rows_visited = rows +
-    kernel_rows`, and one word a wave beside the histogram block in
-    `allreduce_bytes`."""
+    kernel_rows`, and two words a wave beside the histogram block in
+    `allreduce_bytes` (the rows visited and the rows moved)."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.obs import timers
     from lightgbm_tpu.parallel import mesh as mesh_mod
@@ -445,4 +447,200 @@ def test_mesh_counters_against_a_hand_count_on_a_three_wave_tree(
     assert rec["rows_visited"] == rec["rows"] + kernel_rows
     assert rec["hist_rows"] <= kernel_rows < 3 * NM
     fb3 = F * lrn.num_bins * 3
-    assert rec["allreduce_bytes"] == 4 * (3 + fb3 + 3 * (4 * fb3 + 1))
+    assert rec["allreduce_bytes"] == 4 * (3 + fb3 + 3 * (4 * fb3 + 2))
+
+
+# --------------------------------------- the move by chunks (move_rows)
+
+CAP, TILE, G = 4096, 512, 1024      # a slab of 8 kernel tiles, 4 chunks
+
+
+def _single_take(X, keys, live, g, buf):
+    """The move as it was before the chunks: one take and one transpose
+    over the slab's whole capacity, whatever `live` is."""
+    del live, g, buf
+    xt = jnp.transpose(jnp.take(X, keys, axis=0, mode="clip"))
+    return xt, jnp.asarray(keys.shape[0], jnp.int32)
+
+
+def _slab_operands(live, n=10_000, seed=5):
+    """One slab as slab_hist hands it to the kernel: `live` rows of the
+    children in row order, then fill rows (keys past the table, leaf -2,
+    weight 0)."""
+    rng = np.random.default_rng(seed)
+    X = rng.integers(0, 63, size=(n, F)).astype(np.uint8)
+    active = min(live, CAP)
+    keys = np.concatenate([
+        np.sort(rng.choice(n, size=active, replace=False)),
+        n + np.arange(CAP - active)]).astype(np.int32)
+    lid = np.where(np.arange(CAP) < active,
+                   rng.integers(0, 6, size=CAP), -2).astype(np.int32)
+    w3 = np.where(np.arange(CAP)[:, None] < active,
+                  rng.normal(size=(CAP, 3)), 0.0).astype(np.float32)
+    return (jnp.asarray(X), jnp.asarray(keys), jnp.asarray(lid),
+            jnp.asarray(w3), jnp.asarray([1, 3, -1, 5], jnp.int32))
+
+
+def _slab_launch(xt, lid, w3, cid, live):
+    return wave_histogram_pallas_t(xt, lid, w3, cid, 63, interpret=True,
+                                   row_tile=TILE, n_active=jnp.asarray(live))
+
+
+def _trees_with(monkeypatch, move, **kw):
+    from lightgbm_tpu.ops import wave
+    monkeypatch.setattr(wave, "move_rows", move)
+    make_wave_core.cache_clear()
+    try:
+        return _run(True, 63, 4, n=70_000, **kw)
+    finally:
+        make_wave_core.cache_clear()
+
+
+@pytest.mark.parametrize("case", [0, 1, G, G + 1, CAP, CAP + 700,
+                                  "tree", "second_slab"])
+def test_chunked_move_equals_the_single_take(case, monkeypatch):
+    """The slab moved by chunks up to the last live row against the one
+    take over its whole capacity.  A number: that many live rows in a
+    slab of four chunks (none, one, a whole chunk, one row into the next,
+    a full slab, more than a slab holds): the chunks written hold the
+    single take's rows, the rest of the buffer is untouched, and the
+    launch's sums are equal to the bit.  "tree": 63 leaves on 70,000 rows
+    (a slab of 40,960 rows, five chunks of one tile) grown either way;
+    "second_slab": the same under bagging weights that put most rows in
+    the smaller child, so that a second slab follows with trips of its
+    own.  Equal trees, to the bit."""
+    from lightgbm_tpu.ops.wave import move_rows
+    if isinstance(case, str):
+        kw = {}
+        if case == "second_slab":
+            col = np.asarray(_setup(63, n=70_000)[1].binned)[:, 1]
+            light = col <= np.quantile(col, 0.7)
+            y = ~light ^ (np.random.default_rng(7).random(70_000) < 0.1)
+            kw = dict(row_mult=np.where(light, 0.01, 1.0).astype(np.float32),
+                      grad=(0.5 - y).astype(np.float32))
+        cap, tile = slab_plan(70_000, F, 63, 4)
+        assert cap == 5 * tile == 5 * slab_chunk(cap, tile)
+        t_one, l_one = _trees_with(monkeypatch, _single_take, **kw)
+        t_chunks, l_chunks = _trees_with(monkeypatch, move_rows, **kw)
+        assert int(t_one.num_leaves) == 63
+        _same(t_one, t_chunks, STRUCTURE + FLOATS)
+        np.testing.assert_array_equal(np.asarray(l_one), np.asarray(l_chunks))
+        one, chunks = _counters(t_one), _counters(t_chunks)
+        assert chunks["kernel_rows"] == one["kernel_rows"]
+        assert one["slab_rows_moved"] > chunks["slab_rows_moved"] \
+            == chunks["kernel_rows"]                  # a chunk is a tile
+        if case == "second_slab":
+            assert one["slab_rows_moved"] > one["waves"] * cap
+        return
+    X, keys, lid, w3, cid = _slab_operands(case)
+    whole, _ = _single_take(X, keys, case, G, None)
+    marked = jnp.full((F, CAP), 200, jnp.uint8)
+    buf, moved = jax.jit(move_rows, static_argnums=3)(X, keys, case, G,
+                                                      marked)
+    written = -(-min(case, CAP) // G) * G
+    assert int(moved) == written
+    np.testing.assert_array_equal(np.asarray(buf)[:, :written],
+                                  np.asarray(whole)[:, :written])
+    assert (np.asarray(buf)[:, written:] == 200).all()
+    np.testing.assert_array_equal(
+        np.asarray(_slab_launch(whole, lid, w3, cid, case)),
+        np.asarray(_slab_launch(buf, lid, w3, cid, case)))
+
+
+@pytest.mark.parametrize("bin_", [255, 5])
+def test_what_the_buffer_holds_past_the_last_chunk_changes_no_sum(bin_):
+    """1,100 live rows in a slab of 4,096: two chunks of 1,024 are moved
+    and three kernel tiles of 512 read.  Past the chunks the buffer is
+    filled on purpose, with a bin no column has and with one every
+    column has: the launch stops before it, and inside the last tile the
+    rows from 1,100 on carry leaf -2 and weight 0, so no sum changes."""
+    from lightgbm_tpu.ops.wave import move_rows
+    live = 1100
+    X, keys, lid, w3, cid = _slab_operands(live)
+    hists = []
+    for fill in (0, bin_):
+        buf, moved = move_rows(X, keys, live, G,
+                               jnp.full((F, CAP), fill, jnp.uint8))
+        assert int(moved) == 2 * G
+        assert (np.asarray(buf)[:, 2 * G:] == fill).all()
+        hists.append(np.asarray(_slab_launch(buf, lid, w3, cid, live)))
+    np.testing.assert_array_equal(*hists)
+    assert np.abs(hists[0]).max() > 0
+
+
+def test_last_chunk_of_a_slab_that_is_no_whole_number_of_chunks():
+    """Epsilon's slab is 293 tiles and a chunk 9: the 33rd chunk starts
+    at cap - g and moves some rows twice, to the same place.  Here 7
+    tiles by chunks of 2: a full slab comes out as the single take's."""
+    from lightgbm_tpu.ops.wave import move_rows
+    rng = np.random.default_rng(2)
+    X = jnp.asarray(rng.integers(0, 63, size=(9000, F)).astype(np.uint8))
+    cap = 7 * TILE
+    keys = jnp.asarray(np.sort(rng.choice(9000, size=cap, replace=False))
+                       .astype(np.int32))
+    for live, chunks in ((cap, 4), (3 * G, 3), (3 * G + 1, 4)):
+        buf, moved = move_rows(X, keys, live, G,
+                               jnp.full((F, cap), 200, jnp.uint8))
+        assert int(moved) == chunks * G
+        upto = min(chunks * G, cap)
+        np.testing.assert_array_equal(
+            np.asarray(buf)[:, :upto], np.asarray(X)[np.asarray(keys)].T[
+                :, :upto])
+
+
+def test_slab_rows_moved_against_a_hand_count_on_a_three_wave_tree():
+    """The tree of test_counters_against_a_hand_count_on_a_three_wave_tree:
+    cap = tile = chunk = 3,000 rows, so each of the three waves moves one
+    chunk, where the single take moved the slab's whole capacity whatever
+    it held; without the slab nothing is moved."""
+    c = _counters(_run(True, 8, 4)[0])
+    assert slab_chunk(*slab_plan(N, F, 63, 4)) == 3000
+    assert c["waves"] == 3 == c["compacted"]
+    assert c["slab_rows_moved"] == 3 * 3000 == c["kernel_rows"]
+    assert c["hist_rows"] <= c["kernel_rows"] <= c["slab_rows_moved"] \
+        <= c["kernel_rows"] + c["compacted"] * 3000
+    assert _counters(_run(False, 8, 4)[0])["slab_rows_moved"] == 0
+    # 63 leaves on 20,000 rows: two tiles a slab, a chunk a tile
+    c = _counters(_run(True, 63, 4, n=20_000)[0])
+    assert slab_chunk(*slab_plan(20_000, F, 63, 4)) == 8192
+    assert c["kernel_rows"] == c["slab_rows_moved"] < c["waves"] * 16_384
+
+
+def test_mesh_slab_rows_moved_sums_the_shards_own_trips():
+    """Rows sorted by the root's split column (the stump of
+    test_sorted_rows_put_the_smaller_child_on_one_shard): a shard moves
+    two slabs of one chunk each, another runs no trip at all, and the
+    record holds the sum over the shards, handed over in the same
+    all-reduce operand as `kernel_rows`."""
+    stump, _ = _run(True, 2, 4, n=NM, shards=SHARDS)
+    col = np.asarray(_setup(2, n=NM)[1].binned)[:, int(stump.split_feature[0])]
+    t2, l2 = _run(True, 2, 4, n=NM, shards=SHARDS,
+                  order=np.argsort(col, kind="stable"))
+    small = int(np.argmin(np.bincount(np.asarray(l2), minlength=2)))
+    active = (np.asarray(l2) == small).reshape(SHARDS, -1).sum(axis=1)
+    assert active.max() == NM // SHARDS and active.min() == 0
+    trips = sorted(-(-int(a) // 1024) for a in active)
+    assert trips[0] == 0 and trips[-1] == 2       # each shard its own count
+    c, off = _counters(t2), _counters(_run(False, 2, 4, n=NM,
+                                           shards=SHARDS)[0])
+    assert slab_chunk(*slab_plan(NM // SHARDS, F, 63, 4)) == 1024
+    assert c["slab_rows_moved"] == sum(_slab_rows(int(a)) for a in active)
+    assert c["slab_rows_moved"] == c["kernel_rows"]
+    assert c["allreduce_words"] == off["allreduce_words"] + 2 * c["waves"]
+    assert off["slab_rows_moved"] == 0
+
+
+@pytest.mark.parametrize("cap, tile, chunks", [
+    (3000, 3000, 1), (1024, 1024, 1), (16_384, 8192, 2), (40_960, 8192, 5),
+    (600_064, 2048, 33), (1_250_944, 3712, 31)])
+def test_chunk_rule(cap, tile, chunks):
+    """A chunk is a whole number of the slab launch's tiles, about a
+    thirty-second of the slab: one chunk where the slab is one tile, a
+    chunk a tile up to 47 tiles, 28-36 chunks at the two wide cells'
+    shapes (Epsilon's 1,200,128 x 2,000 and Bosch's 2,500,608 x 968 as
+    the upload pads them)."""
+    g = slab_chunk(cap, tile)
+    assert g % tile == 0 and 0 < g <= cap
+    assert -(-cap // g) == chunks
+    if cap > 48 * tile:
+        assert 28 <= chunks <= 36
