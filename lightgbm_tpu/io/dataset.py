@@ -874,8 +874,10 @@ class TrainingData:
         self._build_feature_arrays()
         groups = h.get("bundle_groups")
         if groups is not None:
-            self.bundle = build_layout(groups, self.num_bin_arr,
-                                       self.default_bin_arr)
+            from ..obs import timers
+            with timers.span("bundle_layout", groups=len(groups)):
+                self.bundle = build_layout(groups, self.num_bin_arr,
+                                           self.default_bin_arr)
         self._binned_reader = reader
         self._comm = comm if (comm is not None and comm.size > 1) else None
         self.metadata = Metadata(self.num_data)

@@ -219,7 +219,12 @@ RING_SIZE = 4096
 # its op_name, or to UNSCOPED
 SCOPES = ("gradients", "root_histogram", "wave_partition", "wave_compact",
           "wave_histogram", "hist_allreduce", "split_search", "tree_commit",
-          "score_update")
+          "score_update", "bundle_view")
+# scopes opened INSIDE another declared scope: an instruction under one of
+# these belongs to it and not to the scope around it (`bundle_view`, the
+# EFB view of ops/grow.py feature_hist_view, sits inside `split_search`,
+# whose seconds therefore do not hold it)
+INNER_SCOPES = ("bundle_view",)
 UNSCOPED = "unscoped"
 # the grow loop's counter vector (ops/wave.py), in order.  On the device
 # `kernel_rows` holds what the row-slab launches visited (`compacted`
@@ -396,7 +401,13 @@ _HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
 def _scope_of(op_name):
-    for part in op_name.split("/"):
+    parts = op_name.split("/")
+    for part in parts:
+        # inside a vmap the name stack says ``vmap(bundle_view)``
+        inner = part.rsplit("(", 1)[-1].rstrip(")")
+        if inner in INNER_SCOPES:
+            return inner
+    for part in parts:
         if part in SCOPES:
             return part
     return None

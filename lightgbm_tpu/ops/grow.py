@@ -100,12 +100,15 @@ def feature_hist_view(ghist, sums, meta, bundle, has_bundle: bool,
             return ghist.at[fidx, meta.default_bin].set(
                 sums[None, :] - ghist.sum(axis=1))
         return ghist
-    flat = ghist.reshape(-1, 3)
-    v = flat[bundle.gather_idx] * bundle.valid_mask[..., None].astype(
-        ghist.dtype)
-    fidx = jnp.arange(v.shape[0])
-    v = v.at[fidx, meta.default_bin].set(sums[None, :] - v.sum(axis=1))
-    return v
+    # a scope of its own inside the caller's `split_search`
+    # (obs/timers.py INNER_SCOPES): what the bundle costs a search
+    with jax.named_scope("bundle_view"):
+        flat = ghist.reshape(-1, 3)
+        v = flat[bundle.gather_idx] * bundle.valid_mask[..., None].astype(
+            ghist.dtype)
+        fidx = jnp.arange(v.shape[0])
+        return v.at[fidx, meta.default_bin].set(
+            sums[None, :] - v.sum(axis=1))
 
 
 def pvary_for(x, axis: str):

@@ -25,7 +25,7 @@ from ..utils.config import Config
 from ..utils.random import Random
 from .grow import (BundleArrays, TreeArrays, default_row_capacities,
                    make_grow_fn)
-from .plan import resolve_plan
+from .plan import resolve_plan, store_bin_width
 from .sparse_mxu import ChunkedSparseStore, build_chunked_store
 from .sparse_store import (SparseDeviceStore, build_sparse_store,
                            column_fill_bins)
@@ -199,6 +199,17 @@ class SerialTreeLearner:
             psum_axis=psum_axis,
             dense_device_data=(device_data is not None and not isinstance(
                 device_data, (SparseDeviceStore, ChunkedSparseStore))))
+        if bundle is not None:
+            # what EFB made of the features, once a learner (inside the
+            # caller's `learner_build` span): the bins the groups hold
+            # against the one-hot width the plan's engine multiplies by
+            timers.count(
+                "bundle", bundle_groups=bundle.num_groups,
+                bundled_features=sum(
+                    len(g) for g in bundle.groups if len(g) > 1),
+                group_bins_used=int(bundle.num_group_bins.sum()),
+                group_bins_padded=bundle.num_groups * store_bin_width(
+                    self.plan, self.group_bins))
         self._upload(psum_axis, device_data, device_row_pad,
                      device_packed_cols, device_sparse_col_cap)
         self._build_grow(psum_axis)

@@ -36,8 +36,12 @@ from .wave import (WAVE_ONLY_MODES, _bin_pad, hist_block_bytes,
 WAVE_VMEM_GATE = 64 << 20
 
 # pallas_ct (partition fused into the histogram kernel) up to this
-# ncols * bin_pad on one device, pallas_t above.  Not measured by the
-# driver: every cell is far above it (968 x 64 = 61,952).
+# ncols * bin_pad on one device, pallas_t above.  The driver measures a
+# cell on each side: under it `higgs_28_train` (28 x 64 = 1,792, PR 33)
+# and ON it `expo_700_train` (10 EFB groups x a 256-bin pad = 2,560,
+# PR 35: pallas_ct; an eleventh group would take pallas_t and the slab);
+# far above it the wide cells (968 x 64 = 61,952: pallas_t).
+# tests/test_plan.py pins the plan at 9, 10 and 11 groups.
 CT_PROMOTION_BOUND = 2560
 
 # auto histogram-cache budget when histogram_pool_size is unset (-1): the
@@ -186,6 +190,16 @@ def prior_hist_hilo(growth: str, psum_axis: Optional[str],
     one-chip cells, `correct`; PR 28/29: hi/lo under the mesh, whose
     `loss_gap` limit one product would not meet (PERF.md section 7)."""
     return not (growth == "wave" and psum_axis is None and kernel_runs)
+
+
+def store_bin_width(plan: "Plan", nbins: int) -> int:
+    """The one-hot width a column of the device store is multiplied
+    against under `plan`: the Pallas wave kernels (compiled or through
+    the interpreter) pad the bins (`_bin_pad`), every other engine takes
+    the `nbins` it is given."""
+    pallas = plan.growth == "wave" and plan.hist_mode.startswith("pallas") \
+        and (plan.kernel_runs or plan.pallas_interpret)
+    return _bin_pad(nbins) if pallas else nbins
 
 
 def resolve_plan(config: Config, *, ncols: int, nbins: int,

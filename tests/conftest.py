@@ -45,7 +45,7 @@ _LONGEST_FIRST = (
     "test_wave_exact_order.py", "test_parallel.py",
     "test_python_guide_examples.py", "test_engine.py",
     "test_wave_compact.py", "test_pack.py", "test_obs_spans.py",
-    "test_compile_rows.py",
+    "test_compile_rows.py", "test_bundled_reference.py",
     "test_multiprocess.py", "test_graft_entry.py", "test_fused_iter.py",
     "test_chip_smoke.py", "test_perfbench_correct.py")
 

@@ -398,7 +398,63 @@ def test_fused_step_registers_every_scope_it_runs(binned_dir):
     tables = timers.device_scopes()
     assert list(tables) == ["jit_step"]
     found = set(tables["jit_step"].values())
-    assert found == set(timers.SCOPES) - {"hist_allreduce"}
+    assert found == set(timers.SCOPES) - {"hist_allreduce", "bundle_view"}
+    # no bundle in the dataset: no scope, no counter, no span of it
+    assert not [r for r in timers.snapshot()
+                if r["name"] in ("bundle", "bundle_layout")]
+
+
+@pytest.fixture(scope="module")
+def bundled_dir(tmp_path_factory):
+    """Twelve one-hot columns of one source column and two dense ones:
+    EFB packs the twelve into one group."""
+    rng = np.random.default_rng(1)
+    level = rng.integers(0, 12, 3000)
+    X = np.concatenate([np.eye(12)[level], rng.standard_normal((3000, 2))],
+                       axis=1).astype(np.float32)
+    y = ((level % 3 == 0) + 0.3 * X[:, 12]
+         + 0.1 * rng.standard_normal(3000) > 0.5).astype(np.float32)
+    path = str(tmp_path_factory.mktemp("bundled") / "d")
+    from lightgbm_tpu.io import binned_format
+    ds = lgb.Dataset(X, label=y, params=dict(WAVE)).construct()
+    assert ds._handle.bundle is not None
+    binned_format.save_training_data(ds._handle, path, shard_rows=1024)
+    return path
+
+
+def test_a_bundled_dataset_has_its_scope_span_and_counters(bundled_dir):
+    """`bundle_view` (the EFB view in front of every split search) is a
+    scope of its own INSIDE `split_search`, under a vmap, and taken out
+    of it; `from_binned` rebuilds the layout under a span; one `bundle`
+    record a learner says what EFB made of the features."""
+    from lightgbm_tpu.ops.plan import store_bin_width
+
+    timers._scopes.clear()
+    bst = _train_binned(bundled_dir, rounds=1)
+    table = timers.device_scopes()["jit_step"]
+    assert set(table.values()) == set(timers.SCOPES) - {"hist_allreduce"}
+    assert timers._scope_of(
+        "jit(step)/jit(grow)/while/body/split_search/split_search/"
+        "vmap(bundle_view)/gather") == "bundle_view"
+    assert timers._scope_of(
+        "jit(step)/jit(grow)/split_search/mul") == "split_search"
+    layout = bst._gbdt.train_data.bundle
+    (rec,) = [r for r in timers.snapshot()
+              if r["kind"] == "count" and r["name"] == "bundle"]
+    by_seq = {r["seq"]: r for r in _spans()}
+    assert by_seq[rec["cause"]]["name"] == "learner_build"
+    bundled = [g for g in layout.groups if len(g) > 1]
+    assert bundled and rec["fields"] == {
+        "bundle_groups": layout.num_groups,
+        "bundled_features": sum(len(g) for g in bundled),
+        "group_bins_used": int(layout.num_group_bins.sum()),
+        "group_bins_padded": layout.num_groups * store_bin_width(
+            bst._gbdt.learner.plan, int(layout.num_group_bins.max()))}
+    # the interpreted wave kernel pads the widest group's 255 bins to 256
+    assert rec["fields"]["group_bins_padded"] == layout.num_groups * 256
+    (span,) = _spans("bundle_layout")
+    assert by_seq[span["cause"]]["name"] == "dataset_open"
+    assert span["ids"]["groups"] == layout.num_groups
 
 
 def test_one_executable_a_signature_and_no_compile_after_the_first(

@@ -24,12 +24,17 @@ import pytest
 
 from lightgbm_tpu.ops import plan
 from lightgbm_tpu.ops.plan import (Plan, prior_hist_mode, resolve_plan,
-                                   resolve_wave_order, resolve_wave_width)
+                                   resolve_wave_order, resolve_wave_width,
+                                   store_bin_width)
 from lightgbm_tpu.utils.config import Config
 from lightgbm_tpu.utils.log import LightGBMError, Log
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T, F = True, False
+
+
+with open(os.path.join(REPO, "benchmark", "configs", "expo_700.json")) as _f:
+    EXPO = json.load(_f)["params"]     # the cell's recipe, as it is filed
 
 
 def _cfg(num_leaves, **kw):
@@ -164,6 +169,18 @@ PLANS = [
      136, 63, "tpu", None, F,
      ("onehot", "exact", "batched", 1, T, "onehot", 0, "",
       T, 16384, F, F, F, F)),
+    # `expo_700` (PR 35): the store is what EFB makes of the 700 one-hot
+    # columns, 10 groups of up to 256 bins, so 10 x a 256-bin pad sits ON
+    # `CT_PROMOTION_BOUND`: the side the cell runs on, and its neighbours
+    ("expo_700-10-groups-tpu", EXPO, 10, 256, "tpu", None, F,
+     ("pallas_ct", "wave", "batched", 32, F, "compact", 0, "",
+      T, 16384, F, T, T, F)),
+    ("expo_700-9-groups-tpu", EXPO, 9, 256, "tpu", None, F,
+     ("pallas_ct", "wave", "batched", 32, F, "compact", 0, "",
+      T, 16384, F, T, T, F)),
+    ("expo_700-11-groups-tpu", EXPO, 11, 256, "tpu", None, F,
+     ("pallas_t", "wave", "batched", 32, F, "compact", 0, "",
+      T, 16384, F, T, T, T)),
 ]
 
 
@@ -198,6 +215,22 @@ def test_resolve_plan_pins_the_whole_plan(params, ncols, nbins, backend,
     # runs: both one-chip cells (the mesh's wish is refused later, by
     # ops/fused_iter.py fused_supported)
     assert got.fused_wanted == got.kernel_runs
+
+
+@pytest.mark.parametrize("params,backend,width", [
+    (EXPO, "tpu", 256),                                 # pallas_ct: padded
+    (dict(EXPO, max_bin=63), "cpu", 200),               # the XLA engines
+    (dict(EXPO, tpu_growth="wave", tpu_histogram_mode="pallas_t",
+          tpu_pallas_interpret=True), "cpu", 256),      # the interpreter
+], ids=["tpu-kernel", "cpu-xla", "cpu-interpreter"])
+def test_store_bin_width_is_padded_where_a_wave_kernel_runs(params, backend,
+                                                            width):
+    """The `bundle` counter's `group_bins_padded` (ops/learner.py) is the
+    groups times this: `_bin_pad` only where the plan takes a Pallas wave
+    kernel, the bins as they are under every other engine."""
+    config, ncols, nbins = _case(params, 10, 200)
+    plan = _resolve(config, ncols, nbins, backend)
+    assert store_bin_width(plan, nbins) == width
 
 
 # every check of a key moved with its rule, word for word: the parent's
