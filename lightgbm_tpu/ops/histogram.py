@@ -103,9 +103,36 @@ def _scatter_accumulate(binned, w, num_bins: int, logical_cols: int = 0):
     return jnp.concatenate([lo, hi], axis=0)[:logical_cols]
 
 
+def _width_classes(col_pads, num_bins: int):
+    """A ragged store's columns by one-hot width -> ((width, columns),
+    ...), widest class last; a width never passes `num_bins`."""
+    classes = {}
+    for j, p in enumerate(col_pads):
+        classes.setdefault(min(p, num_bins), []).append(j)
+    return tuple(sorted(classes.items()))
+
+
+def _columns(xc, cols):
+    """Columns `cols` (ascending) of a chunk, as slices of their runs."""
+    runs = [[cols[0], cols[0] + 1]]
+    for j in cols[1:]:
+        if j == runs[-1][1]:
+            runs[-1][1] = j + 1
+        else:
+            runs.append([j, j + 1])
+    return jnp.concatenate([xc[:, a:b] for a, b in runs], axis=1)
+
+
 def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
-                       logical_cols: int = 0, hilo: bool = False):
+                       logical_cols: int = 0, hilo: bool = False,
+                       col_pads: tuple = ()):
     """(F, B, 3) via chunked one-hot contraction on the MXU.
+
+    col_pads: the columns' one-hot widths where the store is ragged
+    (ops/wave.py col_bin_pads; () = `num_bins` for every column): one
+    contraction a width class, over the class's columns of the chunk,
+    never of the whole matrix; the bins past a column's width come back
+    zero, as they are.
 
     logical_cols > 0: binned is 4-bit packed (ops/pack.py); chunks unpack
     in-scan so the full-width matrix never materializes in HBM.
@@ -144,17 +171,33 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
         if logical_cols:
             from .pack import unpack4
             xc = unpack4(xc, f)
-        onehot = jax.nn.one_hot(xc.astype(jnp.int32), num_bins,
-                                dtype=wc.dtype)          # (C, F, B)
-        return acc + jnp.einsum("cfb,cw->fbw", onehot, wc,
-                                preferred_element_type=wc.dtype)
+        xc = xc.astype(jnp.int32)
+        return tuple(
+            a + jnp.einsum(
+                "cfb,cw->fbw",
+                jax.nn.one_hot(_columns(xc, cols) if col_pads else xc,
+                               width, dtype=wc.dtype),   # (C, F, B)
+                wc, preferred_element_type=wc.dtype)
+            for a, (width, cols) in zip(acc, classes))
 
-    init = jnp.zeros((f, num_bins, k), dtype=w.dtype)
+    classes = (_width_classes(col_pads, num_bins) if col_pads
+               else ((num_bins, range(f)),))
+    init = tuple(jnp.zeros((len(cols), width, k), dtype=w.dtype)
+                 for width, cols in classes)
     if nchunks == 1:
-        hist = step(0, init)
+        hists = step(0, init)
     else:
         from .grow import vary_like
-        hist = lax.fori_loop(0, nchunks, step, vary_like(init, binned, w))
+        hists = lax.fori_loop(0, nchunks, step, vary_like(init, binned, w))
+    if col_pads:
+        # the classes' blocks side by side, then back in column order
+        order = [j for _, cols in classes for j in cols]
+        hist = jnp.concatenate(
+            [jnp.pad(h, ((0, 0), (0, num_bins - h.shape[1]), (0, 0)))
+             for h in hists])[jnp.asarray(sorted(range(f),
+                                                 key=order.__getitem__))]
+    else:
+        hist, = hists
     return hist[..., :3] + hist[..., 3:] if hilo else hist
 
 
@@ -188,10 +231,12 @@ def leaf_histogram_scatter(binned, grad, hess, leaf_id, leaf, row_mult,
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk",
-                                             "logical_cols", "hilo"))
+                                             "logical_cols", "hilo",
+                                             "col_pads"))
 def leaf_histogram_onehot(binned, grad, hess, leaf_id, leaf, row_mult,
                           num_bins: int, chunk: int = 16384,
-                          logical_cols: int = 0, hilo: bool = False):
+                          logical_cols: int = 0, hilo: bool = False,
+                          col_pads: tuple = ()):
     """(F, B, 3) histogram via chunked one-hot matmul on the MXU.
 
     For each row chunk: one_hot(bins) (C, F, B) contracted with weights
@@ -199,8 +244,11 @@ def leaf_histogram_onehot(binned, grad, hess, leaf_id, leaf, row_mult,
     one-hot tensor never exceeds chunk x F x B.
     """
     w = _weights(grad, hess, leaf_id, leaf, row_mult)  # (N, 3)
+    # a uniform store's call is the one it was: the benchmark's tests and
+    # faults wrap `_onehot_accumulate` by these six arguments
+    ragged = {"col_pads": col_pads} if col_pads else {}
     return _onehot_accumulate(binned, w, num_bins, chunk, logical_cols,
-                              hilo)
+                              hilo, **ragged)
 
 
 def leaf_histogram(binned, grad, hess, leaf_id, leaf, row_mult,

@@ -25,7 +25,7 @@ from ..utils.config import Config
 from ..utils.random import Random
 from .grow import (BundleArrays, TreeArrays, default_row_capacities,
                    make_grow_fn)
-from .plan import resolve_plan, store_bin_width
+from .plan import resolve_plan, store_bin_width, store_col_pads
 from .sparse_mxu import ChunkedSparseStore, build_chunked_store
 from .sparse_store import (SparseDeviceStore, build_sparse_store,
                            column_fill_bins)
@@ -187,28 +187,31 @@ class SerialTreeLearner:
         self.params = build_split_params(config)
         self.bundle_arrays, self.group_bins = build_bundle_arrays(train_data)
         bundle = train_data.bundle
+        bins_per_col = (bundle.num_group_bins if bundle is not None
+                        else train_data.num_bin_arr)
+        nbins = self.group_bins if bundle is not None else self.num_bins
         self.plan = resolve_plan(
             config,
             ncols=(len(bundle.num_group_bins) if bundle is not None
                    else max(train_data.num_features, 1)),
-            nbins=self.group_bins if bundle is not None else self.num_bins,
-            num_leaves=self.num_leaves,
-            bins_per_col=(bundle.num_group_bins if bundle is not None
-                          else train_data.num_bin_arr),
+            nbins=nbins, num_leaves=self.num_leaves,
+            bins_per_col=bins_per_col,
             backend=jax.default_backend(), dtype=self.dtype,
             psum_axis=psum_axis,
             dense_device_data=(device_data is not None and not isinstance(
                 device_data, (SparseDeviceStore, ChunkedSparseStore))))
+        self.col_pads = store_col_pads(self.plan, bins_per_col, nbins)
         if bundle is not None:
             # what EFB made of the features, once a learner (inside the
             # caller's `learner_build` span): the bins the groups hold
-            # against the one-hot width the plan's engine multiplies by
+            # against the one-hot widths the plan's engine multiplies by
             timers.count(
                 "bundle", bundle_groups=bundle.num_groups,
                 bundled_features=sum(
                     len(g) for g in bundle.groups if len(g) > 1),
                 group_bins_used=int(bundle.num_group_bins.sum()),
-                group_bins_padded=bundle.num_groups * store_bin_width(
+                group_bins_padded=sum(self.col_pads)
+                or bundle.num_groups * store_bin_width(
                     self.plan, self.group_bins))
         self._upload(psum_axis, device_data, device_row_pad,
                      device_packed_cols, device_sparse_col_cap)
@@ -339,7 +342,7 @@ class SerialTreeLearner:
                 self.plan.wave_chunk, self.packed_cols,
                 self.sparse_col_cap, self.wave_order == "exact",
                 self.wave_lookup, self.hist_hilo, True,
-                self.pallas_interpret)
+                self.pallas_interpret, self.col_pads)
             meta, bund = self.meta, self.bundle_arrays
             # the transposed kernel's (F, N) matrix: materialized ONCE per
             # booster (X never changes across trees), not per dispatch;

@@ -35,8 +35,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .grow import vma_struct
-from .wave import WAVE_ONLY_MODES, _bin_pad  # noqa: F401  (shared policy
-# lives in wave.py, which stays importable without jax.experimental.pallas)
+from .wave import (BIN_GRANULE, WAVE_ONLY_MODES,  # noqa: F401  (shared
+                   _bin_pad)   # policy lives in wave.py, which stays
+# importable without jax.experimental.pallas)
 
 
 # -- VMEM scheduling thresholds (the 18-30 MB band post-mortem) ----------
@@ -218,6 +219,59 @@ def _accum_hist(out_ref, xr, base, wh, wl, *, bp, fc, bsub, dims):
                 preferred_element_type=jnp.float32)
         rows = slice(s * bsub * fc, (s + 1) * bsub * fc)
         out_ref[rows, :] = out_ref[rows, :] + acc
+
+
+# most one-hot rows of one contraction of the ragged walk, as the
+# uniform loop's sub-blocks have (`_tile_plan`).  On the v5e a launch at
+# Expo's widths takes the same 117.4 ms with blocks of 224, 320 and 448
+# rows (PERF.md section 6, PR 36); far shorter blocks stream few rows
+# past each 128 x 128 weight tile and starve the MXU
+_RAGGED_BLOCK_ROWS = 512
+
+
+def _ragged_blocks(col_pads):
+    """The ragged walk of a store whose column j is `col_pads[j]` bins
+    wide (ops/wave.py col_bin_pads): its (column, first bin) segments of
+    `BIN_GRANULE` bins each, column by column, cut into blocks of equal
+    length and at most `_RAGGED_BLOCK_ROWS` one-hot rows."""
+    segs = [(j, b0) for j, p in enumerate(col_pads)
+            for b0 in range(0, p, BIN_GRANULE)]
+    nblocks = max(1, -(-len(segs) * BIN_GRANULE // _RAGGED_BLOCK_ROWS))
+    per = max(1, -(-len(segs) // nblocks))
+    return tuple(tuple(segs[i:i + per]) for i in range(0, len(segs), per))
+
+
+def _accum_hist_ragged(out_ref, xt, wh, wl, *, blocks):
+    """`_accum_hist` over the columns' own bins: xt (Fc, Cg) float32
+    bins, wh/wl (3K, Cg).  A block's one-hot holds `BIN_GRANULE` rows a
+    segment: the column's bins less the segment's first, against the bin
+    within the segment.  out_ref's rows are the segments in order, so a
+    column's bins lie together, at the offset the pads before it sum to.
+    """
+    cg = xt.shape[1]
+    # the bin within its segment, built anew for a shorter last block:
+    # Mosaic's compiler fails on a sublane slice of the longer one
+    within = {}
+    row0 = 0
+    for block in blocks:
+        rows = len(block) * BIN_GRANULE
+        if rows not in within:
+            within[rows] = (jax.lax.broadcasted_iota(
+                jnp.int32, (rows, cg), 0) % BIN_GRANULE).astype(jnp.float32)
+        rel = jnp.concatenate(
+            [jnp.broadcast_to(xt[j:j + 1, :] - jnp.float32(b0),
+                              (BIN_GRANULE, cg)) for j, b0 in block], axis=0)
+        oh = jnp.where(rel == within[rows], jnp.float32(1.0),
+                       jnp.float32(0.0)).astype(jnp.bfloat16)
+        acc = jax.lax.dot_general(
+            oh, wh, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (rows, 3K)
+        if wl is not None:
+            acc = acc + jnp.float32(1.0 / 256.0) * jax.lax.dot_general(
+                oh, wl, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        out_ref[row0:row0 + rows, :] = out_ref[row0:row0 + rows, :] + acc
+        row0 += rows
 
 
 def _wave_hist_kernel(x_ref, lid_ref, w3_ref, cid_ref, out_ref,
@@ -492,7 +546,7 @@ def wave_histogram_pallas_t(X_t, leaf_id, w3, child_id, num_bins: int,
 def _wave_fused_kernel_ct(xt_ref, lid_ref, w3_ref, cid_ref, tblt_ref,
                           psrc_ref, lid_out_ref, out_ref,
                           *, bp, fc, k, bsub, packed, bundled,
-                          hilo=True):
+                          hilo=True, blocks=()):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -543,6 +597,9 @@ def _wave_fused_kernel_ct(xt_ref, lid_ref, w3_ref, cid_ref, tblt_ref,
     wh, wl = _split_weights_t(new_lid, w3_ref, cid_ref, hilo)  # (3K, Cg)
 
     xt = xint.astype(jnp.float32)
+    if blocks:                  # a ragged store: each column its own bins
+        _accum_hist_ragged(out_ref, xt, wh, wl, blocks=blocks)
+        return
     xr = pltpu.repeat(xt, bsub, axis=0)              # (bsub*Fc, Cg)
     base = (jax.lax.broadcasted_iota(jnp.int32, (bsub * fc, cg), 0)
             // fc).astype(jnp.float32)
@@ -552,13 +609,14 @@ def _wave_fused_kernel_ct(xt_ref, lid_ref, w3_ref, cid_ref, tblt_ref,
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "bundled",
                                              "row_tile", "interpret",
-                                             "logical_cols", "hilo"))
+                                             "logical_cols", "hilo",
+                                             "col_pads"))
 def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
                                   num_bins: int, bundled: bool = False,
                                   row_tile: int = 8192,
                                   interpret: bool = False,
                                   logical_cols: int = 0,
-                                  hilo: bool = True):
+                                  hilo: bool = True, col_pads: tuple = ()):
     """Fused wave step from the transposed matrix alone.
 
     X_t: (F, N) bins (packed: (ceil(F/2), N) with logical_cols);
@@ -566,6 +624,11 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
     child_id: (K,) target smaller-child leaves (-1 = inactive);
     cols: (W, 10) compact split rows (ops/wave.py column layout);
     psrc: (W,) parent leaf id per wave slot (-3 = inactive).
+    col_pads: the one-hot width of each column where the store is ragged
+    (ops/wave.py col_bin_pads; () = every column `_bin_pad(num_bins)`):
+    the kernel multiplies each column against its own bins, over the
+    uniform kernel's row tiles, and the bins past a column's pad come
+    back zero, as they are.
     Returns (new_leaf_id (N,), (K, F, B, 3) child histograms).
     """
     fdev, n = X_t.shape
@@ -574,6 +637,7 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
     bp = _bin_pad(num_bins)
     bsub, c = _tile_plan(n, fc, bp, row_tile, k=k,
                          packed=bool(logical_cols))
+    acc_rows = sum(col_pads) or fc * bp
     pad = (-n) % c
     lid2 = (jnp.pad(leaf_id, (0, pad), constant_values=-2) if pad
             else leaf_id)[None, :]                   # (1, N)
@@ -586,7 +650,8 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
 
     kernel = functools.partial(_wave_fused_kernel_ct, bp=bp, fc=fc, k=k,
                                bsub=bsub, packed=bool(logical_cols),
-                               bundled=bundled, hilo=hilo)
+                               bundled=bundled, hilo=hilo,
+                               blocks=_ragged_blocks(col_pads))
     operands = (X_t, lid2, w3t, child_id[:, None], tblt, psrc[:, None])
     newlid, flat = pl.pallas_call(
         kernel,
@@ -609,16 +674,25 @@ def wave_partition_hist_pallas_ct(X_t, leaf_id, w3, child_id, cols, psrc,
         out_specs=[
             pl.BlockSpec((1, c), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((fc * bp, 3 * k), lambda i: (0, 0),
+            pl.BlockSpec((acc_rows, 3 * k), lambda i: (0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             vma_struct((1, n + pad), jnp.int32, *operands),
-            vma_struct((fc * bp, 3 * k), jnp.float32, *operands),
+            vma_struct((acc_rows, 3 * k), jnp.float32, *operands),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(*operands)
+    if col_pads:
+        # a column's rows lie together: back into num_bins bins a column
+        starts = [sum(col_pads[:j]) for j in range(fc)]
+        h = jnp.stack([
+            jnp.pad(flat[s:s + min(p, num_bins)],
+                    ((0, max(num_bins - p, 0)), (0, 0)))
+            for s, p in zip(starts, col_pads)])      # (Fc, B, 3K)
+        return newlid[0, :n], jnp.transpose(
+            h.reshape(fc, num_bins, 3, k), (3, 0, 1, 2))
     h = flat.reshape(bp, fc, 3, k)[:num_bins]
     return newlid[0, :n], jnp.transpose(h, (3, 1, 0, 2))

@@ -25,8 +25,9 @@ import jax.numpy as jnp
 from ..utils.config import _FALSE_SET, _TRUE_SET, Config
 from ..utils.log import Log
 from .pack import can_pack4
-from .wave import (WAVE_ONLY_MODES, _bin_pad, hist_block_bytes,
-                   pallas_wave_active, slab_active, transposed_wave_active)
+from .wave import (WAVE_ONLY_MODES, _bin_pad, col_bin_pads,
+                   hist_block_bytes, pallas_wave_active, slab_active,
+                   transposed_wave_active)
 
 # the VMEM budget the Pallas wave kernels compile under, shared with the
 # auto hist-mode gate (64 MB of the kernels' 100 MB compiler limit so
@@ -197,9 +198,26 @@ def store_bin_width(plan: "Plan", nbins: int) -> int:
     against under `plan`: the Pallas wave kernels (compiled or through
     the interpreter) pad the bins (`_bin_pad`), every other engine takes
     the `nbins` it is given."""
-    pallas = plan.growth == "wave" and plan.hist_mode.startswith("pallas") \
+    return _bin_pad(nbins) if _wave_kernel(plan, "pallas") else nbins
+
+
+def _wave_kernel(plan: "Plan", mode: str) -> bool:
+    """A Pallas wave kernel whose mode starts with `mode` runs under
+    `plan`, compiled or through the interpreter."""
+    return plan.growth == "wave" and plan.hist_mode.startswith(mode) \
         and (plan.kernel_runs or plan.pallas_interpret)
-    return _bin_pad(nbins) if pallas else nbins
+
+
+def store_col_pads(plan: "Plan", bins_per_col, nbins: int) -> tuple:
+    """Each column's own one-hot width, where `plan`'s kernel multiplies a
+    column against its own bins and they differ from `store_bin_width`:
+    the fused kernel (pallas_ct, compiled or through the interpreter) on
+    a ragged store (ops/wave.py col_bin_pads: EFB groups of 13 to 256
+    bins).  () everywhere else: every column is `store_bin_width` wide.
+    What `auto` resolves to is judged on the uniform block, not on these
+    (`prior_hist_mode`)."""
+    return (col_bin_pads(bins_per_col, nbins)
+            if _wave_kernel(plan, "pallas_ct") else ())
 
 
 def resolve_plan(config: Config, *, ncols: int, nbins: int,

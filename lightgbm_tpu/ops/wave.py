@@ -76,6 +76,23 @@ def _bin_pad(num_bins: int) -> int:
     return ((num_bins + 127) // 128) * 128
 
 
+# a column's one-hot width is its own bins rounded up to this: bins that
+# differ by a few between seeds (a bundle's rare levels) share a program
+BIN_GRANULE = 32
+
+
+def col_bin_pads(bins_per_col, num_bins: int) -> tuple:
+    """The one-hot width of each column of a store whose widest column
+    has `num_bins` bins: the column's own bins rounded up to
+    `BIN_GRANULE`, so a kernel multiplies against the bins a column has
+    and not against the widest column's.  () where every width is
+    `_bin_pad(num_bins)`: the store is uniform and the uniform kernels
+    serve it as they are."""
+    pads = tuple(-(-max(int(b), 1) // BIN_GRANULE) * BIN_GRANULE
+                 for b in bins_per_col)
+    return () if all(p == _bin_pad(num_bins) for p in pads) else pads
+
+
 def hist_block_bytes(ncols: int, bin_pad: int, width: int) -> int:
     """Bytes of the (ncols*bin_pad, 3W) f32 accumulator block the wave
     kernels keep resident in VMEM — the single geometry fact behind the
@@ -170,7 +187,8 @@ def make_wave_grow_fn(num_leaves: int, num_bins: int, meta: FeatureMeta,
                       with_xt: bool = False, exact_order: bool = False,
                       lookup: str = "onehot", hist_hilo: bool = True,
                       compact: bool = True,
-                      pallas_interpret: bool = False):
+                      pallas_interpret: bool = False,
+                      col_pads: tuple = ()):
     """Bind meta/bundle onto the cached wave-grow program (same contract as
     ops/grow.make_grow_fn: grow(X, grad, hess, row_mult, feature_mask) ->
     (TreeArrays, leaf_id)).
@@ -185,7 +203,7 @@ def make_wave_grow_fn(num_leaves: int, num_bins: int, meta: FeatureMeta,
                           bundle is not None, group_bins, cache_hists,
                           hist_mode, chunk, packed_cols, sparse_col_cap,
                           exact_order, lookup, hist_hilo, compact,
-                          pallas_interpret)
+                          pallas_interpret, col_pads)
 
     if with_xt:
         def grow(X, grad, hess, row_mult, feature_mask, Xt):
@@ -215,8 +233,12 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                    packed_cols: int = 0, sparse_col_cap: int = 0,
                    exact_order: bool = False, lookup: str = "onehot",
                    hist_hilo: bool = True, compact: bool = True,
-                   pallas_interpret: bool = False):
-    """packed_cols > 0: X is 4-bit packed (ops/pack.py, two columns per
+                   pallas_interpret: bool = False, col_pads: tuple = ()):
+    """col_pads: the columns' own one-hot widths where the store is ragged
+    and the fused kernel takes them (`col_bin_pads`, asked by ops/plan.py
+    store_col_pads); () = the uniform pad for every column.
+
+    packed_cols > 0: X is 4-bit packed (ops/pack.py, two columns per
     byte) and packed_cols is the LOGICAL column count; every chunk is
     unpacked in-scan so the full-width matrix never hits HBM (the
     dense_nbits_bin.hpp:37 bandwidth halving, TPU form).
@@ -483,7 +505,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                         jnp.where(valid, small_id, -1), cols, psrc,
                         hist_bins, bundled=has_bundle,
                         logical_cols=packed_cols, hilo=hist_hilo,
-                        interpret=pallas_interpret) + (no_rows,)
+                        interpret=pallas_interpret,
+                        col_pads=col_pads) + (no_rows,)
             with scope("wave_partition"):
                 lb = jnp.pad(leaf_id, (0, pad)) if pad else leaf_id
                 wpad = jnp.pad(w3, ((0, pad), (0, 0))) if pad else w3
@@ -704,7 +727,8 @@ def make_wave_core(num_leaves: int, num_bins: int, params: SplitParams,
                 # the smaller, and down that chain a leaf would be left
                 # with all of a once-rounded root's error
                 root_kw = ({"chunk": chunk,
-                            "hilo": bool(use_pallas_hist and hist_hilo)}
+                            "hilo": bool(use_pallas_hist and hist_hilo),
+                            "col_pads": col_pads}
                            if root_hist_fn is leaf_histogram_onehot else {})
                 hist0 = root_hist_fn(
                     X, grad, hess, leaf_id, 0, row_mult, num_bins=hist_bins,

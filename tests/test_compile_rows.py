@@ -128,6 +128,57 @@ def test_fused_pallas_ct_step_compiles_as_fast_at_any_row_count(
     assert "u8[62,16384,28]" not in text
 
 
+@pytest.mark.parametrize("bins,acc_rows", [
+    ((13, 32, 8, 23, 256, 59, 256, 59, 63, 63), 896),   # two blocks of 448
+    ((256, 256, 256, 256, 40, 3), 1120),    # blocks of 384, 384 and 352
+], ids=["expo", "a-shorter-last-block"])
+def test_the_ragged_fused_kernel_and_root_compile_for_a_v5e(topo, bins,
+                                                            acc_rows):
+    """The bundled cell's two one-hot passes at its ten groups' own
+    widths (PR 36): Mosaic takes the ragged walk's sublane broadcasts,
+    its row slices of the accumulator and the (896, 3K) block, and XLA
+    the root's width classes, for a described v5e at 8,388,608 rows
+    (interpret mode shows neither: a walk that sliced one iota for its
+    shorter last block passed it and crashed the compiler)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.ops.histogram import leaf_histogram_onehot
+    from lightgbm_tpu.ops.pallas_wave import wave_partition_hist_pallas_ct
+    from lightgbm_tpu.ops.wave import col_bin_pads
+
+    one = SingleDeviceSharding(topo.devices[0])
+    pads = col_bin_pads(bins, 256)
+    n, w, f = 8_388_608, 32, len(bins)
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one)
+
+    def launch(xt, lid, g, h, cid, cols, psrc):
+        w3 = jnp.stack([g, h, jnp.ones_like(g)], axis=-1)
+        return wave_partition_hist_pallas_ct(
+            xt, lid, w3, cid, cols, psrc, 256, bundled=True, hilo=False,
+            col_pads=pads)
+
+    _, text = _compile_seconds(jax.jit(launch), (
+        shape((f, n), jnp.uint8), shape((n,), jnp.int32),
+        shape((n,), jnp.float32), shape((n,), jnp.float32),
+        shape((w,), jnp.int32), shape((w, 10), jnp.float32),
+        shape((w,), jnp.int32)))
+    assert "wave_partition_hist_pallas_ct" in text
+    assert "f32[%d,96]" % acc_rows in text
+    assert "f32[%d,96]" % (f * 256) not in text
+
+    def root(x, lid, g, h):
+        return leaf_histogram_onehot(x, g, h, lid, 0, None, num_bins=256,
+                                     col_pads=pads)
+
+    _, text = _compile_seconds(jax.jit(root), (
+        shape((n, f), jnp.uint8), shape((n,), jnp.int32),
+        shape((n,), jnp.float32), shape((n,), jnp.float32)))
+    assert "f32[%d,256,3]" % f in text
+
+
 @pytest.mark.parametrize("cols", [28, 64])
 def test_pallas_t_grow_program_compiles_as_fast_at_any_row_count(
         topo, as_tpu, cols):

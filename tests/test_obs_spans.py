@@ -422,17 +422,22 @@ def bundled_dir(tmp_path_factory):
     return path
 
 
-def test_a_bundled_dataset_has_its_scope_span_and_counters(bundled_dir):
+@pytest.mark.parametrize("mode", ["pallas_t", "pallas_ct"])
+def test_a_bundled_dataset_has_its_scope_span_and_counters(bundled_dir,
+                                                           mode):
     """`bundle_view` (the EFB view in front of every split search) is a
     scope of its own INSIDE `split_search`, under a vmap, and taken out
     of it; `from_binned` rebuilds the layout under a span; one `bundle`
     record a learner says what EFB made of the features."""
-    from lightgbm_tpu.ops.plan import store_bin_width
+    from lightgbm_tpu.ops.plan import store_bin_width, store_col_pads
 
     timers._scopes.clear()
-    bst = _train_binned(bundled_dir, rounds=1)
+    timers.clear()
+    bst = _train_binned(bundled_dir, rounds=1, tpu_histogram_mode=mode)
     table = timers.device_scopes()["jit_step"]
-    assert set(table.values()) == set(timers.SCOPES) - {"hist_allreduce"}
+    # the fused kernel routes and histograms every row: no slab to move
+    assert set(table.values()) == set(timers.SCOPES) - {"hist_allreduce"} - (
+        {"wave_compact"} if mode == "pallas_ct" else set())
     assert timers._scope_of(
         "jit(step)/jit(grow)/while/body/split_search/split_search/"
         "vmap(bundle_view)/gather") == "bundle_view"
@@ -448,10 +453,17 @@ def test_a_bundled_dataset_has_its_scope_span_and_counters(bundled_dir):
         "bundle_groups": layout.num_groups,
         "bundled_features": sum(len(g) for g in bundled),
         "group_bins_used": int(layout.num_group_bins.sum()),
-        "group_bins_padded": layout.num_groups * store_bin_width(
+        "group_bins_padded": sum(store_col_pads(
+            bst._gbdt.learner.plan, layout.num_group_bins,
+            int(layout.num_group_bins.max())))
+        or layout.num_groups * store_bin_width(
             bst._gbdt.learner.plan, int(layout.num_group_bins.max()))}
-    # the interpreted wave kernel pads the widest group's 255 bins to 256
-    assert rec["fields"]["group_bins_padded"] == layout.num_groups * 256
+    # the interpreted pallas_t pads every group to the widest one's 256;
+    # the fused kernel multiplies each against its own bins by granules
+    # of 32: the twelve one-hot columns' 13 bins against 32
+    assert sorted(layout.num_group_bins) == [13, 255, 255]
+    assert rec["fields"]["group_bins_padded"] == (
+        layout.num_groups * 256 if mode == "pallas_t" else 32 + 256 + 256)
     (span,) = _spans("bundle_layout")
     assert by_seq[span["cause"]]["name"] == "dataset_open"
     assert span["ids"]["groups"] == layout.num_groups

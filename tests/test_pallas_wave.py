@@ -446,6 +446,114 @@ def test_fused_compact_kernel_bundled_remap():
     np.testing.assert_array_equal(np.asarray(got_lid), want_lid)
 
 
+# Expo's ten EFB groups (benchmark/configs/expo_700.json, `assumed`), and
+# a seed whose two rare-level groups read 62 and 56: one layout for both
+EXPO_BINS = (13, 32, 8, 23, 256, 59, 256, 59, 63, 63)
+EXPO_PADS = (32, 32, 32, 32, 256, 64, 256, 64, 64, 64)
+
+
+def _ragged_store(bins, n, seed):
+    rng = np.random.default_rng(seed)
+    X = np.stack([rng.integers(0, b, size=n) for b in bins],
+                 axis=1).astype(np.uint8)
+    leaf_id = rng.integers(0, 8, size=n).astype(np.int32)
+    w3 = rng.normal(size=(n, 3)).astype(np.float32)
+    return X, leaf_id, w3
+
+
+@pytest.mark.parametrize("bins", [
+    EXPO_BINS, (13, 32, 8, 23, 256, 62, 256, 56, 63, 63)],
+    ids=["expo", "rare-62-56"])
+@pytest.mark.parametrize("hilo", [False, True], ids=["bf16", "hilo"])
+def test_ragged_kernel_is_the_uniform_kernel_on_each_groups_own_bins(bins,
+                                                                     hilo):
+    """A bundled store's groups hold 8 to 256 bins.  With the columns'
+    own widths (ops/wave.py col_bin_pads) the fused kernel multiplies
+    832 bins against 896 one-hot rows where the uniform pad has 2,560:
+    the same leaf ids, the same histograms (the row tiles are the
+    uniform kernel's), and zeros in the bins a group does not have."""
+    from lightgbm_tpu.ops.pallas_wave import (_ragged_blocks,
+                                              wave_partition_hist_pallas_ct)
+    from lightgbm_tpu.ops.wave import col_bin_pads
+
+    pads = col_bin_pads(bins, 256)
+    assert pads == EXPO_PADS and sum(pads) == 896
+    blocks = _ragged_blocks(pads)
+    assert [len(b) * 32 for b in blocks] == [448, 448]
+    assert sorted(s for b in blocks for s in b) == [
+        (j, b0) for j, p in enumerate(pads) for b0 in range(0, p, 32)]
+
+    X, leaf_id, w3 = _ragged_store(bins, n=2300, seed=36)
+    cid = np.array([1, 9, -1, 5, 12], np.int32)
+    tbl = np.zeros((16, 10), np.float32)
+    tbl[2] = [1, 4, 100, 0, 0, 1, 9, 0, 1, 256]    # a wide group's bin
+    tbl[6] = [1, 0, 5, 0, 0, 0, 12, 1, 1, 12]      # a narrow group's
+    cols, psrc = _compact_from_tbl(tbl, w=5)
+    args = (jnp.asarray(X.T), jnp.asarray(leaf_id), jnp.asarray(w3),
+            jnp.asarray(cid), jnp.asarray(cols), jnp.asarray(psrc), 256)
+    want_lid, want = wave_partition_hist_pallas_ct(
+        *args, bundled=True, interpret=True, hilo=hilo)
+    got_lid, got = wave_partition_hist_pallas_ct(
+        *args, bundled=True, interpret=True, hilo=hilo, col_pads=pads)
+    np.testing.assert_array_equal(np.asarray(got_lid), np.asarray(want_lid))
+    assert got.shape == want.shape == (5, 10, 256, 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-5)
+    for j, b in enumerate(bins):
+        assert not np.asarray(got)[:, j, b:].any()
+    assert np.asarray(got)[0].any()
+
+
+def test_uniform_store_takes_the_uniform_kernel():
+    """Where every column's width is the uniform pad (28 x 63 bins: the
+    narrow cell; any store of up to 64 bins whose columns pass 32) the
+    layout is () and the call is the program it was: the same jaxpr,
+    and so the same Mosaic kernel and compile-cache key."""
+    import jax
+    from lightgbm_tpu.ops.pallas_wave import wave_partition_hist_pallas_ct
+    from lightgbm_tpu.ops.wave import col_bin_pads
+
+    assert col_bin_pads([63] * 28, 63) == ()
+    assert col_bin_pads([40, 63, 33], 63) == ()
+    assert col_bin_pads([255] * 4, 255) == ()
+    assert col_bin_pads([2, 63], 63) == (32, 64)
+    X, leaf_id, w3, cid, b = _data(n=1500, f=28, b=63, k=5, seed=3)
+    cols, psrc = np.zeros((5, 10), np.float32), np.full(5, -3, np.int32)
+    args = (jnp.asarray(X.T), jnp.asarray(leaf_id), jnp.asarray(w3),
+            jnp.asarray(cid), jnp.asarray(cols), jnp.asarray(psrc))
+    before = jax.make_jaxpr(
+        lambda *a: wave_partition_hist_pallas_ct(*a, b))(*args)
+    after = jax.make_jaxpr(lambda *a: wave_partition_hist_pallas_ct(
+        *a, b, col_pads=col_bin_pads([63] * 28, 63)))(*args)
+    assert str(before) == str(after)
+    assert "f32[1792,15]" in str(after)      # the (28 x 64, 3K) block
+
+
+@pytest.mark.parametrize("hilo", [False, True], ids=["bf16", "hilo"])
+@pytest.mark.parametrize("chunk", [16384, 512], ids=["one-chunk", "chunks"])
+def test_ragged_root_pass_matches_scatter(hilo, chunk):
+    """The root's one-hot pass by width class (ops/histogram.py
+    `_onehot_accumulate`, col_pads): each class's columns taken from the
+    chunk, contracted against the class's own width and written back in
+    column order, against the segment-sum histogram."""
+    from lightgbm_tpu.ops.histogram import (leaf_histogram_onehot,
+                                            leaf_histogram_scatter)
+    X, leaf_id, w3 = _ragged_store(EXPO_BINS, n=1800, seed=37)
+    args = (jnp.asarray(X), jnp.asarray(w3[:, 0]),
+            jnp.abs(jnp.asarray(w3[:, 1])), jnp.asarray(leaf_id % 2), 0,
+            jnp.ones(len(X), jnp.float32))
+    want = leaf_histogram_scatter(*args, num_bins=256)
+    got = leaf_histogram_onehot(*args, num_bins=256, chunk=chunk, hilo=hilo,
+                                col_pads=EXPO_PADS)
+    assert got.shape == (10, 256, 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=2e-4)
+    uniform = leaf_histogram_onehot(*args, num_bins=256, chunk=chunk,
+                                    hilo=hilo)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(uniform),
+                               rtol=1e-6, atol=1e-5)
+
+
 def test_kernel_hist_w_invariant_per_child():
     """Per-child histogram sums are independent of the wave width K:
     child c's (F, B, 3) block is bitwise identical whether the kernel

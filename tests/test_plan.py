@@ -25,7 +25,7 @@ import pytest
 from lightgbm_tpu.ops import plan
 from lightgbm_tpu.ops.plan import (Plan, prior_hist_mode, resolve_plan,
                                    resolve_wave_order, resolve_wave_width,
-                                   store_bin_width)
+                                   store_bin_width, store_col_pads)
 from lightgbm_tpu.utils.config import Config
 from lightgbm_tpu.utils.log import LightGBMError, Log
 
@@ -217,20 +217,57 @@ def test_resolve_plan_pins_the_whole_plan(params, ncols, nbins, backend,
     assert got.fused_wanted == got.kernel_runs
 
 
-@pytest.mark.parametrize("params,backend,width", [
-    (EXPO, "tpu", 256),                                 # pallas_ct: padded
-    (dict(EXPO, max_bin=63), "cpu", 200),               # the XLA engines
+# the cell's ten EFB groups (benchmark/configs/expo_700.json, `assumed`)
+EXPO_BINS = (13, 32, 8, 23, 256, 59, 256, 59, 63, 63)
+
+
+@pytest.mark.parametrize("params,backend,nbins,width,padded", [
+    # pallas_ct: each group its own bins by granules of 32
+    (EXPO, "tpu", 256, 256, 4 * 32 + 256 + 64 + 256 + 3 * 64),
+    (dict(EXPO, max_bin=63), "cpu", 200, 200, 10 * 200),  # the XLA engines
     (dict(EXPO, tpu_growth="wave", tpu_histogram_mode="pallas_t",
-          tpu_pallas_interpret=True), "cpu", 256),      # the interpreter
-], ids=["tpu-kernel", "cpu-xla", "cpu-interpreter"])
-def test_store_bin_width_is_padded_where_a_wave_kernel_runs(params, backend,
-                                                            width):
+          tpu_pallas_interpret=True), "cpu", 200, 256, 10 * 256),
+    (dict(EXPO, tpu_growth="wave", tpu_histogram_mode="pallas_ct",
+          tpu_pallas_interpret=True), "cpu", 256, 256, 896),
+], ids=["tpu-kernel", "cpu-xla", "cpu-interpreter", "cpu-interpreter-ct"])
+def test_store_bin_width_is_padded_where_a_wave_kernel_runs(
+        params, backend, nbins, width, padded):
     """The `bundle` counter's `group_bins_padded` (ops/learner.py) is the
-    groups times this: `_bin_pad` only where the plan takes a Pallas wave
-    kernel, the bins as they are under every other engine."""
-    config, ncols, nbins = _case(params, 10, 200)
+    one-hot rows the plan's engine multiplies a table row against: under
+    the fused kernel the sum of the groups' own widths
+    (`store_col_pads`), else the groups times `store_bin_width`:
+    `_bin_pad` where the plan takes another Pallas wave kernel, the bins
+    as they are under every other engine."""
+    config, ncols, nbins = _case(params, 10, nbins)
     plan = _resolve(config, ncols, nbins, backend)
     assert store_bin_width(plan, nbins) == width
+    pads = store_col_pads(plan, [min(b, nbins) for b in EXPO_BINS], nbins)
+    assert (sum(pads) or ncols * store_bin_width(plan, nbins)) == padded
+    assert bool(pads) == (plan.hist_mode == "pallas_ct")
+    # a rare-level pair of 62 / 56 bins is the same layout: one program
+    assert store_col_pads(
+        plan, (13, 32, 8, 23, nbins, 62, nbins, 56, 63, 63), nbins) == pads
+    # and a store whose columns all fill the uniform pad has none
+    assert store_col_pads(plan, [nbins] * 10, nbins) == ()
+
+
+@pytest.mark.parametrize("groups", [9, 10, 11])
+def test_the_plan_is_judged_on_the_uniform_block(groups):
+    """`auto` reads `ncols * _bin_pad(nbins)` (`CT_PROMOTION_BOUND`,
+    `WAVE_VMEM_GATE`), not the ragged sum: a store of 9, 10 or 11 groups
+    with Expo's own bin counts resolves as the pinned rows above say, the
+    eleventh group to pallas_t and the slab although its ragged sum
+    (896 + 64) is far under the bound (ROADMAP.md A7d)."""
+    config = Config(dict({"objective": "binary", "verbose": -1}, **EXPO))
+    bins = (EXPO_BINS + (63,))[:groups]
+    got = resolve_plan(
+        config, ncols=groups, nbins=256, num_leaves=config.num_leaves,
+        bins_per_col=bins, backend="tpu", dtype=jnp.float32,
+        psum_axis=None, dense_device_data=False)
+    (want,) = [row[-1] for row in PLANS
+               if row[0] == "expo_700-%d-groups-tpu" % groups]
+    assert got == Plan(*want)
+    assert bool(store_col_pads(got, bins, 256)) == (groups <= 10)
 
 
 # every check of a key moved with its rule, word for word: the parent's
