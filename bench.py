@@ -376,7 +376,7 @@ def dry():
         % len(iter_recs)
     assert all(e["time_s"] > 0 and e["fenced"] for e in iter_recs)
     # schema 11: every iter record carries the host-glue seconds between
-    # device program submissions (obs/timers.py OrchestrationClock)
+    # device program submissions (obs/timers.py open_self_seconds)
     assert all(e.get("host_orchestration_s", -1.0) >= 0.0
                for e in iter_recs), \
         "iter records missing host_orchestration_s: %r" % iter_recs

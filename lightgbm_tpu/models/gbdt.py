@@ -37,7 +37,7 @@ import numpy as np
 from ..io.dataset import TrainingData
 from ..metrics import Metric
 from ..obs import NULL_OBSERVER, observer_from_config, timers
-from ..obs.timers import OrchestrationClock, fenced_get
+from ..obs.timers import fenced_get
 from ..objectives import ObjectiveFunction, load_objective_from_string
 from ..ops.learner import SerialTreeLearner, materialize_tree
 from ..ops import predict as dev_predict
@@ -47,25 +47,6 @@ from ..utils.log import Log
 from .tree import Tree
 
 kEpsilon = 1e-15
-
-
-class _NullOrchestration:
-    """No-op stand-in for OrchestrationClock when telemetry is off — the
-    disabled hot path must not construct obs objects (the allocation
-    guard in tests/test_obs.py)."""
-    __slots__ = ()
-
-    def enter(self):
-        pass
-
-    def exit(self):
-        pass
-
-    def host_seconds(self):
-        return 0.0
-
-
-_NULL_ORCH = _NullOrchestration()
 
 
 # elements of one device-to-host copy of a score matrix (16 MB of
@@ -609,12 +590,6 @@ class GBDT:
         # /statusz and incident evidence bundles (obs/incident.py) —
         # a host dict update, nothing on the device path
         obs.stamp_context(stage="boost", it=it0, trees=len(self.models))
-        # host-orchestration accounting (obs/timers.py): everything this
-        # method does OUTSIDE the enter()/exit()-bracketed device
-        # dispatches is per-iteration host glue — emitted as the
-        # schema-11 ``host_orchestration_s`` iter field, the quantity
-        # the fused iteration exists to drive to ~0
-        oc = OrchestrationClock() if obs.enabled else _NULL_ORCH
         # split-audit needs to know which models this iteration appends
         # (includes the iteration-0 boost_from_average stub, which the
         # audit emitter skips — a stub has no realized split to record)
@@ -656,12 +631,11 @@ class GBDT:
         elif not custom:
             if self.objective is None:
                 Log.fatal("No object function provided")
-            oc.enter()
-            g_dev, h_dev = self.objective.get_gradients(
-                self._score_for_objective())
-            oc.exit()
-            g_dev = jnp.reshape(g_dev, (k, self.num_data))
-            h_dev = jnp.reshape(h_dev, (k, self.num_data))
+            with timers.span("gradients"):
+                g_dev, h_dev = self.objective.get_gradients(
+                    self._score_for_objective())
+                g_dev = jnp.reshape(g_dev, (k, self.num_data))
+                h_dev = jnp.reshape(h_dev, (k, self.num_data))
             gradients = hessians = None
         else:
             gradients = np.array(gradients, dtype=np.float32).reshape(k, self.num_data)
@@ -693,7 +667,6 @@ class GBDT:
                     # the partition score update never return to host
                     # (bit-identical to the staged chain below —
                     # tests/test_fused_iter.py)
-                    oc.enter()
                     dev_tree, leaf_id, new_score = fused.run(
                         self._score_dev[tid], self.row_mult, None,
                         jnp.asarray(self.shrinkage_rate, self.score_dtype))
@@ -702,10 +675,8 @@ class GBDT:
                     # one dispatch, one lap: gradients, grow and the score
                     # update are one program and have no host boundary
                     obs.lap("step", self._score_dev)
-                    oc.exit()
-                    last_leaf_id = leaf_id
                 else:
-                    oc.enter()
+                    # spans `grow_args` and `dispatch` inside
                     dev_tree, leaf_id = self.learner.train_device(
                         g_dev[tid], h_dev[tid], self.row_mult)
                     if getattr(self.learner, "_nproc", 1) > 1:
@@ -719,36 +690,33 @@ class GBDT:
                     # (one jitted entry; finer decomposition needs a
                     # profiler window — see docs/Observability.md)
                     obs.lap("grow", leaf_id)
-                    oc.exit()
-                    last_leaf_id = leaf_id
                     # device score updates (train via partition, valids
                     # via traversal) — all async
-                    oc.enter()
-                    self._score_dev = self._score_dev.at[tid].set(
-                        dev_predict.update_score_from_partition(
-                            self._score_dev[tid], leaf_id,
-                            dev_tree.leaf_value,
-                            jnp.asarray(self.shrinkage_rate,
-                                        self.score_dtype),
-                            engine=self._score_engine))
+                    with timers.span("score_update"):
+                        self._score_dev = self._score_dev.at[tid].set(
+                            dev_predict.update_score_from_partition(
+                                self._score_dev[tid], leaf_id,
+                                dev_tree.leaf_value,
+                                jnp.asarray(self.shrinkage_rate,
+                                            self.score_dtype),
+                                engine=self._score_engine))
                     self._invalidate_train()
                     obs.lap("partition", self._score_dev)
-                    oc.exit()
-                oc.enter()
-                ta = dev_predict.traversal_from_grow(dev_tree)
-                scaled = ta._replace(leaf_value=ta.leaf_value)
-                for vi in range(len(self.valid_data)):
-                    self._valid_score_dev[vi] = self._valid_score_dev[vi].at[tid].set(
-                        dev_predict.add_tree_to_score(
-                            self._valid_score_dev[vi][tid],
-                            self._valid_X_dev[vi], scaled,
-                            jnp.asarray(self.shrinkage_rate,
-                                        self.score_dtype),
-                            self.learner.bundle_arrays))
-                    self._invalidate_valid(vi)
+                last_leaf_id = leaf_id
                 if self.valid_data:
+                    with timers.span("score_update"):
+                        ta = dev_predict.traversal_from_grow(dev_tree)
+                        for vi in range(len(self.valid_data)):
+                            self._valid_score_dev[vi] = \
+                                self._valid_score_dev[vi].at[tid].set(
+                                    dev_predict.add_tree_to_score(
+                                        self._valid_score_dev[vi][tid],
+                                        self._valid_X_dev[vi], ta,
+                                        jnp.asarray(self.shrinkage_rate,
+                                                    self.score_dtype),
+                                        self.learner.bundle_arrays))
+                            self._invalidate_valid(vi)
                     obs.lap("update", self._valid_score_dev[-1])
-                oc.exit()
                 self.models.append(None)
                 self._models_dev.append(dev_tree)
                 self._models_shrink.append(self.shrinkage_rate)
@@ -775,8 +743,11 @@ class GBDT:
 
         # snapshot BEFORE the opt-in sync work below (health verdicts,
         # eval, model obs): host_orchestration_s is the per-tree
-        # submission glue, not the explicitly-priced sync features
-        host_orch = oc.host_seconds()
+        # submission glue, not the explicitly-priced sync features: the
+        # `iteration` span's own host time so far, outside its call spans
+        # (`gradients`, `grow_args`, `dispatch`, `score_update`) and the
+        # observer's fences (obs/timers.py open_self_seconds)
+        host_orch = timers.open_self_seconds() if obs.enabled else 0.0
 
         if health_leaves is not None:
             # one batched device_get over the staged scalars; may raise

@@ -123,7 +123,7 @@ SCHEMA_VERSION = 16
 # 7 (no autotune/band-escape events), 8 (no dataset_construct),
 # 9 (no run_header provenance), 10 (no host_orchestration_s iter
 # field — schema 11 adds the host-glue seconds between device program
-# submissions, models/gbdt.py OrchestrationClock), 11 (no pod
+# submissions, obs/timers.py open_self_seconds), 11 (no pod
 # scale-out events — schema 12 adds scaling / mesh_shrink / checkpoint
 # and the sharded-ingest dataset_construct fields), 12 (no roofline
 # attribution — schema 13 adds the per-iteration ``utilization``

@@ -50,7 +50,9 @@ def fence(value):
     try:
         import jax
         _FENCE_COUNT += 1
+        t0 = time.perf_counter_ns()
         jax.block_until_ready(value)
+        _waited_since(t0)
     except Exception:       # non-jax value, or backend already torn down
         pass
 
@@ -69,7 +71,21 @@ def fenced_get(value):
     global _FENCE_COUNT
     import jax
     _FENCE_COUNT += 1
-    return jax.device_get(value)
+    t0 = time.perf_counter_ns()
+    out = jax.device_get(value)
+    _waited_since(t0)
+    return out
+
+
+def _waited_since(t0):
+    """Charge a fence's wait since `t0` to the span open in this thread
+    (``fenced_ns`` on its record): waiting on the device is not host
+    work, and `open_self_seconds` leaves it out."""
+    stack = _stack()
+    if stack:
+        rec = stack[-1]
+        rec["fenced_ns"] = rec.get("fenced_ns", 0) \
+            + time.perf_counter_ns() - t0
 
 
 class PhaseClock:
@@ -175,38 +191,6 @@ class EntryTimers:
                                       else 0.0)),
             }
         return out
-
-
-class OrchestrationClock:
-    """Host time BETWEEN device program submissions within one iteration.
-
-    Construction marks the iteration start; ``enter()``/``exit()``
-    bracket each device-entry dispatch (the jitted call itself, which is
-    asynchronous — its wall time is queueing, not orchestration); the
-    remainder is the host's own per-iteration glue: gradient reshapes,
-    padding, ``.at[].set`` staging, bookkeeping Python.  That remainder
-    is the ``host_orchestration_s`` field on the schema-11 ``iter``
-    event — the quantity the fused iteration (ops/fused_iter.py) is
-    built to drive to ~0.  Never fences: measuring must not perturb the
-    async pipeline.
-    """
-
-    __slots__ = ("_t0", "_t_enter", "_inside")
-
-    def __init__(self):
-        self._t0 = time.perf_counter()
-        self._t_enter = 0.0
-        self._inside = 0.0
-
-    def enter(self):
-        self._t_enter = time.perf_counter()
-
-    def exit(self):
-        self._inside += time.perf_counter() - self._t_enter
-
-    def host_seconds(self) -> float:
-        """Elapsed since construction minus time spent inside dispatches."""
-        return max(0.0, (time.perf_counter() - self._t0) - self._inside)
 
 
 # --------------------------------------------------------------------------
@@ -331,7 +315,9 @@ class span:
         if stack:
             parent = stack[-1]
             rec["cause"] = parent["seq"]
-            rec["ids"] = dict(parent["ids"], **rec["ids"])
+            # a span with no ids of its own shares its parent's dict
+            rec["ids"] = (dict(parent["ids"], **rec["ids"]) if rec["ids"]
+                          else parent["ids"])
         rec["seq"] = next(_seq)
         ann = _annotation or _hook_jax()
         self._ann = ann("lgbm_" + rec["name"], seq=rec["seq"], **rec["ids"])
@@ -381,16 +367,42 @@ def self_seconds(records):
     kids = collections.defaultdict(list)
     for r in spans:
         kids[r["cause"]].append((r["t0"], r["t1"]))
-    out = {}
-    for r in spans:
-        covered, end = 0, r["t0"]
-        for t0, t1 in sorted(kids.get(r["seq"], ())):
-            t0, t1 = max(t0, end), min(t1, r["t1"])
-            if t1 > t0:
-                covered += t1 - t0
-                end = t1
-        out[r["seq"]] = (r["t1"] - r["t0"] - covered) * 1e-9
-    return out
+    return {r["seq"]: (r["t1"] - r["t0"] - _covered_ns(
+        r["t0"], r["t1"], kids.get(r["seq"], ()))) * 1e-9 for r in spans}
+
+
+def _covered_ns(t0, t1, intervals):
+    """Nanoseconds of [t0, t1] that the union of `intervals` covers."""
+    covered, end = 0, t0
+    for s, e in sorted(intervals):
+        s, e = max(s, end), min(e, t1)
+        if e > s:
+            covered += e - s
+            end = e
+    return covered
+
+
+def open_self_seconds():
+    """Host seconds so far of the innermost span open in this thread: its
+    elapsed time less what its finished child spans cover and less the
+    fences it waited in itself.  Of the staged chain's ``iteration`` that
+    is the host's own glue between its calls (``host_orchestration_s``).
+    Walks the ring back to the span's first child; 0.0 where no span is
+    open."""
+    stack = _stack()
+    if not stack:
+        return 0.0
+    rec = stack[-1]
+    now = time.perf_counter_ns()
+    kids = []
+    with _ring_lock:
+        for r in reversed(_ring):
+            if r["seq"] < rec["seq"]:
+                break
+            if r["kind"] == "span" and r["cause"] == rec["seq"]:
+                kids.append((r["t0"], r["t1"]))
+    return max(0.0, (now - rec["t0"] - _covered_ns(rec["t0"], now, kids)
+                     - rec.get("fenced_ns", 0)) * 1e-9)
 
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")

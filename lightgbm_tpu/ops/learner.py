@@ -460,24 +460,25 @@ class SerialTreeLearner:
     def train_device(self, grad, hess, row_mult=None,
                      feature_mask=None) -> Tuple[TreeArrays, jnp.ndarray]:
         """Grow one tree fully on device; no host synchronization."""
-        if row_mult is None:
-            row_mult = self._ones
-        else:
-            row_mult = jnp.asarray(row_mult, self.dtype)
+        with timers.span("grow_args"):
+            if row_mult is None:
+                row_mult = self._ones
+            else:
+                row_mult = jnp.asarray(row_mult, self.dtype)
+                if self._row_pad:
+                    row_mult = jnp.concatenate(
+                        [row_mult, jnp.zeros(self._row_pad, self.dtype)])
+            if feature_mask is None:
+                feature_mask = self.sample_feature_mask()
+            grad = jnp.asarray(grad, self.dtype)
+            hess = jnp.asarray(hess, self.dtype)
             if self._row_pad:
-                row_mult = jnp.concatenate(
-                    [row_mult, jnp.zeros(self._row_pad, self.dtype)])
-        if feature_mask is None:
-            feature_mask = self.sample_feature_mask()
-        grad = jnp.asarray(grad, self.dtype)
-        hess = jnp.asarray(hess, self.dtype)
-        if self._row_pad:
-            grad = jnp.concatenate(
-                [grad, jnp.zeros(self._row_pad, self.dtype)])
-            hess = jnp.concatenate(
-                [hess, jnp.zeros(self._row_pad, self.dtype)])
+                grad = jnp.concatenate(
+                    [grad, jnp.zeros(self._row_pad, self.dtype)])
+                hess = jnp.concatenate(
+                    [hess, jnp.zeros(self._row_pad, self.dtype)])
+            args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs = self._obs
-        args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs.entry_args("tree_grow", self._grow, args,
                        names=("X", "grad", "hess", "row_mult",
                               "feature_mask", "Xt")[:len(args)])

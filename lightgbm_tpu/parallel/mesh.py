@@ -385,16 +385,17 @@ class DataParallelTreeLearner(SerialTreeLearner):
         (tests/mp_worker.py drives this directly; the GBDT layer slices
         it back to local rows via ``local_rows``).  Single-process
         callers pass host arrays and get unpadded local maps."""
-        grad = self._pad_rows_dev(grad)
-        hess = self._pad_rows_dev(hess)
-        if row_mult is None:
-            row_mult = self._ones
-        else:
-            row_mult = self._pad_rows_dev(row_mult)
-        if feature_mask is None:
-            feature_mask = self.sample_feature_mask()
-        feature_mask = jax.device_put(feature_mask, self._replicated)
-        args = self.grow_args(grad, hess, row_mult, feature_mask)
+        with timers.span("grow_args"):
+            grad = self._pad_rows_dev(grad)
+            hess = self._pad_rows_dev(hess)
+            if row_mult is None:
+                row_mult = self._ones
+            else:
+                row_mult = self._pad_rows_dev(row_mult)
+            if feature_mask is None:
+                feature_mask = self.sample_feature_mask()
+            feature_mask = jax.device_put(feature_mask, self._replicated)
+            args = self.grow_args(grad, hess, row_mult, feature_mask)
         obs = self._obs
         obs.entry_args("tree_grow", self._grow, args,
                        names=("X", "grad", "hess", "row_mult",

@@ -92,7 +92,7 @@ METRICS = {
     # both slow binning AND accidental re-binning of a binned artifact
     "construct_s": (-1, 0.30),
     # mean host seconds between device program submissions per iteration
-    # (schema v11 iter field, models/gbdt.py OrchestrationClock) — the
+    # (schema v11 iter field, obs/timers.py open_self_seconds) — the
     # number the fused iteration (ops/fused_iter.py) drives to ~0.  A
     # fused baseline sits near zero where scheduler jitter is a large
     # relative move, so the tolerance is wide (50%) — the gate is for
