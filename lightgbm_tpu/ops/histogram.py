@@ -112,6 +112,44 @@ def _width_classes(col_pads, num_bins: int):
     return tuple(sorted(classes.items()))
 
 
+# the widest one-hot one contraction of the one-hot pass takes: on the v5e
+# a 256-wide class cost 2.86 ps an element, 64-wide ones 0.67-0.86 and the
+# 32-wide one 1.08, and 32-bin segments read slower than 64 (PERF.md section 6)
+SEGMENT_BINS = 64
+
+
+def _onehot_widths(col_pads, num_bins: int, f: int):
+    """Each of the `f` columns' one-hot width."""
+    return ([min(p, num_bins) for p in col_pads] if col_pads
+            else [num_bins] * f)
+
+
+def _segments(col_pads, num_bins: int, f: int):
+    """Where some column's one-hot passes SEGMENT_BINS: the pass's width
+    classes of pieces, ((width, ((column, first bin), ...)), ...), widest
+    last.  A column that wide becomes SEGMENT_BINS-wide segments at first
+    bins 0, 64, ... (the last one's bins past the column's width stay
+    empty); a narrower column is one piece of its own width.  A class's
+    pieces come by first bin, then column, so that they are runs of the
+    chunk's columns.  () where no column passes."""
+    widths = _onehot_widths(col_pads, num_bins, f)
+    if max(widths) <= SEGMENT_BINS:
+        return ()
+    classes = {}
+    for b0, j in sorted((b0, j) for j, w in enumerate(widths)
+                        for b0 in range(0, w, SEGMENT_BINS)):
+        classes.setdefault(min(widths[j], SEGMENT_BINS), []).append((j, b0))
+    return tuple(sorted(classes.items()))
+
+
+def onehot_pass_shape(col_pads, num_bins: int, f: int):
+    """(the widest one-hot the one-hot pass contracts, the segments it
+    cuts from wider columns) for a store of `f` columns."""
+    widths = _onehot_widths(col_pads, num_bins, f)
+    return (min(max(widths), SEGMENT_BINS),
+            sum(-(-w // SEGMENT_BINS) for w in widths if w > SEGMENT_BINS))
+
+
 def _columns(xc, cols):
     """Columns `cols` (ascending) of a chunk, as slices of their runs."""
     runs = [[cols[0], cols[0] + 1]]
@@ -123,6 +161,15 @@ def _columns(xc, cols):
     return jnp.concatenate([xc[:, a:b] for a, b in runs], axis=1)
 
 
+def _pieces(xc, pieces):
+    """Pieces ((column, first bin), ...) of a chunk: each its column's bin
+    less its first bin, so that a bin outside the segment is a value
+    outside [0, width), a zero one-hot row."""
+    first = [b0 for _, b0 in pieces]
+    x = _columns(xc, [j for j, _ in pieces])
+    return x - jnp.asarray(first, jnp.int32) if any(first) else x
+
+
 def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
                        logical_cols: int = 0, hilo: bool = False,
                        col_pads: tuple = ()):
@@ -132,7 +179,9 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
     (ops/wave.py col_bin_pads; () = `num_bins` for every column): one
     contraction a width class, over the class's columns of the chunk,
     never of the whole matrix; the bins past a column's width come back
-    zero, as they are.
+    zero, as they are.  No contraction's one-hot is wider than
+    SEGMENT_BINS: a wider column, ragged or uniform, goes in as segments
+    of that width in its class (`_segments`).
 
     logical_cols > 0: binned is 4-bit packed (ops/pack.py); chunks unpack
     in-scan so the full-width matrix never materializes in HBM.
@@ -175,13 +224,17 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
         return tuple(
             a + jnp.einsum(
                 "cfb,cw->fbw",
-                jax.nn.one_hot(_columns(xc, cols) if col_pads else xc,
+                jax.nn.one_hot(_pieces(xc, cols) if segments
+                               else _columns(xc, cols) if col_pads else xc,
                                width, dtype=wc.dtype),   # (C, F, B)
                 wc, preferred_element_type=wc.dtype)
             for a, (width, cols) in zip(acc, classes))
 
-    classes = (_width_classes(col_pads, num_bins) if col_pads
-               else ((num_bins, range(f)),))
+    # a column wider than SEGMENT_BINS goes in as segments of the
+    # SEGMENT_BINS class; a store with no such column is what it was
+    segments = _segments(col_pads, num_bins, f)
+    classes = segments or (_width_classes(col_pads, num_bins) if col_pads
+                           else ((num_bins, range(f)),))
     init = tuple(jnp.zeros((len(cols), width, k), dtype=w.dtype)
                  for width, cols in classes)
     if nchunks == 1:
@@ -189,7 +242,21 @@ def _onehot_accumulate(binned, w, num_bins: int, chunk: int,
     else:
         from .grow import vary_like
         hists = lax.fori_loop(0, nchunks, step, vary_like(init, binned, w))
-    if col_pads:
+    if segments:
+        # each column's segments side by side (a zero block where a
+        # narrower column has fewer), cut or padded to `num_bins`
+        row = {p: i for i, p in enumerate(
+            p for _, pieces in classes for p in pieces)}
+        nseg = max(b0 for _, b0 in row) // SEGMENT_BINS + 1
+        hist = jnp.concatenate(
+            [jnp.pad(h, ((0, 0), (0, SEGMENT_BINS - h.shape[1]), (0, 0)))
+             for h in hists] + [jnp.zeros((1, SEGMENT_BINS, k), w.dtype)])[
+            jnp.asarray([[row.get((j, s * SEGMENT_BINS), len(row))
+                          for s in range(nseg)] for j in range(f)])]
+        hist = hist.reshape(f, nseg * SEGMENT_BINS, k)
+        hist = jnp.pad(hist, ((0, 0), (0, max(num_bins - hist.shape[1], 0)),
+                              (0, 0)))[:, :num_bins]
+    elif col_pads:
         # the classes' blocks side by side, then back in column order
         order = [j for _, cols in classes for j in cols]
         hist = jnp.concatenate(

@@ -25,6 +25,7 @@ from ..utils.config import Config
 from ..utils.random import Random
 from .grow import (BundleArrays, TreeArrays, default_row_capacities,
                    make_grow_fn)
+from .histogram import onehot_pass_shape
 from .plan import resolve_plan, store_bin_width, store_col_pads
 from .sparse_mxu import ChunkedSparseStore, build_chunked_store
 from .sparse_store import (SparseDeviceStore, build_sparse_store,
@@ -213,6 +214,14 @@ class SerialTreeLearner:
                 group_bins_padded=sum(self.col_pads)
                 or bundle.num_groups * store_bin_width(
                     self.plan, self.group_bins))
+            widest, segments = onehot_pass_shape(
+                self.col_pads, nbins, bundle.num_groups)
+            if segments and (self.plan.hist_mode == "onehot" or (
+                    self.plan.growth == "wave" and self.plan.hist_mode
+                    not in ("scatter", "sparse", "sparse_mxu"))):
+                # and the groups the root's one-hot pass cuts into
+                # segments (ops/histogram.py `_segments`)
+                timers.count("root_pass", widest=widest, segments=segments)
         self._upload(psum_axis, device_data, device_row_pad,
                      device_packed_cols, device_sparse_col_cap)
         self._build_grow(psum_axis)
