@@ -39,6 +39,7 @@ from ..metrics import Metric
 from ..obs import NULL_OBSERVER, observer_from_config, timers
 from ..obs.timers import fenced_get
 from ..objectives import ObjectiveFunction, load_objective_from_string
+from ..ops.fused_iter import ClassTree
 from ..ops.learner import SerialTreeLearner, materialize_tree
 from ..ops import predict as dev_predict
 from ..utils.config import Config
@@ -467,15 +468,33 @@ class GBDT:
         with timers.span("materialize"):
             self._materialize_pending(pending)
 
+    @staticmethod
+    def _host_trees(devs) -> list:
+        """Host TreeArrays of `devs`, in order, from one counted sync: the
+        single trees stacked on the device first, each class step's batch
+        fetched whole and split here (span ``class_unstack``)."""
+        single = [d for d in devs if not isinstance(d, ClassTree)]
+        batches = {id(d.batch): d.batch for d in devs
+                   if isinstance(d, ClassTree)}
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                         *single) \
+            if len(single) > 1 else (single[0] if single else None)
+        host, host_batches = fenced_get((stacked, batches))
+        singles = iter([jax.tree_util.tree_map(lambda x: x[j], host)
+                        for j in range(len(single))]
+                       if len(single) > 1 else [host])
+        if not batches:
+            return list(singles)
+        with timers.span("class_unstack", trees=len(devs) - len(single)):
+            return [jax.tree_util.tree_map(lambda x: x[d.index],
+                                           host_batches[id(d.batch)])
+                    if isinstance(d, ClassTree) else next(singles)
+                    for d in devs]
+
     def _materialize_pending(self, pending) -> None:
-        devs = [self._models_dev[i] for i in pending]
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *devs) \
-            if len(devs) > 1 else devs[0]
-        host = fenced_get(stacked)      # counted: one sync per batch
+        hosts = self._host_trees([self._models_dev[i] for i in pending])
         mesh = getattr(self.learner, "mesh", None)
-        for j, i in enumerate(pending):
-            ht = jax.tree_util.tree_map(lambda x: x[j], host) \
-                if len(devs) > 1 else host
+        for i, ht in zip(pending, hosts):
             tree = materialize_tree(ht, self.train_data,
                                     self.config.num_leaves)
             tree.shrink(self._models_shrink[i])
@@ -567,10 +586,14 @@ class GBDT:
                                 "chain", why)
             else:
                 if mode == "on" or self.learner.plan.fused_wanted:
+                    k = self.num_tree_per_iteration
+                    # k > 1: the class step, over the trained classes
+                    classes = None if k == 1 else [
+                        c for c in range(k) if self.class_need_train[c]]
                     fused = _fi.FusedIteration.build(
                         self.learner, self.objective,
                         self.num_data, self.score_dtype,
-                        self._score_engine)
+                        self._score_engine, classes)
         self._fused_state = (fused,)
         return fused
 
@@ -660,9 +683,24 @@ class GBDT:
 
         num_leaves_this_iter = []
         last_leaf_id = None
+        class_trees = None
+        if fused is not None and fused.classes is not None:
+            # one dispatch for every trained class: the softmax over the
+            # (k, N) score, one grow body scanned over the classes and
+            # their score updates (bit-identical to the staged chain's k
+            # grows: tests/test_fused_multiclass.py)
+            class_trees, last_leaf_id, self._score_dev = fused.run(
+                self._score_dev, self.row_mult, None,
+                jnp.asarray(self.shrinkage_rate, self.score_dtype))
+            self._invalidate_train()
+            obs.lap("step", self._score_dev)
+            num_leaves_this_iter.append(class_trees.num_leaves)
         for tid in range(k):
             if self.class_need_train[tid]:
-                if fused is not None:
+                if class_trees is not None:
+                    dev_tree = ClassTree(
+                        class_trees, fused.classes.index(tid))
+                elif fused is not None:
                     # one dispatch: gradients, the grow while_loop and
                     # the partition score update never return to host
                     # (bit-identical to the staged chain below —
@@ -702,10 +740,14 @@ class GBDT:
                                 engine=self._score_engine))
                     self._invalidate_train()
                     obs.lap("partition", self._score_dev)
-                last_leaf_id = leaf_id
+                if class_trees is None:
+                    last_leaf_id = leaf_id
+                    num_leaves_this_iter.append(dev_tree.num_leaves)
                 if self.valid_data:
                     with timers.span("score_update"):
-                        ta = dev_predict.traversal_from_grow(dev_tree)
+                        ta = dev_predict.traversal_from_grow(
+                            dev_tree.on_device() if class_trees is not None
+                            else dev_tree)
                         for vi in range(len(self.valid_data)):
                             self._valid_score_dev[vi] = \
                                 self._valid_score_dev[vi].at[tid].set(
@@ -720,7 +762,6 @@ class GBDT:
                 self.models.append(None)
                 self._models_dev.append(dev_tree)
                 self._models_shrink.append(self.shrinkage_rate)
-                num_leaves_this_iter.append(dev_tree.num_leaves)
                 if health_leaves is not None:
                     health_leaves.append(dev_tree.leaf_value)
                 if self._metrics is not None:
@@ -768,8 +809,9 @@ class GBDT:
         should_continue = True
         if num_leaves_this_iter:
             if is_eval or (self.iter % 16 == 0):
-                should_continue = any(int(nl) > 1
-                                      for nl in fenced_get(num_leaves_this_iter))
+                should_continue = any(
+                    bool((np.asarray(nl) > 1).any())
+                    for nl in fenced_get(num_leaves_this_iter))
                 comm = self._dist_comm()
                 if comm is not None:
                     # pod-wide stop vote.  Trees are bit-identical across

@@ -21,6 +21,18 @@ accelerator-GBDT literature (PAPERS.md: arxiv 2011.02022's pipelined
 stage dataflow, 1706.08359's on-device leaf loop) gets its headline win
 from exactly this collapse.
 
+A booster of k > 1 trees an iteration (multiclass softmax, one-vs-all)
+takes the class step: the (k, N) score in, the objective's gradients
+over all k classes ONCE, then ONE grow body for the trained classes, a
+``lax.scan`` over them (so compile time does not follow k), each class
+its own g[c], h[c], feature mask and score update, and the stacked
+trees, the last class's leaf ids and the new (k, N) score out.  The host
+splits the stacked trees into k trees in class order (models/gbdt.py
+``_materialize``, span ``class_unstack``).  A class that
+``class_need_train`` skips drops out of the scan by the static list of
+the trained classes; its stub tree stays a host tree, as in the staged
+chain.
+
 Bit-identity contract
 ---------------------
 The fused program traces the SAME functions the staged path calls:
@@ -37,12 +49,14 @@ The fused program traces the SAME functions the staged path calls:
   compare-select kernel, which selects the same clipped f32 values.
 
 Same trees, same split-audit events, same model file — enforced by
-tests/test_fused_iter.py across the flagship/epsilon/msltr/expo_cat
-shape buckets.
+tests/test_fused_iter.py across the flagship/epsilon/msltr/expo_cat/
+multiclass shape buckets, and for k = 3 and 7 by
+tests/test_fused_multiclass.py.
 
-Eligibility (models/gbdt.py _resolve_fused_iter): serial learner, one
-tree per iteration, a built-in (traceable) objective, no custom
-gradients, no GOSS/DART gradient rescale, no gradient health staging.
+Eligibility (models/gbdt.py _resolve_fused_iter): serial learner, a
+built-in (traceable) objective of one tree an iteration or of k (at
+least one class trained), no custom gradients, no GOSS/DART gradient
+rescale, no gradient health staging.
 Everything else falls back to the staged chain; ``tpu_fused_iter``
 (auto/on/off) picks between them (auto: ops/plan.py Plan.fused_wanted).
 """
@@ -50,6 +64,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from .predict import score_update_traced
 from ..obs import timers
@@ -68,8 +84,9 @@ def fused_supported(booster) -> tuple:
         return False, "boosting mode overrides train_one_iter (dart)"
     if type(booster)._bagging_with_grad is not GBDT._bagging_with_grad:
         return False, "gradient-rescaling bagging (goss)"
-    if booster.num_tree_per_iteration != 1:
-        return False, "num_tree_per_iteration > 1 (multiclass)"
+    if booster.num_tree_per_iteration > 1 \
+            and not any(booster.class_need_train):
+        return False, "no class to train (every class empty or full)"
     if booster.objective is None:
         return False, "no built-in objective (custom fobj)"
     if type(booster.learner) is not SerialTreeLearner:
@@ -84,13 +101,31 @@ def fused_supported(booster) -> tuple:
     return True, ""
 
 
+class ClassTree:
+    """Tree `index` of a batch the class step grew (TreeArrays stacked over
+    the trained classes): what the booster keeps for one class tree until
+    ``_materialize`` fetches the batch whole and splits it on the host."""
+
+    __slots__ = ("batch", "index")
+
+    def __init__(self, batch, index: int):
+        self.batch = batch
+        self.index = int(index)
+
+    def on_device(self):
+        """The class's own TreeArrays (a slice a field, dispatched)."""
+        return jax.tree_util.tree_map(lambda x: x[self.index], self.batch)
+
+
 class FusedIteration:
     """One boosting step as one jitted device entry.
 
     Built once per booster (the grow closure and objective are fixed for
     a training run); ``run`` submits a single program and returns the
     same (TreeArrays, leaf_id, new_score) triple the staged chain
-    produces across its three entries.
+    produces across its three entries.  With ``classes`` (a booster of
+    k > 1 trees an iteration: the trained classes' indices) the score is
+    the (k, N) matrix and the trees come stacked over those classes.
 
     Every dataset-sized array — the bin matrix X, its transpose Xt, the
     objective's labels / weights / per-row tables — is an ARGUMENT of
@@ -100,26 +135,25 @@ class FusedIteration:
     """
 
     def __init__(self, learner, objective, num_data: int,
-                 score_engine: str = "gather"):
+                 score_engine: str = "gather", classes=None):
         self._learner = learner
         self._num_data = int(num_data)
         self._obj_arrays, rebind = objective.split_device_state()
+        self.classes = None if classes is None else tuple(classes)
+        n = self._num_data
         pad = int(learner._row_pad)
         dtype = learner.dtype
         grow = learner._grow
 
-        def step(X, Xt, obj_arrays, score, row_mult, feature_mask, scale):
-            # stage 1: objective gradients in-graph — same ops the staged
-            # path dispatches as its own entry (reshape to (1, N) and the
-            # [0] slice are identities at k=1, so they are elided)
-            with jax.named_scope("gradients"):
-                g, h = rebind(obj_arrays).get_gradients(score)
-                g = jnp.asarray(g, dtype)
-                h = jnp.asarray(h, dtype)
-                if pad:
-                    z = jnp.zeros(pad, dtype)
-                    g = jnp.concatenate([g, z])
-                    h = jnp.concatenate([h, z])
+        def row_padded(g, h):
+            g, h = jnp.asarray(g, dtype), jnp.asarray(h, dtype)
+            if pad:
+                z = jnp.zeros(g.shape[:-1] + (pad,), dtype)
+                g = jnp.concatenate([g, z], axis=-1)
+                h = jnp.concatenate([h, z], axis=-1)
+            return g, h
+
+        def grow_tree(X, Xt, g, h, row_mult, feature_mask, score, scale):
             # stage 2: the learner's own grow program, inlined — the
             # lax.while_loop over the leaf frontier (hist accumulation,
             # FindBestThreshold, row->leaf partition) never touches host
@@ -131,12 +165,53 @@ class FusedIteration:
             # own engine (bit-identity single source)
             with jax.named_scope("score_update"):
                 if pad:
-                    leaf_id = leaf_id[: self._num_data]
+                    leaf_id = leaf_id[:n]
                 new_score = score_update_traced(
                     score, leaf_id, tree.leaf_value, scale, score_engine)
             return tree, leaf_id, new_score
 
-        self._step = jax.jit(step)
+        def step(X, Xt, obj_arrays, score, row_mult, feature_mask, scale):
+            # stage 1: objective gradients in-graph — same ops the staged
+            # path dispatches as its own entry (reshape to (1, N) and the
+            # [0] slice are identities at k=1, so they are elided)
+            with jax.named_scope("gradients"):
+                g, h = rebind(obj_arrays).get_gradients(score)
+                g, h = row_padded(g, h)
+            return grow_tree(X, Xt, g, h, row_mult, feature_mask, score,
+                             scale)
+
+        def class_step(X, Xt, obj_arrays, score, row_mult, feature_masks,
+                       scale):
+            # every class's gradients from the one (k, N) score, once:
+            # the softmax couples the classes, so no class can take its
+            # own before the others' trees are grown
+            k = score.shape[0]
+            with jax.named_scope("gradients"):
+                g, h = rebind(obj_arrays).get_gradients(score)
+                g = jnp.reshape(g, (k, n))
+                h = jnp.reshape(h, (k, n))
+                if len(self.classes) < k:
+                    trained = np.asarray(self.classes)
+                    g, h = g[trained], h[trained]
+                g, h = row_padded(g, h)
+
+            def one_class(carry, xs):
+                score, _ = carry
+                c, g_c, h_c, mask = xs
+                tree, leaf_id, row = grow_tree(
+                    X, Xt, g_c, h_c, row_mult, mask,
+                    lax.dynamic_index_in_dim(score, c, keepdims=False),
+                    scale)
+                with jax.named_scope("score_update"):
+                    score = lax.dynamic_update_index_in_dim(score, row, c, 0)
+                return (score, leaf_id), tree
+
+            (score, leaf_id), trees = lax.scan(
+                one_class, (score, jnp.zeros(n, jnp.int32)),
+                (jnp.asarray(self.classes, jnp.int32), g, h, feature_masks))
+            return trees, leaf_id, score
+
+        self._step = jax.jit(step if self.classes is None else class_step)
         # the step compiled ahead of time, one executable per argument
         # signature: holding the Compiled is what lets its own HLO text be
         # read (timers.scoped_executable) at no second compile
@@ -150,7 +225,7 @@ class FusedIteration:
 
     @classmethod
     def build(cls, learner, objective, num_data: int, score_dtype,
-              score_engine: str = "gather"):
+              score_engine: str = "gather", classes=None):
         """Construct and trace-check the fused program.
 
         jax.eval_shape traces without compiling or executing, so a
@@ -159,14 +234,20 @@ class FusedIteration:
         user-defined ObjectiveFunction subclass whose get_gradients runs
         host code on the score (numpy on a tracer: a JAXTypeError) sends
         the booster back to the staged chain, with a warning."""
-        fused = cls(learner, objective, num_data, score_engine)
+        fused = cls(learner, objective, num_data, score_engine, classes)
         n = int(num_data)
+        features = max(learner.train_data.num_features, 1)
+        if classes is None:
+            score, masks = (n,), (features,)
+        else:
+            k = objective.num_tree_per_iteration()
+            score, masks = (k, n), (len(classes), features)
+            timers.count("class_trees", classes=k, trained=len(classes))
         shapes = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             fused.step_args(
-                jax.ShapeDtypeStruct((n,), score_dtype), learner._ones,
-                jax.ShapeDtypeStruct(
-                    (max(learner.train_data.num_features, 1),), jnp.bool_),
+                jax.ShapeDtypeStruct(score, score_dtype), learner._ones,
+                jax.ShapeDtypeStruct(masks, jnp.bool_),
                 jax.ShapeDtypeStruct((), score_dtype)))
         try:
             jax.eval_shape(fused._step, *shapes)
@@ -193,7 +274,11 @@ class FusedIteration:
                 row_mult = jnp.concatenate(
                     [row_mult, jnp.zeros(lrn._row_pad, lrn.dtype)])
         if feature_mask is None:
-            feature_mask = lrn.sample_feature_mask()
+            # the class step: one mask a trained class, drawn in class
+            # order as the staged chain draws one a tree
+            feature_mask = (lrn.sample_feature_mask() if self.classes is None
+                            else jnp.stack([lrn.sample_feature_mask()
+                                            for _ in self.classes]))
         obs = lrn._obs
         args = self.step_args(score, row_mult, feature_mask, scale)
         obs.entry_args("fused_iter", self._step, args,

@@ -47,7 +47,8 @@ _LONGEST_FIRST = (
     "test_wave_compact.py", "test_pack.py", "test_obs_spans.py",
     "test_compile_rows.py", "test_bundled_reference.py",
     "test_multiprocess.py", "test_graft_entry.py", "test_fused_iter.py",
-    "test_chip_smoke.py", "test_perfbench_correct.py")
+    "test_chip_smoke.py", "test_perfbench_covtype.py",
+    "test_fused_multiclass.py", "test_perfbench_correct.py")
 
 
 def pytest_collection_modifyitems(items):

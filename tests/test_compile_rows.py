@@ -332,3 +332,58 @@ def test_a_score_matrix_comes_to_the_host_by_pieces(n, monkeypatch):
     assert np.array_equal(got, np.asarray(x, np.float64))
     if n > piece:
         assert gbdt._columns_from._cache_size() == 1
+
+
+def test_the_multiclass_class_step_compiles_for_a_v5e(topo, as_tpu):
+    """The multiclass cell's step: Covertype's 10 numerical
+    columns and two one-hot blocks, which the program's EFB packs into 12
+    group columns, seven classes, one grow body scanned over them, at the
+    cell's 48,234,496 rows for a described v5e: both kernels inside the
+    one program, each under its scope, and the softmax under
+    `gradients`."""
+    import copy
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.obs import timers
+    from lightgbm_tpu.ops.fused_iter import FusedIteration
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rng = np.random.default_rng(40)
+    n0 = 4096
+    levels = [rng.integers(0, k, n0) for k in (4, 40)]
+    X = np.concatenate([rng.integers(0, 63, (n0, 10)).astype(np.float32)]
+                       + [np.eye(k, dtype=np.float32)[v]
+                          for k, v in zip((4, 40), levels)], axis=1)
+    y = rng.integers(0, 7, n0).astype(np.float32)
+    params = dict(HIGGS, objective="multiclass", num_class=7)
+    bst = lgb.Booster(dict(params), lgb.Dataset(X, label=y,
+                                                params=dict(params)))
+    gbdt = bst._gbdt
+    lrn = gbdt.learner
+    assert (lrn.plan.hist_mode, lrn.plan.wave_width, lrn.plan.fused_wanted,
+            int(lrn.X.shape[1])) == ("pallas_ct", 32, True, 12)
+    rows = 48_234_496
+    objective = copy.copy(gbdt.objective)
+    objective.num_data = rows
+    stub = types.SimpleNamespace(_row_pad=0, dtype=lrn.dtype,
+                                 _grow=lrn._grow)
+    fused = FusedIteration(stub, objective, rows, gbdt._score_engine,
+                           range(7))
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one)
+
+    args = (shape((rows, 12), jnp.uint8), shape((12, rows), jnp.uint8),
+            [shape((rows,), a.dtype) for a in fused._obj_arrays],
+            shape((7, rows), jnp.float32), shape((rows,), jnp.float32),
+            shape((7, 54), jnp.bool_), shape((), jnp.float32))
+    seconds, text = _compile_seconds(fused._step, args)
+    assert seconds < 60, seconds
+    _, table = timers.scope_table(text)
+    scopes = {name.rsplit(".", 1)[0]: scope for name, scope in table.items()
+              if name.startswith(("wave_partition_hist", "score_update"))}
+    assert scopes == {"wave_partition_hist_pallas_ct": "wave_histogram",
+                      "score_update_pallas": "score_update"}
+    assert {"gradients", "root_histogram", "split_search",
+            "bundle_view"} <= set(table.values())

@@ -4,9 +4,10 @@
   reproduces the staged chain's model file and predictions bit for bit —
   on the default (exact) grower AND on the CPU-interpret Pallas wave
   path (``tpu_pallas_interpret=true``) across scaled-down versions of
-  the flagship/epsilon/msltr/expo_cat benchmark shape buckets.
-* eligibility: DART/GOSS/multiclass/custom-fobj/gradient-health configs
-  fall back to the staged chain (with a warning under ``on``), and
+  the flagship/epsilon/msltr/expo_cat/multiclass benchmark shape buckets.
+* eligibility: DART/GOSS/custom-fobj/gradient-health configs fall back
+  to the staged chain (with a warning under ``on``), multiclass fuses
+  (the class step, tests/test_fused_multiclass.py), and
   ``auto`` keeps the staged chain on plain-CPU default runs.
 * the default boosting loop issues ZERO mid-tree host syncs — every
   deliberate block routes through obs/timers.fence, whose counter is
@@ -23,10 +24,12 @@ from lightgbm_tpu.ops.fused_iter import fused_supported
 from lightgbm_tpu.obs import timers as obs_timers
 
 
-def _xy(n, f, seed, classification=True):
+def _xy(n, f, seed, classification=True, num_class=2):
     rng = np.random.default_rng(seed)
     X = rng.standard_normal((n, f)).astype(np.float32)
     raw = X[:, 0] - 0.5 * X[:, 1 % f] + 0.1 * rng.standard_normal(n)
+    if num_class > 2:   # the class whose column is largest
+        return X, np.argmax(X[:, :num_class], axis=1).astype(np.float32)
     y = (raw > 0).astype(np.float32) if classification \
         else raw.astype(np.float32)
     return X, y
@@ -72,12 +75,14 @@ def test_fused_matches_staged_regression_objective():
 # scaled-down benchmark shape buckets (tools/BENCH_SUITE.md): the axes
 # that select different wave-kernel layouts — narrow-F (flagship),
 # wide-F (epsilon), mid-F deep trees (msltr), and the pallas_ct fused
-# partition kernel (expo_cat's ct-bound shape)
+# partition kernel (expo_cat's ct-bound shape), and seven class trees an
+# iteration through the same kernel (covtype's class step)
 PARITY_MATRIX = [
     ("flagship", 400, 12, 15, "pallas_t", "binary"),
     ("epsilon", 260, 48, 15, "pallas_t", "binary"),
     ("msltr", 350, 24, 31, "pallas_t", "regression"),
     ("expo_cat", 300, 10, 7, "pallas_ct", "binary"),
+    ("multiclass", 350, 12, 7, "pallas_ct", "multiclass"),
 ]
 
 
@@ -87,10 +92,13 @@ def test_fused_matches_staged_on_interpret_pallas_wave(name, n, f, leaves,
     """The fused program inlines the learner's own grow closure, so the
     Pallas wave kernels (run in interpret mode on CPU) must produce the
     same trees through either entry granularity."""
-    X, y = _xy(n, f, 7, classification=obj == "binary")
+    k = 7 if obj == "multiclass" else 2
+    X, y = _xy(n, f, 7, classification=obj == "binary", num_class=k)
     p = {"objective": obj, "num_leaves": leaves, "min_data_in_leaf": 5,
          "verbose": -1, "tpu_growth": "wave", "tpu_histogram_mode": mode,
          "tpu_pallas_interpret": True}
+    if k > 2:
+        p["num_class"] = k
     bf, bs = _pair(p, X, y, rounds=3)
     _assert_identical(bf, bs, X)
 
@@ -122,7 +130,7 @@ def test_fused_supported_rejects_special_modes():
     bst = lgb.train(p, lgb.Dataset(X, label=(y + (X[:, 1] > 0)),
                                    params=p), num_boost_round=2)
     ok, why = fused_supported(bst._gbdt)
-    assert not ok and "multiclass" in why
+    assert ok and not why, "multiclass takes the fused class step"
 
     def fobj(preds, ds):
         g = preds - ds.get_label()
